@@ -4,7 +4,7 @@
 PY ?= python
 export JAX_PLATFORMS ?= cpu
 
-.PHONY: lint lint-baseline verify-static plan-fuzz test test-slow sanitize-demo service-smoke chaos-smoke obs-smoke bench-check bench-trend shuffle-smoke fusion-smoke warmup-smoke multichip-smoke stream-smoke mem-smoke explain-smoke health-smoke adapt-smoke resume-smoke durability-smoke devprof-smoke verify
+.PHONY: lint lint-baseline verify-static plan-fuzz test test-slow sanitize-demo service-smoke chaos-smoke obs-smoke bench-check bench-trend shuffle-smoke fusion-smoke warmup-smoke multichip-smoke stream-smoke mem-smoke explain-smoke health-smoke adapt-smoke resume-smoke durability-smoke devprof-smoke chip-smoke-rehearse verify
 
 # engine-invariant static analysis; exits nonzero on findings beyond the
 # checked-in baseline (quokka_tpu/analysis/baseline.json)
@@ -106,9 +106,21 @@ warmup-smoke:
 # (ops/strategy.py), the timed shuffle path stays at ZERO blocking host
 # syncs, and no query fell back from the mesh to the embedded engine.
 multichip-smoke:
+	JAX_PLATFORMS=cpu \
 	QUOKKA_BENCH_SF=0.01 QUOKKA_BENCH_CACHE=/tmp/quokka_tpu_bench_mc \
 		QUOKKA_MULTICHIP_OUT=/tmp/MULTICHIP_timed_smoke.json \
 		$(PY) bench.py --multichip --smoke
+
+# CPU rehearsal of chip_smoke.py (the chip itself is reached only through the
+# builder's chip tool: `chiprun -- python chip_smoke.py`): every phase at
+# SF 0.01 with x64 off and the kernel strategies the TPU picks (sort
+# group-by, sorted join build, searchsorted asof), so the chip's branches
+# run here first.  Its last line names the platform jax reported (cpu) and
+# "rehearsal": true — it cannot pass for a chip run.
+chip-smoke-rehearse:
+	JAX_PLATFORMS=cpu \
+	QK_KERNEL_STRATEGY=groupby=sort,join_build=sort,asof=searchsorted \
+		$(PY) chip_smoke.py --rehearse --sf 0.01
 
 # streaming-plane smoke: a continuous asof join + a continuous windowed
 # aggregate over tailed CSV sources, under a seeded QK_CHAOS kill plan AND

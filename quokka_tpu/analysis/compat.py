@@ -1,4 +1,4 @@
-"""Version-guarded shims over private JAX APIs.
+"""Shims over private JAX APIs, resolved for the one installed jax.
 
 The package needs a handful of facts only private JAX surfaces expose (am I
 inside a trace?).  Using them ad hoc is how silent breakage happens: when a
@@ -8,7 +8,7 @@ wrong constant answer and the bug the probe exists to avoid comes back
 ``trace_state_clean`` would silently re-enable the nested-pjit dispatch
 race).  This module is the single allowed consumer of ``jax._src``/
 ``jax.core`` (lint rule QK003 exempts it): each shim resolves AT IMPORT TIME
-against an explicit candidate list and raises ``ImportError`` with the pinned
+against an explicit candidate list and raises ``ImportError`` with the installed
 version when none resolves — an upgrade that drops the API fails the whole
 package loudly at import instead of corrupting behavior at a call site.
 """
@@ -50,31 +50,10 @@ def _resolve(name: str, candidates: Sequence[Tuple[str, str]]) -> Callable:
 # negation to route nested calls to plain (traceable) bodies instead of
 # hitting a jit-wrapped object from inside another trace.
 trace_state_clean: Callable[[], bool] = _resolve(
-    "trace_state_clean",
-    (
-        ("core", "trace_state_clean"),
-        ("_src.core", "trace_state_clean"),
-    ),
+    "trace_state_clean", (("_src.core", "trace_state_clean"),)
 )
 
 
-# Size of a named mesh axis from inside a shard_map/pmap trace.  jax >= 0.5
-# exposes public ``jax.lax.axis_size``; on older jax the only source is the
-# axis-env frame (``jax.core.axis_frame(name).size``).  Shapes derive from
-# this (bucket capacity = axis size), so a wrong/defaulted answer would
-# build mis-shaped collectives — resolve loudly, never default.
-if hasattr(jax.lax, "axis_size"):
-    axis_size: Callable = jax.lax.axis_size
-else:
-    _axis_frame: Callable = _resolve(
-        "axis_frame",
-        (
-            ("core", "axis_frame"),
-            ("_src.core", "axis_frame"),
-        ),
-    )
-
-    def axis_size(axis) -> int:
-        frame = _axis_frame(axis)
-        # 0.4.37 returns the size itself; other 0.4.x return a frame object
-        return frame if isinstance(frame, int) else frame.size
+# Size of a named mesh axis from inside a shard_map trace.  Shapes derive
+# from this (bucket capacity = axis size), so it must resolve, never default.
+axis_size: Callable = jax.lax.axis_size

@@ -98,8 +98,7 @@ def _dir() -> Optional[str]:
     d = os.environ.get("QK_DEVPROF_DIR")
     if d is not None:
         return d or None
-    root = config.CACHE_ROOT
-    return os.path.join(root, "devprof") if root else None
+    return os.path.join(config.CACHE_ROOT, "devprof")
 
 
 def _fingerprint() -> str:

@@ -670,8 +670,6 @@ def _profile_dir() -> Optional[str]:
         return env or None
     from quokka_tpu import config
 
-    if not config.CACHE_ROOT:
-        return None
     return os.path.join(config.CACHE_ROOT, "cardprofile")
 
 

@@ -180,8 +180,6 @@ def _dir() -> Optional[str]:
     d = os.environ.get("QK_STRATEGY_DIR")
     if d is not None:
         return d or None
-    if not config.CACHE_ROOT:
-        return None
     return os.path.join(config.CACHE_ROOT, "strategy")
 
 

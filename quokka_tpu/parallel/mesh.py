@@ -37,20 +37,11 @@ def make_mesh(n_devices: Optional[int] = None, axis: str = "dp") -> Mesh:
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable shard_map.  jax >= 0.5 exposes ``jax.shard_map``
-    (replication-check knob named ``check_vma``); older jax ships it as
-    ``jax.experimental.shard_map.shard_map`` with the same knob named
-    ``check_rep``.  Every mesh program goes through this shim so the mesh
-    layer works on both — a bare ``jax.shard_map`` call raises
-    AttributeError on 0.4.x and silently disables the whole multichip
-    plane."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental import shard_map as _sm  # jax < 0.5
-
-    return _sm.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=check_vma)
+    """Every mesh program goes through this one spelling of
+    ``jax.shard_map`` (replication checking off by default: the steps mix
+    per-shard and replicated values freely)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 # ---------------------------------------------------------------------------

@@ -41,6 +41,8 @@ import queue
 import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
+
 from quokka_tpu import config
 from quokka_tpu.ops import sigkey
 from quokka_tpu.runtime.errors import CorruptArtifactError
@@ -51,7 +53,9 @@ from quokka_tpu.runtime.integrity import frame, unframe
 # acquire()/prewarm fill it.
 PROGRAMS: Dict[Tuple, object] = {}
 
-_ENTRY_VERSION = 1
+# v2: entries record the device assignment the program was compiled for
+# (v1 artifacts were loaded across every local device and are ignored)
+_ENTRY_VERSION = 2
 
 
 def _enabled() -> bool:
@@ -69,29 +73,24 @@ _fingerprint: Optional[str] = None
 
 
 def backend_fingerprint() -> str:
-    """Platform + device kind + device count + jax version + host uarch:
-    serialized executables are valid only on the topology that compiled
+    """Platform + device kind + device count + x64 mode + jax version +
+    host uarch: serialized executables are valid only on the topology (and
+    under the dtype policy — program keys do not carry it) that compiled
     them, so the artifact directory is namespaced by this — a foreign
     host/backend/jax is a cache MISS instead of a load error."""
     global _fingerprint
     with _fp_lock:
         if _fingerprint is not None:
             return _fingerprint
-        import jax
-
-        try:
-            devs = jax.devices()
-            platform = jax.default_backend()
-            kind = devs[0].device_kind if devs else "none"
-            count = len(devs)
-        except Exception:  # pragma: no cover - backend init failure
-            platform, kind, count = "unknown", "unknown", 0
+        devs = jax.devices()
+        platform = jax.default_backend()
         raw = "|".join([
-            platform, str(kind), str(count),
-            getattr(jax, "__version__", ""), config._host_fingerprint(),
+            platform, str(devs[0].device_kind), str(len(devs)),
+            f"x64={config.x64_enabled()}",
+            jax.__version__, config._host_fingerprint(),
         ])
         h = hashlib.sha256(raw.encode()).hexdigest()[:12]
-        _fingerprint = f"{platform}-{count}x-{h}"
+        _fingerprint = f"{platform}-{len(devs)}x-{h}"
         return _fingerprint
 
 
@@ -99,11 +98,7 @@ def _root_dir() -> Optional[str]:
     if not _enabled():
         return None
     base = os.environ.get("QUOKKA_AOT_CACHE_DIR", "")
-    if not base:
-        if not config.CACHE_ROOT:
-            return None  # persistent caching opted out entirely
-        base = os.path.join(config.CACHE_ROOT, "aot")
-    return base
+    return base or os.path.join(config.CACHE_ROOT, "aot")
 
 
 def _aot_dir(create: bool = False) -> Optional[str]:
@@ -383,9 +378,9 @@ def plan_sig_hashes(fp: str) -> List[str]:
 
 
 # Compiled.__call__'s argument-mismatch class: TypeError for aval/pytree
-# drift, ValueError for input-sharding drift (virtual multi-device CPU
-# places arrays jit would silently re-place; a compiled executable
-# refuses).  Both degrade to the jit fallback, never an error.
+# drift, ValueError for input-sharding drift (a multi-device process places
+# arrays jit would silently re-place; a compiled executable refuses).
+# Both degrade to the jit fallback, never an error.
 _MISMATCH_ERRORS = (TypeError, ValueError)
 
 
@@ -395,7 +390,8 @@ class AotProgram:
     compiled ones — the program keeps answering, one
     ``compile.aot_mismatch`` counter richer."""
 
-    __slots__ = ("compiled", "_builder", "_fallback", "prewarmed", "_counted")
+    __slots__ = ("compiled", "_builder", "_fallback", "prewarmed", "_counted",
+                 "_unproven")
 
     def __init__(self, compiled, builder: Optional[Callable[[], object]] = None,
                  prewarmed: bool = False):
@@ -404,6 +400,11 @@ class AotProgram:
         self._fallback = None
         self.prewarmed = prewarmed
         self._counted = False
+        # an executable LOADED from the store (those carry no builder, see
+        # acquire) has never run here: until its first call returns, a
+        # runtime refusal is a mismatch between artifact and process, not a
+        # query failure
+        self._unproven = builder is None
 
     def __call__(self, *args):
         if self.prewarmed and not self._counted:
@@ -412,9 +413,16 @@ class AotProgram:
         c = self.compiled
         if c is not None:
             try:
-                return c(*args)
+                out = c(*args)
+                self._unproven = False
+                return out
             except _MISMATCH_ERRORS:
                 # aval/sharding drift: drop to the jitted fallback for good
+                _count("aot_mismatch")
+                self.compiled = None
+            except jax.errors.JaxRuntimeError:
+                if not self._unproven:
+                    raise
                 _count("aot_mismatch")
                 self.compiled = None
         fb = self._fallback
@@ -457,8 +465,10 @@ def _load_entry(path: str):
         entry = pickle.loads(payload)
         if entry.get("v") != _ENTRY_VERSION:
             raise CorruptArtifactError(f"{path}: unknown entry version")
+        by_id = {d.id: d for d in jax.devices()}
         compiled = deserialize_and_load(
-            entry["exe"], entry["in_tree"], entry["out_tree"])
+            entry["exe"], entry["in_tree"], entry["out_tree"],
+            execution_devices=[by_id[i] for i in entry["devices"]])
         from quokka_tpu.obs import memplane
 
         # a loaded executable is host residency for the process lifetime
@@ -511,12 +521,17 @@ def _persist_now(key: Tuple, compiled) -> None:
     if path is None or os.path.exists(path):
         return
     exe, in_tree, out_tree = serialize(compiled)
-    # verify the round trip BEFORE writing: an executable that was itself
-    # loaded from the XLA persistent cache can serialize with its jitted
-    # symbols unresolved ("Symbols not found" on deserialize) — persisting
-    # that would poison every future restart with a quarantine cycle
+    # the ordered device assignment the program was compiled for: a reader
+    # must load onto exactly these (deserialize_and_load's default is every
+    # local device, which breaks a one-device program in a process that
+    # has several)
+    devices = compiled.runtime_executable().local_devices()
+    # verify the round trip BEFORE writing, loading as a reader will: an
+    # artifact that cannot be loaded back here would poison every future
+    # restart with a quarantine cycle
     try:
-        deserialize_and_load(exe, in_tree, out_tree)
+        deserialize_and_load(exe, in_tree, out_tree,
+                             execution_devices=devices)
     except Exception:  # noqa: BLE001 — any load failure means "don't ship"
         from quokka_tpu import obs
 
@@ -525,6 +540,7 @@ def _persist_now(key: Tuple, compiled) -> None:
     payload = pickle.dumps({
         "v": _ENTRY_VERSION, "key": key, "exe": exe,
         "in_tree": in_tree, "out_tree": out_tree,
+        "devices": [d.id for d in devices],
     })
     tmp = path + f".tmp.{os.getpid()}"
     with open(tmp, "wb") as f:
@@ -586,7 +602,10 @@ def acquire(key: Tuple, builder: Callable[[], object], args,
     prog: object = fn
     if _enabled():
         try:
+            from quokka_tpu.utils import compilestats
+
             lowered = lowerer() if lowerer is not None else fn.lower(*args)
+            hits = compilestats.thread_cache_hits()
             compiled = lowered.compile()
             prog = AotProgram(compiled, builder=lambda: fn)
             from quokka_tpu.obs import devprof
@@ -595,8 +614,13 @@ def acquire(key: Tuple, builder: Callable[[], object], args,
             # sidecar next to the AOT artifact under the same key
             devprof.record_cost(key, compiled,
                                 _entry_path(key, create=True))
-            _ensure_writer()
-            _write_q.put((key, compiled))
+            # an executable the XLA persistent cache answered re-serializes
+            # without its kernels: the copy loads, then fails its first
+            # run with an asynchronous NOT_FOUND no call site can catch.
+            # Only a real compile is shipped.
+            if compilestats.thread_cache_hits() == hits:
+                _ensure_writer()
+                _write_q.put((key, compiled))
         except Exception:  # noqa: BLE001 — AOT is an optimization layer:
             prog = fn      # the jitted callable is always a valid program
     PROGRAMS[key] = prog

@@ -1330,7 +1330,7 @@ class Engine:
     def _shutdown_prefetch(self) -> None:
         """Cancel speculative reads and release the IO threads — without this
         every Engine leaks its pool, and interpreter exit can block on a read
-        stuck in a wedged filesystem/tunnel."""
+        stuck in a wedged filesystem."""
         pool = getattr(self, "_prefetch_pool", None)
         if pool is not None:
             pool.shutdown(wait=False, cancel_futures=True)

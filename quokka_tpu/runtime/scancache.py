@@ -39,15 +39,11 @@ def _default_bytes() -> int:
     env = os.environ.get("QUOKKA_SCAN_CACHE_BYTES")
     if env is not None:
         return int(env)
-    try:
-        import jax
+    import jax
 
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
     # TPU HBM is >= 16 GB; host-memory (CPU) runs get a modest default so
     # tests and small boxes are not pinned by cached scans
-    return (2 << 30) if backend not in ("cpu",) else (256 << 20)
+    return (2 << 30) if jax.default_backend() != "cpu" else (256 << 20)
 
 
 def _batch_nbytes(batch: DeviceBatch) -> int:

@@ -29,12 +29,22 @@ _stats = {
     "traces": 0,
 }
 _registered = False
+# jax records the cache-hit event synchronously in the compiling thread, so
+# a per-thread count tells a caller whether ITS compile was a cache load
+_tls = threading.local()
 
 
 def _on_event(event: str, **kw) -> None:
-    with _lock:
-        if event == "/jax/compilation_cache/cache_hits":
+    if event == "/jax/compilation_cache/cache_hits":
+        _tls.cache_hits = thread_cache_hits() + 1
+        with _lock:
             _stats["cache_hits"] += 1
+
+
+def thread_cache_hits() -> int:
+    """Persistent-cache hits seen by the calling thread: unchanged across a
+    ``lowered.compile()`` means that compile was a real one."""
+    return getattr(_tls, "cache_hits", 0)
 
 
 def _on_duration(event: str, duration_secs: float, **kw) -> None:
@@ -65,13 +75,10 @@ def ensure_registered() -> None:
     if _registered:
         return
     _registered = True
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:
-        pass  # older jax: counters stay at zero rather than breaking
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def snapshot() -> Dict:

@@ -15,18 +15,24 @@ import numpy as np
 
 _LIB = None
 _TRIED = False
+# what the first-use build did: the helper is optional, but a missing
+# compiler is reported (status()), not hidden
+_BUILD_NOTE = "not attempted"
 
 
 def _build_lib(native_dir: str) -> None:
     """Best-effort auto-build of the native helper on first use."""
     import subprocess
 
+    global _BUILD_NOTE
     src = os.path.join(native_dir, "columnar.cpp")
     out = os.path.join(native_dir, "libquokka_native.so")
     if not os.path.exists(src):
+        _BUILD_NOTE = f"no source at {src}"
         return
     if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
-        return  # up to date; rebuild only when the source is newer
+        _BUILD_NOTE = "up to date"  # rebuild only when the source is newer
+        return
     tmp = out + f".build-{os.getpid()}"
     try:
         subprocess.run(
@@ -36,11 +42,20 @@ def _build_lib(native_dir: str) -> None:
             timeout=120,
         )
         os.replace(tmp, out)  # atomic: never leave a torn .so behind
-    except Exception:
+        _BUILD_NOTE = "built from columnar.cpp"
+    except Exception as e:
+        _BUILD_NOTE = f"build failed: {e!r}"
         try:
             os.remove(tmp)
         except OSError:
             pass
+
+
+def status() -> str:
+    """One line for bring-up logs: did the helper load, and what did its
+    first-use build do."""
+    how = "loaded" if _find_lib() is not None else "absent (Python paths)"
+    return f"native helper {how}; build: {_BUILD_NOTE}"
 
 
 def _find_lib():

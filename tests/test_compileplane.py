@@ -90,9 +90,9 @@ def aot_dir(tmp_path, monkeypatch):
 
 
 # Unique per test run: the shared XLA test cache must MISS on these toy
-# programs (an executable the XLA persistent cache loaded serializes with
-# unresolved symbols; compileplane verify-before-write would then skip
-# persistence and the AOT round-trip tests would have nothing to test).
+# programs (an executable the XLA persistent cache answered is never
+# persisted — see test_xla_cache_answered_compile_is_not_persisted — and
+# the AOT round-trip tests would have nothing to test).
 _RUN_TOKEN = int.from_bytes(os.urandom(4), "little") % 100_000
 
 
@@ -126,6 +126,100 @@ def test_aot_roundtrip_bit_exact(aot_dir):
     out2 = prog2(*args)
     for a, b in zip(out1, out2):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _child_env(**extra):
+    """This process's environment for a child that imports the checkout."""
+    env = dict(os.environ, **extra)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [_REPO] + [p for p in [env.get("PYTHONPATH", "")] if p])
+    return env
+
+
+_CROSS_PROCESS_CHILD = """
+import json, sys
+import jax, jax.numpy as jnp
+from quokka_tpu.ops import sigkey
+from quokka_tpu.runtime import compileplane
+
+assert jax.device_count() == 8, jax.devices()
+key = sigkey.make_key("t_xproc", int(sys.argv[1]), ((8,), "float32"))
+x = jnp.arange(8.0, dtype=jnp.float32)
+builder = lambda: jax.jit(lambda a: (a * 3 + int(sys.argv[1]), jnp.sum(a)))
+prog = compileplane.acquire(key, builder, (x,))
+out = prog(x)
+compileplane.drain_writes()
+print(json.dumps({"out": [float(v) for v in out[0]], "sum": float(out[1]),
+                  "stats": compileplane.stats()}))
+"""
+
+
+def test_persisted_executable_loads_and_runs_in_second_process(tmp_path):
+    """Persist in one process, load AND CALL in a second, both with eight
+    local devices: a one-device program must load onto its one device (the
+    jax default loads it across all eight and the first call is refused)."""
+    import subprocess
+    import sys
+
+    # conftest's 8 forced host devices ride along in os.environ
+    env = _child_env(QUOKKA_AOT_CACHE_DIR=str(tmp_path / "aot"),
+                     QUOKKA_AOT_CACHE="1")
+
+    def run():
+        r = subprocess.run(
+            [sys.executable, "-c", _CROSS_PROCESS_CHILD, str(_RUN_TOKEN)],
+            capture_output=True, text=True, timeout=300, env=env)
+        assert r.returncode == 0, r.stderr[-3000:]
+        return json.loads(r.stdout.strip().splitlines()[-1])
+
+    first, second = run(), run()
+    assert first["stats"].get("miss") == 1
+    assert second["stats"].get("cache_hit") == 1, second
+    assert not second["stats"].get("miss"), second
+    assert not second["stats"].get("aot_mismatch"), second
+    assert second["out"] == first["out"] and second["sum"] == first["sum"]
+
+
+def test_xla_cache_answered_compile_is_not_persisted(aot_dir):
+    """An executable the XLA persistent cache loaded re-serializes without
+    its kernels (it loads, then fails its first run asynchronously), so
+    only a real compile reaches the AOT store."""
+    args = (jnp.arange(8.0, dtype=jnp.float32),
+            jnp.ones(8, dtype=jnp.float32))
+    k1 = sigkey.make_key("t_xla_hit", _RUN_TOKEN, 1, ((8,), "float32"))
+    k2 = sigkey.make_key("t_xla_hit", _RUN_TOKEN, 2, ((8,), "float32"))
+    # same program under two keys: the second compile is an XLA cache hit
+    compileplane.acquire(k1, functools.partial(_toy_builder, 7), args)
+    compileplane.acquire(k2, functools.partial(_toy_builder, 7), args)
+    compileplane.drain_writes()
+    assert os.path.exists(compileplane._entry_path(k1))
+    assert not os.path.exists(compileplane._entry_path(k2))
+
+
+def test_runtime_refusal_on_first_call_of_loaded_executable_is_a_mismatch(
+        aot_dir):
+    """An artifact the process cannot run (written for another device
+    layout) costs one ``compile.aot_mismatch`` and a rebuild at the call
+    site, never the query."""
+    import jax
+
+    class Refuses:
+        def __call__(self, *a):
+            raise jax.errors.JaxRuntimeError("INVALID_ARGUMENT: 8 shards")
+
+    before = compileplane.stats().get("aot_mismatch", 0)
+    prog = compileplane.AotProgram(Refuses())  # no builder: loaded
+    with pytest.raises(compileplane.AotMismatch):
+        prog(jnp.ones(4))
+    assert compileplane.stats().get("aot_mismatch", 0) == before + 1
+    # a program that has already answered here is proven: a later runtime
+    # error is the query's own (OOM, ...) and must surface
+    fresh = compileplane.AotProgram(Refuses(), builder=lambda: None)
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        fresh(jnp.ones(4))
 
 
 def test_corrupt_artifact_falls_back_to_fresh_compile(aot_dir):
@@ -280,6 +374,45 @@ def test_per_query_counters_through_scope(aot_dir):
         compileplane.acquire(key, functools.partial(_toy_builder, 6), args)
     assert counters["cache_hit"].value == 1
     obs.REGISTRY.remove(*(c.name for c in counters.values()))
+
+
+@pytest.mark.parametrize("jax_dir,quokka_dir,expect", [
+    ("from_jax", None, "from_jax"),         # placed from outside
+    ("from_jax", "from_quokka", "from_jax"),  # the tests' override loses
+    (None, "from_quokka", "from_quokka"),   # the tests' scratch override
+    (None, None, os.path.join(_REPO, ".jax_cache")),  # fixed, in-checkout
+])
+def test_cache_root_placement(tmp_path, jax_dir, quokka_dir, expect):
+    """JAX_COMPILATION_CACHE_DIR places jax's cache AND every store under
+    config.CACHE_ROOT; unset, both land on one fixed path in the checkout
+    (the directory is part of the cache key: it must never move)."""
+    import subprocess
+    import sys
+
+    env = _child_env()
+    for k in ("JAX_COMPILATION_CACHE_DIR", "QUOKKA_JAX_CACHE_DIR",
+              "QUOKKA_AOT_CACHE_DIR", "QK_STRATEGY_DIR"):
+        env.pop(k, None)
+    if jax_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / jax_dir)
+    if quokka_dir:
+        env["QUOKKA_JAX_CACHE_DIR"] = str(tmp_path / quokka_dir)
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import json, jax\n"
+         "from quokka_tpu import config\n"
+         "from quokka_tpu.ops import strategy\n"
+         "from quokka_tpu.runtime import compileplane\n"
+         "print(json.dumps([jax.config.jax_compilation_cache_dir,"
+         " config.CACHE_ROOT, compileplane._root_dir(), strategy._dir()]))"],
+        capture_output=True, text=True, timeout=120, env=env,
+        cwd=str(tmp_path))
+    assert r.returncode == 0, r.stderr[-3000:]
+    jax_cache, root, aot, strat = json.loads(r.stdout.strip().splitlines()[-1])
+    want = expect if os.path.isabs(expect) else str(tmp_path / expect)
+    assert jax_cache == want and root == want
+    assert aot == os.path.join(want, "aot")
+    assert strat == os.path.join(want, "strategy")
 
 
 def test_backend_fingerprint_shape():

