@@ -108,9 +108,10 @@ Rule ids:
                                 bench.py — a hand-rolled timer is invisible
                                 to the span aggregator (obs/spans.py), the
                                 flight recorder and the bench breakdown;
-                                durations route through obs.span()/
-                                spans.add(), deliberate low-level sites
-                                baseline with a rationale
+                                durations route through obs.span()
+                                (its ``dur``/``self_s`` after the block),
+                                deliberate low-level sites baseline with a
+                                rationale
 
 Finding keys (``Finding.key``) are line-number-free — ``rule::relpath::
 scope::snippet[::n]`` — so a baseline survives unrelated edits above the
@@ -1824,9 +1825,10 @@ def check_adhoc_wall_timing(tree: ast.Module, path: str, rel: str,
     flight recorder and the bench breakdown — the measurement exists only
     in whatever local variable it landed in, which is exactly how the
     engine accumulated three private timing idioms before PR 13.  Route
-    durations through ``obs.span()``/``obs.spans.add()`` (they also land
-    in the merged timeline) or baseline deliberate low-level sites with a
-    rationale.  Deadline arithmetic (``deadline - time.monotonic()``) is
+    durations through ``obs.span()`` (the block's ``dur`` and ``self_s``
+    are on the span afterwards; it also lands in the merged timeline, the
+    query's record and a running profiler trace) or baseline deliberate
+    low-level sites with a rationale.  Deadline arithmetic (``deadline - time.monotonic()``) is
     not flagged: both operands must be clock readings."""
     r = rel.replace("\\", "/")
     base = r.rsplit("/", 1)[-1]
@@ -1863,8 +1865,8 @@ def check_adhoc_wall_timing(tree: ast.Module, path: str, rel: str,
                     "bare wall-clock delta — a hand-rolled timer is "
                     "invisible to the span aggregator, the flight "
                     "recorder and the bench breakdown; route the "
-                    "duration through obs.span()/obs.spans.add() "
-                    "(obs/spans.py), or baseline with a rationale",
+                    "duration through obs.span() (obs/spans.py: dur and "
+                    "self_s after the block), or baseline with a rationale",
                     src_lines))
     return out
 

@@ -9,14 +9,24 @@ One import surface for every runtime component:
   cheap enough to leave on (a tuple store per event, no locks on the hot
   path).  ``QK_TRACE_EVENTS=0`` disables it outright.
 - ``spans``: the span API (``QUOKKA_TRACE=1`` aggregate summary, the role
-  utils/tracing.py used to play) — spans additionally land in the flight
-  recorder as duration events.
+  utils/tracing.py used to play) — spans know their query and their parent,
+  land in the flight recorder as duration events with ``q``/``p``, and are
+  ``qk.<name>`` annotations in a running ``jax.profiler`` trace.
+- ``querylog``: one flat record per finished query (stamps, self seconds by
+  layer, counts), kept in a bounded process-wide deque: the service's query
+  log, and what the benchmark's per-layer metrics read.
 - ``metrics``: typed counters/gauges plus the engine's per-channel task
   accounting (folded out of runtime/engine.py).
 - ``merge``: coordinator-side merger — assembles per-worker event streams
   into one ordered timeline, exports Chrome trace-event JSON (loadable in
   Perfetto: ui.perfetto.dev -> Open trace file) and renders human-readable
   stall reports naming the stuck worker and its in-flight task.
+
+Clocks: a ring event's ``ts`` is ``time.time()`` at the event's END (so
+streams from different processes merge on one axis) and its ``dur`` a
+``time.perf_counter()`` difference; a query record's stamps and seconds are
+all ``time.perf_counter()`` (``wall_done`` alone is ``time.time()``); a
+``qk.*`` annotation is on the profiler trace's own clock.
 
 Env vars (the full table is in README "Observability"):
 
@@ -51,6 +61,7 @@ from quokka_tpu.obs import (
     metrics,
     opstats,
     progress,
+    querylog,
     recorder,
     spans,
 )
@@ -75,7 +86,7 @@ from quokka_tpu.obs.recorder import (
     recorder_enabled,
     trace_export_path,
 )
-from quokka_tpu.obs.spans import add, span, summary
+from quokka_tpu.obs.spans import span, summary
 
 _RPC_SLOW_S = 0.005
 

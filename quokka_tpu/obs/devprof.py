@@ -154,7 +154,9 @@ def _persist_profile(data: Dict[str, Any]) -> None:
         return
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = f"{path}.tmp.{os.getpid()}"
+        # a tmp name per THREAD: two pool threads persisting at once
+        # shared one, and the second os.replace found it gone
+        tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
         with open(tmp, "w") as f:
             json.dump(data, f)
         os.replace(tmp, path)
@@ -362,7 +364,11 @@ def record_cost(key, compiled, path: Optional[str] = None) -> None:
     obs.REGISTRY.counter("devprof.programs_costed").inc()
     if path:
         try:
-            tmp = f"{_cost_sidecar(path)}.tmp.{os.getpid()}"
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            # a tmp name per THREAD: both pool threads compile the same
+            # program at times, and the second os.replace found it gone
+            tmp = (f"{_cost_sidecar(path)}.tmp.{os.getpid()}"
+                   f".{threading.get_ident()}")
             with open(tmp, "w") as f:
                 json.dump({"version": _COST_VERSION, **cost}, f)
             os.replace(tmp, _cost_sidecar(path))

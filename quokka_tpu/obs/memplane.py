@@ -632,7 +632,9 @@ def record_footprint(plan_fp: str, peak_bytes: int,
             "runs": int(ent.get("runs", 0) or 0) + 1,
         }
         os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = f"{path}.tmp.{os.getpid()}"
+        # a tmp name per THREAD: two pool threads persisting at once
+        # shared one, and the second os.replace found it gone
+        tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
         with open(tmp, "w", encoding="utf-8") as f:
             json.dump(prof, f)
         os.replace(tmp, path)

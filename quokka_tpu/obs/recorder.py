@@ -121,8 +121,10 @@ class FlightRecorder:
         self._current: Dict[str, Tuple[str, float]] = {}
 
     # -- hot path -----------------------------------------------------------
-    def record(self, kind: str, name: str = "", dur: float = 0.0,
+    def record(self, kind: str, name: str = "", /, dur: float = 0.0,
                **args) -> int:
+        # kind and name are positional-only: an event's args may hold a
+        # ``kind`` or ``name`` of their own (compile.acquire's program kind)
         if not self.enabled:
             return -1
         rate = self._sample.get(kind)
@@ -166,15 +168,6 @@ class FlightRecorder:
         """Per-kind counts of events QK_TRACE_SAMPLE elided (never entered
         the ring; distinct from ``dropped``, which is ring eviction)."""
         return dict(self._sampled_by)
-
-    def set_current(self, activity: str) -> None:
-        if self.enabled:
-            self._current[threading.current_thread().name] = (
-                activity, time.time())
-
-    def clear_current(self) -> None:
-        if self.enabled:
-            self._current.pop(threading.current_thread().name, None)
 
     class _Activity:
         __slots__ = ("rec", "name", "prev")

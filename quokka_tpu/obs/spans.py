@@ -1,18 +1,29 @@
-"""Structured tracing: named spans with aggregate timings + flight events.
+"""Structured tracing: named spans that know their request and their parent.
 
 The span API that used to live in utils/tracing.py (which now re-exports
-this module).  Two consumers share one ``span(...)`` call site:
+this module).  Four consumers share one ``span(...)`` call site:
 
 - the aggregate summary (``QUOKKA_TRACE=1`` or ``set_enabled(True)``):
   name -> (count, total seconds), printed by bench.py at run end — the
   replacement for the reference's print_if_profile timestamp prints
   (pyquokka/core.py:20-30);
 - the flight recorder: every span lands as a duration event in the ring
-  (obs/recorder.py) so merged timelines show where time went per worker.
+  (obs/recorder.py), with ``q`` (the query id) and ``p`` (the enclosing
+  span's name; ``task`` directly under a dispatch, ``offthread`` on a
+  helper thread) in its args, so merged timelines show where time went per
+  worker and per request;
+- the query log (obs/querylog.py): a span's **self time** (its duration
+  minus what the spans nested in it covered) is summed by layer into the
+  one record each finished query leaves;
+- the profiler's timeline: while a ``jax.profiler`` trace is running, each
+  span is also a ``TraceAnnotation("qk.<name>")`` on the thread that ran
+  it, so the device's timeline and the engine's meet on the trace's clock.
 
-When neither consumer is live the span body pays nothing but the two
-``perf_counter`` calls it skipped before this refactor, restored by the
-early-out below.
+Nesting is tracked per thread: a span opened inside another is its child,
+inherits its query id, and adds its duration to the parent's "covered by
+children" sum when it closes.  ``dispatch(...)`` opens the root of one task
+dispatch; the self times of everything closed under it partition the
+dispatch's duration exactly.  Durations are on ``time.perf_counter()``.
 """
 
 from __future__ import annotations
@@ -21,15 +32,44 @@ import os
 import threading
 import time
 from collections import defaultdict
-from contextlib import contextmanager
-from typing import Dict
+from typing import Dict, Optional
 
+from jax.profiler import TraceAnnotation as _Annotation
+
+from quokka_tpu.obs import querylog as _querylog
 from quokka_tpu.obs import recorder as _recorder
 
 _enabled = os.environ.get("QUOKKA_TRACE", "0") not in ("0", "", "false")
 
 _lock = threading.Lock()
 _stats = defaultdict(lambda: [0, 0.0])  # name -> [count, total_seconds]
+
+# the innermost open span of each thread
+_TLS = threading.local()
+_tracing = _Annotation.is_enabled  # an atomic read while no trace runs
+_now = time.perf_counter
+
+# in-dispatch span name -> the query record's layer key (obs/querylog.py)
+_LAYER_PREFIXES = (
+    (("exec.", "done."), "executors.exec_self"),
+    (("push.",), "runtime.push"),
+    (("reader.execute", "bridge.to_device", "prefetch.wait"), "io.read"),
+    (("emit.",), "emit.d2h"),
+    (("compile.acquire",), "compile.acquire"),
+)
+_LAYER_OF: Dict[str, str] = {}
+# the one blocking device read that is a span (DeviceBatch.count_valid): the
+# record counts it and says how much of ``other`` it is
+_SYNC_BLOCK = "count_valid.block"
+
+
+def _layer(name: str) -> str:
+    layer = _LAYER_OF.get(name)
+    if layer is None:
+        layer = next((key for prefixes, key in _LAYER_PREFIXES
+                      if name.startswith(prefixes)), "other")
+        _LAYER_OF[name] = layer
+    return layer
 
 
 def enabled() -> bool:
@@ -43,35 +83,175 @@ def set_enabled(on: bool) -> None:
     _enabled = bool(on)
 
 
-@contextmanager
-def span(name: str):
-    rec = _recorder.RECORDER
-    if not (_enabled or rec.enabled):
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
+class Span:
+    """One timed region: ``with span("exec.Join"): ...``.  After the block,
+    ``dur`` and ``self_s`` hold its duration and self time, ``t0`` its start
+    (all ``time.perf_counter()``).
+
+    ``ring=False`` keeps it out of the flight recorder and the aggregate
+    summary (a per-turn span would evict the ring's history); it is still a
+    parent for what nests in it and an annotation in a running trace.
+    ``args`` (a dict, settable inside the block) ride the ring event.
+    ``cancel()`` inside the block makes the region vanish: no event, and
+    its time stays the parent's own."""
+
+    __slots__ = ("name", "q", "ring", "args", "parent", "root", "covered",
+                 "t0", "dur", "self_s", "keep", "_ann")
+
+    def __init__(self, name: str, q: Optional[str] = None,
+                 ring: bool = True):
+        self.name = name
+        self.q = q
+        self.ring = ring
+        self.args: Optional[dict] = None
+        self.covered = 0.0
+        self.dur = self.self_s = 0.0
+        self.keep = True
+
+    def __enter__(self) -> "Span":
+        parent = self.parent = getattr(_TLS, "top", None)
+        if parent is not None:
+            self.root = parent.root
+            if self.q is None:
+                self.q = parent.q
+        else:
+            self.root = None
+        _TLS.top = self
+        if _tracing():
+            self._ann = _Annotation("qk." + self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self.t0 = _now()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = self.dur = _now() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        parent = self.parent
+        _TLS.top = parent
+        if self.keep:
+            if parent is not None:
+                parent.covered += dt
+            self.self_s = dt - self.covered
+            self._account(dt, parent)
+        return False
+
+    def cancel(self) -> None:
+        self.keep = False
+
+    def rename(self, name: str) -> None:
+        """Name the region by how it ended (``svc.step`` -> ``svc.fruitless``);
+        a running trace keeps the name it was opened under."""
+        self.name = name
+
+    def _account(self, dt: float, parent: Optional["Span"]) -> None:
+        root = self.root
+        if root is not None:
+            # inside a dispatch: its thread's own dict, no lock
+            layer = _layer(self.name)
+            root.parts[layer] = root.parts.get(layer, 0.0) + self.self_s
+            if self.name == _SYNC_BLOCK:
+                parts = root.parts
+                parts["sync_blocks"] = parts.get("sync_blocks", 0) + 1
+                parts["other.sync_block"] = (
+                    parts.get("other.sync_block", 0.0) + self.self_s)
+        elif self.q is not None:
+            _querylog.add(self.q, _off_name(self.name, parent), dt,
+                          self.self_s)
+        if not self.ring:
+            return
         if _enabled:
             with _lock:
-                s = _stats[name]
+                s = _stats[self.name]
                 s[0] += 1
                 s[1] += dt
-        rec.record("span", name, dur=dt)
+        rec = _recorder.RECORDER
+        if rec.enabled:
+            args = dict(self.args) if self.args else {}
+            if self.q is not None:
+                args["q"] = self.q
+            if parent is not None:
+                args["p"] = "task" if parent is root else parent.name
+            rec.record("span", self.name, dur=dt, **args)
 
 
-def add(name: str, seconds: float, count: int = 1):
-    rec = _recorder.RECORDER
-    if not (_enabled or rec.enabled):
-        return
-    if _enabled:
-        with _lock:
-            s = _stats[name]
-            s[0] += count
-            s[1] += seconds
-    rec.record("span", name, dur=seconds, count=count)
+def _off_name(name: str, parent: Optional[Span]) -> str:
+    if parent is not None and parent.name == "offthread":
+        return "offthread." + name
+    return name
+
+
+span = Span
+
+
+class Dispatch(Span):
+    """The root of one task dispatch (``Engine.dispatch_task``).  Spans
+    closed under it sum their self times by layer into ``parts`` (and the
+    ``count_valid.block`` reads among them: a count, and their seconds); the
+    caller sets ``ok`` before the block ends, and a dispatch that
+    progressed hands ``parts`` (its own self time under
+    ``runtime.dispatch_self``) to the query's record.  One that could not
+    progress is the query waiting on its own pipeline: its whole duration
+    goes to the record's scheduling wait.  ``label`` names the task for
+    whatever compiles under it.  The caller writes the ``task`` ring event
+    itself (it carries the dispatch's causal note)."""
+
+    __slots__ = ("label", "parts", "ok")
+
+    def __init__(self, kind: str, label: str, q: Optional[str]):
+        Span.__init__(self, "task:" + kind, q, ring=False)
+        self.label = label
+        self.parts: Dict[str, float] = {}
+        self.ok = False
+
+    def __enter__(self) -> "Dispatch":
+        Span.__enter__(self)
+        self.root = self
+        return self
+
+    def _account(self, dt: float, parent: Optional[Span]) -> None:
+        if self.q is None:
+            return
+        if self.ok:
+            self.parts["runtime.dispatch_self"] = self.self_s
+            _querylog.task(self.q, self.t0, dt, self.parts)
+        else:
+            _querylog.add(self.q, "task.requeue", dt, dt)
+
+
+dispatch = Dispatch
+
+
+class _Offthread(Span):
+    __slots__ = ()
+
+    def _account(self, dt: float, parent: Optional[Span]) -> None:
+        pass  # a carrier of q for what closes inside it, not a region
+
+
+def offthread(q: Optional[str]) -> Span:
+    """``with offthread(qid): ...`` on a helper thread (prefetch pool,
+    emitter, spill writer): spans closed inside carry ``q`` and
+    ``p="offthread"``, and the record sums them under ``offthread.<name>``,
+    outside the partition of the dispatches."""
+    return _Offthread("offthread", q, ring=False)
+
+
+def current_task() -> Dict[str, str]:
+    """{"q": query id, "task": dispatch label} of what the calling thread
+    is inside, with the keys that are known: what an event recorded from
+    under a dispatch says about who asked."""
+    top = getattr(_TLS, "top", None)
+    if top is None:
+        return {}
+    out = {}
+    if top.q is not None:
+        out["q"] = top.q
+    if top.root is not None:
+        out["task"] = top.root.label
+    return out
 
 
 def stats() -> Dict[str, Dict[str, float]]:

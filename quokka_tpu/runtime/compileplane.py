@@ -577,7 +577,33 @@ def acquire(key: Tuple, builder: Callable[[], object], args,
     Always returns a callable and installs it in PROGRAMS; on any AOT
     failure the plain jitted builder result stands in.  ``lowerer``
     overrides how the jitted function lowers (kernels with trailing static
-    args lower with them but are CALLED without)."""
+    args lower with them but are CALLED without).
+
+    Every call is one ``compile.acquire`` span naming the program (the
+    key's kind and hash, its signature cut to 200 characters), whether it
+    was loaded or built (``hit``), whether this thread really compiled
+    (``real``), and the query and task that asked; the same lands in that
+    query's record (obs/querylog.py ``compiled``)."""
+    from quokka_tpu.obs import querylog, spans
+    from quokka_tpu.utils import compilestats
+
+    kind = key[0] if key and isinstance(key[0], str) else "?"
+    h = key_hash(key)
+    with spans.span("compile.acquire") as sp:
+        before = compilestats.thread_real_compiles()
+        prog, hit = _acquire(key, builder, args, lowerer)
+        real = compilestats.thread_real_compiles() > before
+        sp.args = {"kind": kind, "key_hash": h, "sig": repr(key[1:])[:200],
+                   "hit": hit, "real": real}
+        if sp.root is not None:
+            sp.args["task"] = sp.root.label
+    querylog.compiled(sp.q, kind, h, sp.dur, real, hit)
+    return prog
+
+
+def _acquire(key: Tuple, builder: Callable[[], object], args,
+             lowerer: Optional[Callable[[], object]]):
+    """(program, "cache_hit" | "miss"): acquire's work."""
     note_program(key, installed=True)
     path = _entry_path(key)
     if path is not None and os.path.exists(path):
@@ -596,7 +622,7 @@ def acquire(key: Tuple, builder: Callable[[], object], args,
 
             # replay the persisted static-cost sidecar (no re-analysis)
             devprof.load_cost(key, path)
-            return prog
+            return prog, "cache_hit"
     _count("miss")
     fn = builder()
     prog: object = fn
@@ -624,7 +650,7 @@ def acquire(key: Tuple, builder: Callable[[], object], args,
         except Exception:  # noqa: BLE001 — AOT is an optimization layer:
             prog = fn      # the jitted callable is always a valid program
     PROGRAMS[key] = prog
-    return prog
+    return prog, "miss"
 
 
 def aot_kernel_call(kind: str, jit_fn, args: Tuple, statics: Tuple = ()):

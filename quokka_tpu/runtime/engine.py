@@ -844,13 +844,21 @@ class Engine:
                         thread_name_prefix="quokka-spill",
                     )
         with self._spill_lock:
-            self._spill_futs.append(pool.submit(self._spill_one, name, part))
+            self._spill_futs.append(
+                pool.submit(self._offthread, self._spill_one, name, part))
         while True:
             with self._spill_lock:
                 if len(self._spill_futs) <= config.SPILL_INFLIGHT:
                     break
                 f = self._spill_futs.pop(0)
             f.result()  # bound device memory pinned by pending spills
+
+    def _offthread(self, fn, *args):
+        """Run ``fn`` on a helper thread (prefetch pool, emitter, spill
+        writer) as this query's off-thread work: the spans it closes carry
+        the query id and ``p="offthread"`` (obs/spans.py)."""
+        with tracing.offthread(getattr(self.g, "query_id", None)):
+            return fn(*args)
 
     def _spill_one(self, name: Tuple, part: DeviceBatch) -> None:
         with tracing.span("spill.hbq"):
@@ -972,7 +980,8 @@ class Engine:
                 pf[key] = (
                     nxt,
                     self._prefetch_pool.submit(
-                        self._read_and_bridge, info, task.channel, lineage_n
+                        self._offthread, self._read_and_bridge, info,
+                        task.channel, lineage_n
                     ),
                 )
         return batch
@@ -1178,8 +1187,13 @@ class Engine:
                         del chans[ch]
             if not chans:
                 del task.input_reqs[src]
-                with opstats.OPSTATS.current_op(qid, task.actor,
-                                                task.channel):
+                # executor work (a join's build side finalizes here): a
+                # done.* span, so it is the executors' time in the query's
+                # record and not the dispatch's own
+                with tracing.span(
+                        f"done.source.{type(executor).__name__}"), \
+                        opstats.OPSTATS.current_op(qid, task.actor,
+                                                   task.channel):
                     extra = executor.source_done(
                         info.source_streams[src], task.channel)
                 # emit decisions never inspect device data (a live-row count is
@@ -1187,7 +1201,8 @@ class Engine:
                 emitted = extra is not None
                 if emitted:
                     self._stamp_exec_wm(executor, extra, task.channel)
-                    self._emit(info, task.channel, out_seq, extra)
+                    with tracing.span("push.exec"):
+                        self._emit(info, task.channel, out_seq, extra)
                     self._metric(task.actor, task.channel, self._rows_of(extra), 0)
                     opstats.OPSTATS.exec_out(qid, task.actor, task.channel,
                                              self._rows_of(extra))
@@ -1209,7 +1224,8 @@ class Engine:
             for o in outs:
                 if o is not None:
                     self._stamp_exec_wm(executor, o, task.channel)
-                    self._emit(info, task.channel, out_seq, o)
+                    with tracing.span("push.exec"):
+                        self._emit(info, task.channel, out_seq, o)
                     self._metric(task.actor, task.channel, self._rows_of(o), 0)
                     opstats.OPSTATS.exec_out(qid, task.actor, task.channel,
                                              self._rows_of(o))
@@ -1825,38 +1841,36 @@ class Engine:
         dump needs)."""
         rec = obs.RECORDER
         qid = getattr(self.g, "query_id", None)
+        label = f"{task.name}:a{task.actor}c{task.channel}"
+        if qid is not None:
+            label = f"{qid}:{label}"
+        _OBS_NOTE.d = {}
+        # the root of this dispatch's spans (obs/spans.py): what closes
+        # under it knows its query and its parent, and the self times
+        # partition the dispatch for the query's record
+        with tracing.dispatch(task.name, label, qid) as frame:
+            try:
+                with rec.activity("task:" + label):
+                    ok = frame.ok = self._dispatch(task)
+            finally:
+                note = getattr(_OBS_NOTE, "d", None) or {}
+                _OBS_NOTE.d = None
+        if ok:
+            dt = frame.dur
+            self._observe_latency(dt)
+            opstats.OPSTATS.dispatch_time(qid, task.actor, task.channel, dt)
         if not rec.enabled:
-            t0 = time.perf_counter()
-            ok = self._dispatch(task)
-            if ok:
-                dt = time.perf_counter() - t0
-                self._observe_latency(dt)
-                opstats.OPSTATS.dispatch_time(qid, task.actor, task.channel,
-                                              dt)
             return ok
         qargs = {"a": task.actor, "c": task.channel, "k": task.name}
         if qid is not None:
             qargs["q"] = qid
-        label = f"{task.name}:a{task.actor}c{task.channel}"
-        if qid is not None:
-            label = f"{qid}:{label}"
         idle = getattr(self, "_obs_idle", None)
         if idle is None:
             idle = self._obs_idle = set()
         key = (task.actor, task.channel, task.name)
-        _OBS_NOTE.d = {}
-        t0 = time.perf_counter()
-        try:
-            with rec.activity("task:" + label):
-                ok = self._dispatch(task)
-        finally:
-            note = getattr(_OBS_NOTE, "d", None) or {}
-            _OBS_NOTE.d = None
         if ok:
-            dt = time.perf_counter() - t0
-            rec.record("task", label, dur=dt, **qargs, **note)
-            self._observe_latency(dt)
-            opstats.OPSTATS.dispatch_time(qid, task.actor, task.channel, dt)
+            rec.record("task", label, dur=dt, self_s=frame.self_s, **qargs,
+                       **note)
             idle.discard(key)
         elif key not in idle:
             idle.add(key)
@@ -2049,7 +2063,7 @@ class Engine:
                         max_workers=1, thread_name_prefix="quokka-emit"
                     )
         with self._emit_lock:
-            self._emit_futs.append(pool.submit(fn))
+            self._emit_futs.append(pool.submit(self._offthread, fn))
         while True:
             with self._emit_lock:
                 if len(self._emit_futs) <= self._EMIT_INFLIGHT:
@@ -2144,7 +2158,9 @@ class Engine:
                     continue
                 self._prefetch[key] = (
                     0,
-                    self._prefetch_pool.submit(self._read_and_bridge, info, ch, lineage),
+                    self._prefetch_pool.submit(
+                        self._offthread, self._read_and_bridge, info, ch,
+                        lineage),
                 )
 
     def _run(self, max_batches: Optional[int], timeout: float) -> None:
@@ -2246,6 +2262,20 @@ class Engine:
         """Returns 'done' (query complete), 'progress' (a task ran),
         'wait' (a task popped but could not progress and requeued itself),
         or 'idle' (nothing poppable at the current stage)."""
+        with tracing.span("step.pick", q=getattr(self.g, "query_id", None),
+                          ring=False) as pick:
+            task = self._service_pick()
+            if isinstance(task, str):
+                # "done"/"idle": nothing was picked, so no pick to time;
+                # the walk stays the step's own (svc.fruitless when idle)
+                pick.cancel()
+                return task
+        ok = self.dispatch_task(task)
+        return "progress" if ok else "wait"
+
+    def _service_pick(self):
+        """The next task of this query for one quantum, or "done"/"idle":
+        the stage barrier, the completion check and the actor walk."""
         self._service_prepare()
         actors = self._svc_actors
         stages = self._svc_stages
@@ -2271,8 +2301,7 @@ class Engine:
             if task is None:
                 continue
             self._svc_cursor = (start + i + 1) % n
-            ok = self.dispatch_task(task)
-            return "progress" if ok else "wait"
+            return task
         return "idle"
 
     def service_finalize(self) -> None:

@@ -236,6 +236,19 @@ class QueryService:
         at the next task boundary and fails with ``DeadlineExceeded``
         (default from ``QK_QUERY_DEADLINE_S``; distinct from the global
         stall timeout, which only fires on NO progress)."""
+        # the inside view of the caller's submit time: one ``submit`` span
+        # whose children are the phases (their self times are the query
+        # record's ``entry.*`` keys, obs/querylog.py)
+        with obs.spans.span("submit") as top:
+            handle = self._submit(
+                top, stream, working_set_bytes=working_set_bytes,
+                exec_config=exec_config, durable=durable,
+                resume_from=resume_from, deadline_s=deadline_s)
+        obs.querylog.stamp(handle.query_id, "submit_out", top.t0 + top.dur)
+        return handle
+
+    def _submit(self, top, stream, *, working_set_bytes, exec_config,
+                durable, resume_from, deadline_s) -> QueryHandle:
         with self._lock:
             if self._shutdown:
                 raise ServiceShutdown("QueryService is shut down")
@@ -263,12 +276,15 @@ class QueryService:
                 "durable=True needs fault_tolerance=True: the resume "
                 "manifest records checkpoint frontiers and replays spilled "
                 "batches, neither of which exists without it")
-        qid = new_query_id()
+        qid = top.q = new_query_id()
+        obs.querylog.open(qid)
+        obs.querylog.stamp(qid, "submit_in", top.t0)
         graph = TaskGraph(cfg, store=self.store,
                           cache=BatchCache(owner=qid), query_id=qid,
                           spill_dir=self._spill_dir)
         try:
-            sub, sink_id = ctx._prepare_plan(stream.node_id)
+            with obs.spans.span("submit.prepare_plan"):
+                sub, sink_id = ctx._prepare_plan(stream.node_id)
             blob = None
             if durable:
                 # capture the PREPARED (pre-lowering) plan: recovery
@@ -284,32 +300,40 @@ class QueryService:
                     raise ValueError(
                         "durable=True needs a picklable plan (no lambdas/"
                         f"closures in map/filter payloads): {e!r}") from e
-            sink_actor = ctx._lower_plan(sub, sink_id, graph)
-            est = (int(working_set_bytes) if working_set_bytes is not None
-                   else estimate_working_set(graph))
+            with obs.spans.span("submit.lower_plan"):
+                sink_actor = ctx._lower_plan(sub, sink_id, graph)
+            with obs.spans.span("submit.estimate"):
+                est = (int(working_set_bytes)
+                       if working_set_bytes is not None
+                       else estimate_working_set(graph))
             if durable:
                 from quokka_tpu.runtime import resume as bresume
 
                 graph.resume_manifest = bresume.default_path(graph)
                 graph.resume_plan_blob = blob
                 graph.resume_est_bytes = est
-            session = QuerySession(qid, graph, sink_actor, est,
-                                   self.inflight_per_query)
-            session.durable = durable
-            if deadline_s is not None:
-                session.deadline_at = session.submitted_at + float(deadline_s)
-            self._enqueue_session(session)
-            if durable:
-                # initial manifest at submit: a crash before the first
-                # checkpoint still re-admits (as a fresh run — no frontier
-                # to resume, but no silently vanished query either)
-                bresume.update(graph)
+            with obs.spans.span("submit.enqueue"):
+                session = QuerySession(qid, graph, sink_actor, est,
+                                       self.inflight_per_query)
+                session.durable = durable
+                if deadline_s is not None:
+                    session.deadline_at = (session.submitted_at
+                                           + float(deadline_s))
+                self._enqueue_session(session)
+                if durable:
+                    # initial manifest at submit: a crash before the first
+                    # checkpoint still re-admits (as a fresh run — no
+                    # frontier to resume, but no silently vanished query)
+                    bresume.update(graph)
         except BaseException:
+            obs.querylog.discard(qid)
             graph.cleanup()
             raise
-        # admit synchronously when it fits: the caller's next submit must
-        # see this query CHARGED against the budget, not still in the queue
-        self._admit_pending()
+        with obs.spans.span("submit.enqueue"):
+            # admit synchronously when it fits: the caller's next submit
+            # must see this query CHARGED against the budget, not still in
+            # the queue
+            self._admit_pending()
         obs.RECORDER.record("service.submit", qid, q=qid, est_bytes=est,
                             durable=durable)
         return session.handle
@@ -439,6 +463,7 @@ class QueryService:
         except BaseException:
             # an aborted submit never ran: durable resume state (if any)
             # must survive for the next attempt
+            obs.querylog.discard(qid)
             graph.cleanup(preserve_durable=resume_from is not None)
             raise
         self._admit_pending()
@@ -536,6 +561,7 @@ class QueryService:
         except BaseException:
             # an aborted resume never ran: the durable recovery trio must
             # survive for the next attempt
+            obs.querylog.discard(qid)
             graph.cleanup(preserve_durable=True)
             raise
         self._admit_pending()
@@ -690,6 +716,9 @@ class QueryService:
             "admission": self.admission.stats(),
             "sessions": sessions,  # live only; finished sessions are GC'd
             "finished": self._finished,
+            # records kept by the process-wide query log (obs/querylog.py):
+            # one per finished query, read with obs.querylog.records()
+            "recent_queries": obs.querylog.size(),
             "scan_cache": scancache.GLOBAL.stats(),
             "queue_wait": obs.REGISTRY.histogram(
                 "admission.queue_wait_s").stats(),
@@ -744,6 +773,7 @@ class QueryService:
                 obs.REGISTRY.histogram("admission.queue_wait_s").observe(
                     now - s.submitted_at)
                 obs.RECORDER.record("service.admit", qid, q=qid)
+                obs.querylog.stamp(qid, "admitted")
             for qid, waited in timed_out:
                 s = self._queued.pop(qid, None)
                 if s is not None:
@@ -780,81 +810,110 @@ class QueryService:
             session.inflight -= 1
 
     def _worker_loop(self) -> None:
-        fruitless = 0  # consecutive non-progress quanta on THIS thread
-        while True:
-            with self._lock:
-                if self._shutdown:
-                    return
-                n_running = len(self._running)
-            self._admit_pending()
-            self._reap_deadlines()
-            session = self._next_slot()
-            if session is None:
-                with self._wake:
-                    if not self._shutdown:
-                        self._wake.wait(0.005)
-                continue
-            # cooperative cancellation/deadline: honored at the task
-            # boundary, before dispatching another quantum for this query
-            if session.cancel_requested or (
-                    session.deadline_at is not None
-                    and time.time() > session.deadline_at):
-                self._release_slot(session)
-                if session.cancel_requested:
-                    self._finish(session, QueryCancelled(
-                        f"query {session.query_id} cancelled"))
-                else:
-                    obs.REGISTRY.counter("cancel.deadline").inc()
-                    self._finish(session, DeadlineExceeded(
-                        f"query {session.query_id} exceeded its deadline "
-                        f"({time.time() - session.submitted_at:.1f}s since "
-                        "submit)"))
-                continue
-            err: Optional[BaseException] = None
-            outcome = None
-            try:
+        """One pool thread: quanta until shutdown.  Each turn is one
+        ``svc.quantum`` span (a profiler annotation, never a ring event: one
+        per turn would double the ring's traffic), so in a trace a worker
+        is always inside a named span; what the turn spent outside its
+        children (``_admit_pending``, ``_reap_deadlines``, ``_next_slot``,
+        ``_release_slot`` and their lock) is the loop's own cost,
+        ``service.loop_s``."""
+        fruitless: Optional[int] = 0  # consecutive non-progress quanta
+        while fruitless is not None:
+            with obs.spans.span("svc.quantum", ring=False) as turn:
+                fruitless = self._quantum(fruitless)
+            obs.REGISTRY.counter("service.loop_s").inc(turn.self_s)
+
+    def _quantum(self, fruitless: int) -> Optional[int]:
+        """One turn of a pool thread; returns the thread's count of
+        consecutive fruitless quanta, or None at shutdown."""
+        with self._lock:
+            if self._shutdown:
+                return None
+            n_running = len(self._running)
+        self._admit_pending()
+        self._reap_deadlines()
+        session = self._next_slot()
+        if session is None:
+            # parked: no query to charge, so a process-wide counter
+            with obs.spans.span("svc.park", ring=False) as park, self._wake:
+                if not self._shutdown:
+                    self._wake.wait(0.005)
+            obs.REGISTRY.counter("service.park_s").inc(park.dur)
+            return fruitless
+        # cooperative cancellation/deadline: honored at the task
+        # boundary, before dispatching another quantum for this query
+        if session.cancel_requested or (
+                session.deadline_at is not None
+                and time.time() > session.deadline_at):
+            self._release_slot(session)
+            if session.cancel_requested:
+                self._finish(session, QueryCancelled(
+                    f"query {session.query_id} cancelled"))
+            else:
+                obs.REGISTRY.counter("cancel.deadline").inc()
+                self._finish(session, DeadlineExceeded(
+                    f"query {session.query_id} exceeded its deadline "
+                    f"({time.time() - session.submitted_at:.1f}s since "
+                    "submit)"))
+            return fruitless
+        err: Optional[BaseException] = None
+        outcome = None
+        try:
+            # a step that progressed is its children (step.pick, the task);
+            # one that returned wait/idle is the query waiting on its own
+            # pipeline: svc.fruitless, a duration and a count in its record
+            # (no ring event: a worker spinning on a blocked query would
+            # evict the ring's history, as task.wait's coalescing says)
+            with obs.spans.span("svc.step", q=session.query_id,
+                                ring=False) as step:
                 outcome = session.engine.service_step()
-            except BaseException as e:  # noqa: BLE001 — fail THIS query only
-                err = e
-            finally:
-                self._release_slot(session)
-            if err is not None:
-                self._finish(session, err)
-                continue
-            if outcome == "done":
-                fruitless = 0
-                self._finish(session, None)
-            elif outcome == "progress":
-                fruitless = 0
-                session.last_progress = time.time()
-                due = False
-                with self._lock:
-                    session.handled += 1
-                    inj = session.inject
-                    due = (inj is not None
-                           and session.handled >= inj["after_tasks"])
-                if due:
-                    self._maybe_inject(session)
-            else:  # "wait" / "idle": the query is blocked on its own pipeline
-                # standing queries are exempt from the stall timeout — one
-                # waiting for data is healthy, and keeps its slot
-                # indefinitely (watermark-lag / /status surface staleness);
-                # they share the batch queries' backoff below
-                if (not session.streaming and
-                        time.time() - session.last_progress
-                        > self.query_timeout):
-                    self._finish(session, QueryStallTimeout(
-                        f"query {session.query_id} made no progress for "
-                        f"{self.query_timeout:.0f}s "
-                        f"(pending tasks: {session.graph.store.ntt_total()})"))
-                    continue
-                # back off only once every running query got a fruitless
-                # quantum from this thread — a single blocked query must
-                # neither hot-spin the pool nor throttle its neighbors
-                fruitless += 1
-                if fruitless >= max(2, 2 * n_running):
-                    fruitless = 0
-                    time.sleep(0.002)
+                if outcome in ("wait", "idle"):
+                    step.rename("svc.fruitless")
+        except BaseException as e:  # noqa: BLE001 — fail THIS query only
+            err = e
+        finally:
+            self._release_slot(session)
+        if err is not None:
+            self._finish(session, err)
+            return fruitless
+        if outcome == "done":
+            self._finish(session, None)
+            return 0
+        if outcome == "progress":
+            session.last_progress = time.time()
+            due = False
+            with self._lock:
+                session.handled += 1
+                inj = session.inject
+                due = (inj is not None
+                       and session.handled >= inj["after_tasks"])
+            if due:
+                self._maybe_inject(session)
+            return 0
+        # "wait" / "idle": the query is blocked on its own pipeline.
+        # Standing queries are exempt from the stall timeout — one
+        # waiting for data is healthy, and keeps its slot
+        # indefinitely (watermark-lag / /status surface staleness);
+        # they share the batch queries' backoff below
+        if (not session.streaming and
+                time.time() - session.last_progress
+                > self.query_timeout):
+            self._finish(session, QueryStallTimeout(
+                f"query {session.query_id} made no progress for "
+                f"{self.query_timeout:.0f}s "
+                f"(pending tasks: {session.graph.store.ntt_total()})"))
+            return fruitless
+        # back off only once every running query got a fruitless
+        # quantum from this thread — a single blocked query must
+        # neither hot-spin the pool nor throttle its neighbors
+        fruitless += 1
+        if fruitless >= max(2, 2 * n_running):
+            fruitless = 0
+            # charged to the session the last fruitless quantum was for
+            with obs.spans.span("svc.backoff", q=session.query_id,
+                                ring=False):
+                time.sleep(0.002)
+        return fruitless
 
     def _maybe_inject(self, session: QuerySession) -> None:
         """Run the query's configured fault injection (the
@@ -910,15 +969,16 @@ class QueryService:
                 return
             session.want_exclusive = True
         deadline = time.time() + self.query_timeout
-        while time.time() < deadline:
-            with self._lock:
-                if session.inflight == 0:
-                    break
-            time.sleep(0.001)
-        else:
-            obs.diag(f"[service] tearing down {qid} with "
-                     f"{session.inflight} dispatch quantum(s) still live "
-                     f"after {self.query_timeout:.0f}s drain")
+        with obs.spans.span("svc.drain", q=qid):
+            while time.time() < deadline:
+                with self._lock:
+                    if session.inflight == 0:
+                        break
+                time.sleep(0.001)
+            else:
+                obs.diag(f"[service] tearing down {qid} with "
+                         f"{session.inflight} dispatch quantum(s) still "
+                         f"live after {self.query_timeout:.0f}s drain")
         first = session.finish(err)
         with self._lock:
             if qid in self._running:

@@ -30,6 +30,11 @@ class QuerySession:
                  inflight_cap: int):
         from quokka_tpu.runtime.engine import Engine
 
+        from quokka_tpu.obs import querylog
+
+        # the query's record starts here (idempotent: submit() opened it
+        # before planning, the resume and standing paths open it now)
+        querylog.open(query_id)
         self.query_id = query_id
         self.graph = graph
         self.sink_actor = sink_actor
@@ -101,20 +106,49 @@ class QuerySession:
                 return False
             self.status = FAILED if error is not None else DONE
             self.error = error
+        from quokka_tpu import obs
+        from quokka_tpu.obs import spans
+
+        obs.querylog.stamp(self.query_id, "finalize_in")
         try:
+            # svc.finalize: entry to just before _done.set() releases the
+            # client's to_df — what a request's turnover costs the pool
+            with spans.span("svc.finalize", q=self.query_id):
+                error = self._teardown(error)
+        finally:
+            self.finished_at = time.time()
+            try:
+                # the query's one record (obs/querylog.py), from the
+                # snapshots just taken; plain values only
+                obs.querylog.close(
+                    self.query_id, self.status,
+                    plan_fp=getattr(self.graph, "plan_fp", None),
+                    opstats=self.opstats, scan_stats=self.scan_stats,
+                    pool_size=getattr(self._service, "pool_size", 0))
+            finally:
+                self._done.set()
+        return True
+
+    def _teardown(self, error: Optional[BaseException]
+                  ) -> Optional[BaseException]:
+        """finish()'s work: flush, snapshot, GC.  Returns the query's error
+        (the flush's own, where it failed and the query had none)."""
+        from quokka_tpu import obs
+        from quokka_tpu.obs import spans
+
+        with spans.span("finalize.flush"):
             try:
                 self.engine.service_finalize()
             except Exception as e:  # noqa: BLE001 — keep first error
                 if error is None:
                     self.status = FAILED
                     self.error = error = e
+        with spans.span("finalize.snapshots"):
             from quokka_tpu.runtime import scancache
 
             stats = scancache.GLOBAL.stats()["by_query"].get(self.query_id)
             self.scan_stats = dict(stats) if stats else {"hits": 0,
                                                          "misses": 0}
-            from quokka_tpu import obs
-
             h = obs.REGISTRY.histograms().get(
                 f"task.latency_s.{self.query_id}")
             self.latency_stats = (h.stats() if h is not None
@@ -131,6 +165,7 @@ class QuerySession:
             # last honest estimate — it did NOT complete
             self.progress_snap = progress_mod.TRACKER.on_query_gc(
                 self.query_id, finished=error is None)
+        with spans.span("finalize.cleanup"):
             try:
                 # a standing query that FAILED (or was shut down mid-stream)
                 # keeps its durable recovery trio — checkpoints, HBQ spill,
@@ -147,14 +182,10 @@ class QuerySession:
                     preserve = isinstance(error, ServiceShutdown)
                 self.graph.cleanup(preserve_durable=preserve)
             except Exception as e:  # noqa: BLE001 — teardown must not kill
-                from quokka_tpu import obs  # the pool thread running it
-
+                # the pool thread running it
                 obs.diag(f"[service] cleanup of {self.query_id} failed: "
                          f"{e!r}")
-        finally:
-            self.finished_at = time.time()
-            self._done.set()
-        return True
+        return error
 
     @property
     def finished(self) -> bool:
@@ -265,10 +296,19 @@ class QueryHandle:
         return self.dataset
 
     def to_arrow(self, timeout: Optional[float] = None):
-        return self.result(timeout).to_arrow()
+        return self._materialize(self.result(timeout).to_arrow)
 
     def to_df(self, timeout: Optional[float] = None):
-        return self.result(timeout).to_df()
+        return self._materialize(self.result(timeout).to_df)
+
+    def _materialize(self, convert):
+        """What the caller's thread still does once the query is done (the
+        result's Arrow tables to one table or frame): a ring event and a
+        trace annotation; the query's record is closed by then."""
+        from quokka_tpu.obs import spans
+
+        with spans.span("handle.materialize", q=self.query_id):
+            return convert()
 
     def metrics(self) -> Dict:
         """Per-(actor, channel) progress counters (TaskGraph.metrics shape)

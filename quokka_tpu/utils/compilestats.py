@@ -47,25 +47,36 @@ def thread_cache_hits() -> int:
     return getattr(_tls, "cache_hits", 0)
 
 
+def thread_real_compiles() -> int:
+    """Backend compiles the calling thread paid for itself (persistent-cache
+    loads taken off): a difference across a call says whether that call
+    really compiled, and how many programs."""
+    return getattr(_tls, "backend_compiles", 0) - thread_cache_hits()
+
+
 def _on_duration(event: str, duration_secs: float, **kw) -> None:
     with _lock:
         if event == "/jax/core/compile/backend_compile_duration":
             _stats["backend_compiles"] += 1
             _stats["backend_compile_seconds"] += duration_secs
+            _tls.backend_compiles = getattr(_tls, "backend_compiles", 0) + 1
         elif event == "/jax/core/compile/jaxpr_trace_duration":
             _stats["traces"] += 1
         else:
             return
     # compile activity in the flight recorder: merged timelines show which
-    # worker paid a compile (or a persistent-cache load) and when
+    # worker paid a compile (or a persistent-cache load), when, and for
+    # which query's task where one is open on this thread.  Programs of the
+    # compile plane are named by its own compile.acquire span; this event
+    # is what names the rest (eager jnp calls, plain jit fallbacks)
     try:
-        from quokka_tpu.obs import recorder
+        from quokka_tpu.obs import recorder, spans
 
         recorder.RECORDER.record(
             "compile",
             "backend_compile" if event.endswith("backend_compile_duration")
             else "trace",
-            dur=duration_secs)
+            dur=duration_secs, **spans.current_task())
     except Exception:
         return  # monitoring must never break the compile path
 

@@ -9,7 +9,6 @@ new code.
 from __future__ import annotations
 
 from quokka_tpu.obs.spans import (  # noqa: F401 — re-export surface
-    add,
     enabled,
     reset,
     set_enabled,

@@ -63,9 +63,9 @@ def test_nested_activity_restores_outer_marker():
 def test_dump_text_renders_tail_and_activity():
     rec = FlightRecorder(capacity=16, enabled=True)
     rec.record("task", "exec:a1c0", dur=0.01)
-    rec.set_current("rpc:ntt_pop")
     out = io.StringIO()
-    rec.dump_text(out)
+    with rec.activity("rpc:ntt_pop"):
+        rec.dump_text(out)
     text = out.getvalue()
     assert "exec:a1c0" in text and "rpc:ntt_pop" in text
 
@@ -179,17 +179,20 @@ def test_span_lands_in_summary_and_recorder(monkeypatch):
     spans.reset()
     before = obs.RECORDER.snapshot()
     last = before[-1][0] if before else -1
-    with spans.span("unit.work"):
-        pass
-    spans.add("unit.add", 0.25, count=2)
+    with spans.span("unit.work") as outer:
+        with spans.span("unit.inner") as inner:
+            pass
     st = spans.stats()
     assert st["unit.work"]["count"] == 1
-    assert st["unit.add"] == {"count": 2, "total_s": 0.25}
+    assert st["unit.inner"]["count"] == 1
+    # a span's self time is its duration less what its children covered
+    assert abs(outer.self_s - (outer.dur - inner.dur)) < 1e-9
     assert "unit.work" in spans.summary()
     if obs.RECORDER.enabled:
-        names = [e[3] for e in obs.RECORDER.snapshot(since=last)
-                 if e[2] == "span"]
-        assert "unit.work" in names and "unit.add" in names
+        evs = {e[3]: e for e in obs.RECORDER.snapshot(since=last)
+               if e[2] == "span"}
+        assert "unit.work" in evs and "unit.inner" in evs
+        assert evs["unit.inner"][6] == {"p": "unit.work"}
     spans.reset()
     spans.set_enabled(os.environ.get("QUOKKA_TRACE", "0")
                       not in ("0", "", "false"))
