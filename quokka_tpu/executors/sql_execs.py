@@ -15,7 +15,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 
 from quokka_tpu import config
-from quokka_tpu.ops import bridge, kernels
+from quokka_tpu.ops import aggtail, bridge, kernels
 from quokka_tpu.ops import join as join_ops
 from quokka_tpu.ops.batch import DeviceBatch, NumCol
 from quokka_tpu.ops.expr_compile import AggPlan, evaluate_predicate, evaluate_to_column
@@ -141,7 +141,26 @@ class SelectingStorageExecutor(StorageExecutor):
         return out.select([c for c in self.schema if c in out.columns])
 
 
-class PartialAggExecutor(Executor):
+class _FoldsPartials:
+    """What the two aggregators share: buffered partials folded into one
+    running state (``keys``, ``plan``, ``state``, ``_buffer``)."""
+
+    # NOTE: a merge of small parts is one compiled program that reads nothing
+    # back (ops/aggtail.py).  Above its threshold the parts are compacted
+    # first, which blocks on every live count that has not landed: the async
+    # copies start at partial creation and merges run batches later, so on
+    # the CPU those reads are from host memory; on the chip a pool thread
+    # waits in them (count_valid.block, PERF.md section 5)
+
+    def _merge(self) -> None:
+        if not self._buffer:
+            return  # state alone is already folded
+        parts, self._buffer = self._buffer, []
+        self.state = aggtail.recombine(
+            self.keys, self.plan.recombine, parts, self.state)
+
+
+class PartialAggExecutor(_FoldsPartials, Executor):
     SUPPORTS_CHECKPOINT = True
     """Per-channel partial group-by: maintains one running partial-aggregate
     batch; emits it at done.  Sits upstream of the hash shuffle."""
@@ -188,25 +207,6 @@ class PartialAggExecutor(Executor):
             g = kernels.groupby_aggregate(b, self.keys, aggs)
         return g.select(self.keys + [p for p, _, _ in self.plan.partials])
 
-    def _recombine(self, parts: List[DeviceBatch]) -> DeviceBatch:
-        parts = [kernels.compact(p) for p in parts]
-        merged = bridge.concat_batches(parts) if len(parts) > 1 else parts[0]
-        aggs = [(p, op, merged.columns[p].data) for (p, op) in self.plan.recombine]
-        g = kernels.groupby_aggregate(merged, self.keys, aggs)
-        return g.select(self.keys + [p for p, _ in self.plan.recombine])
-
-    # NOTE: _recombine's per-part compact blocks only on counts that have not
-    # yet landed (async copies start at partial creation; merges run batches
-    # later, so in steady state the reads are from host memory)
-
-    def _merge(self) -> None:
-        if not self._buffer:
-            return  # state alone is already folded
-        parts, self._buffer = self._buffer, []
-        if self.state is not None:
-            parts.append(self.state)
-        self.state = self._recombine(parts)
-
     def _partial_form(self, batch: DeviceBatch) -> DeviceBatch:
         """Raw rows -> partial-FORM rows (count columns = 1 per valid row,
         value columns = the pre-expression inputs) with NO grouping: the
@@ -227,10 +227,15 @@ class PartialAggExecutor(Executor):
         outs = []
         live = [b for b in batches if b is not None]
         if not self._passthrough:
-            # one group-by over the dispatch's bucketed whole instead of a
-            # sort per per-partition batch; deterministic under tape replay
-            # (the same recorded batch set coalesces identically)
-            live = _coalesce(live)
+            # one group-by over a dispatch's SMALL batches (per-partition
+            # slices) instead of a launch chain each; deterministic under
+            # tape replay (the same recorded batch set coalesces
+            # identically).  Large batches stay apart: to concatenate two
+            # 1<<20-row scan batches costs the chip 129 ms, their partial
+            # aggregates 27.7 ms each (PERF.md section 6, PR 27), and which
+            # batches are ready together follows timing, so the concat's
+            # and the aggregate's shapes would too
+            live = _coalesce(live, cap_rows=aggtail.SMALL_ROWS)
         for b in live:
             if self._passthrough:
                 outs.append(self._partial_form(b))
@@ -276,7 +281,7 @@ class PartialAggExecutor(Executor):
         self.state = None if state is None else bridge.arrow_to_device(state)
 
 
-class FinalAggExecutor(Executor):
+class FinalAggExecutor(_FoldsPartials, Executor):
     """Downstream of the key shuffle: recombines partials for its key range,
     then applies final expressions, HAVING, ORDER BY and LIMIT at done."""
 
@@ -301,18 +306,6 @@ class FinalAggExecutor(Executor):
     # batches instead of compacted partials: also fold on accumulated padded
     # rows so the buffer can't hold 32 raw batches on device at once
     MERGE_ROWS = 1 << 21
-
-    def _merge(self) -> None:
-        if not self._buffer:
-            return  # state alone is already folded
-        parts, self._buffer = self._buffer, []
-        if self.state is not None:
-            parts.append(self.state)
-        parts = [kernels.compact(p) for p in parts]
-        merged = bridge.concat_batches(parts) if len(parts) > 1 else parts[0]
-        aggs = [(p, op, merged.columns[p].data) for (p, op) in self.plan.recombine]
-        g = kernels.groupby_aggregate(merged, self.keys, aggs)
-        self.state = g.select(self.keys + [p for p, _ in self.plan.recombine])
 
     def execute(self, batches, stream_id, channel):
         self._buffer.extend(b for b in batches if b is not None)
@@ -344,20 +337,22 @@ class FinalAggExecutor(Executor):
                 else:
                     cols[pname] = np.array([np.nan])
             self.state = bridge.arrow_to_device(pa.table(cols))
-        g = self.state
+        g, self.state = self.state, None
+        out = aggtail.final_tail(g, self.keys, self.plan, self.having,
+                                 self.order_by, self.limit)
+        aggtail.note_path(out is not None)
+        return out if out is not None else self._tail_general(g)
+
+    def _tail_general(self, g: DeviceBatch) -> DeviceBatch:
+        """The tail op by op: a large state, or finals that need the host."""
         for name, e in self.plan.finals:
             g = g.with_column(name, evaluate_to_column(e, g))
         # HAVING runs before the projection: it may reference partial columns
         # (aggregates rewritten by plan.rewrite) that the output drops
         if self.having is not None:
             g = kernels.compact(kernels.apply_mask(g, evaluate_predicate(self.having, g)))
-        out_cols = self.keys + [n for n, _ in self.plan.finals]
         # dedupe (a key may also be an output)
-        seen, cols = set(), []
-        for c in out_cols:
-            if c not in seen:
-                seen.add(c)
-                cols.append(c)
+        cols = list(dict.fromkeys(self.keys + [n for n, _ in self.plan.finals]))
         g = g.select(cols)
         if self.order_by:
             names = [n for n, _ in self.order_by]
@@ -368,7 +363,6 @@ class FinalAggExecutor(Executor):
                 g = kernels.sort_batch(g, names, desc)
         elif self.limit is not None:
             g = kernels.head(g, self.limit)
-        self.state = None
         return g
 
 

@@ -29,7 +29,9 @@ Record keys (README "Observability" documents each):
 - counts: ``tasks``, ``requeues``, ``backoffs``, ``sync_blocks``,
   ``compile_hits``,
   ``compile_misses``, ``compiled``, ``rows_in``, ``padded_in``,
-  ``rows_unknown``, ``scan_hits``, ``scan_misses``;
+  ``rows_unknown``, ``agg_merges_compiled``, ``agg_merges_general`` (merges
+  and final tails of the aggregators by the path they took:
+  ops/aggtail.py), ``scan_hits``, ``scan_misses``;
 - ``pool_size`` and ``park_s_total``, ``loop_s_total``: the service's
   worker threads, and their cumulative counters when the query finished.
 """
@@ -77,9 +79,14 @@ SECONDS = tuple(dict.fromkeys(
     + ["other.sync_block"] + list(OFFTHREAD) + ["task_s"]))
 COUNTS = ("tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
           "compile_misses", "rows_in", "padded_in", "rows_unknown",
+          "agg_merges_compiled", "agg_merges_general",
           "scan_hits", "scan_misses")
 KEYS = (("q", "plan_fp", "status") + STAMPS + SECONDS + COUNTS
         + ("compiled", "pool_size", "park_s_total", "loop_s_total"))
+
+# summed over the operators of the finish-time opstats snapshot
+_FROM_OPSTATS = ("rows_in", "padded_in", "rows_unknown",
+                 "agg_merges_compiled", "agg_merges_general")
 
 _lock = threading.Lock()
 _open: Dict[str, dict] = {}   # live queries' accumulators
@@ -186,9 +193,7 @@ def close(q: str, status: str, plan_fp: Optional[str] = None,
             return
         acc.update(
             q=q, plan_fp=plan_fp, status=status,
-            rows_in=sum(int(o.get("rows_in", 0)) for o in ops),
-            padded_in=sum(int(o.get("padded_in", 0)) for o in ops),
-            rows_unknown=sum(int(o.get("rows_unknown", 0)) for o in ops),
+            **{k: sum(int(o.get(k, 0)) for o in ops) for k in _FROM_OPSTATS},
             scan_hits=int((scan_stats or {}).get("hits", 0)),
             scan_misses=int((scan_stats or {}).get("misses", 0)),
             pool_size=int(pool_size), park_s_total=park_s,

@@ -16,6 +16,9 @@ and gathered by code on device; joins/groupbys on strings use the hash limbs.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import threading
+from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -23,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from quokka_tpu import config
+from quokka_tpu.ops import sigkey
 
 # ---------------------------------------------------------------------------
 # String dictionaries
@@ -108,6 +112,17 @@ class StringDict:
         return int(hits[0]) if len(hits) else -1
 
     @property
+    def rank(self) -> np.ndarray:
+        """int32 rank of every entry in the values' lexicographic order: an
+        ascending sort of ranks is a true string sort, not hash order."""
+        if getattr(self, "_rank", None) is None:
+            order = np.argsort(self.values.astype(str), kind="stable")
+            rank = np.empty(len(order), dtype=np.int32)
+            rank[order] = np.arange(len(order), dtype=np.int32)
+            self._rank = rank
+        return self._rank
+
+    @property
     def none_entries(self) -> Optional[np.ndarray]:
         """Bool mask of None (null) entries, or None when there are none."""
         if not hasattr(self, "_none_entries"):
@@ -145,6 +160,94 @@ class NumCol:
         )
 
 
+class _IdCache:
+    """LRU of device tables derived from host objects, keyed by the objects'
+    IDENTITY (an entry keeps its objects alive, so an id cannot be reused
+    while it is cached) and bounded by the elements held on the device."""
+
+    def __init__(self, budget: int):
+        self._budget = budget
+        self._held = 0
+        self._lock = threading.Lock()
+        self._entries: "OrderedDict[tuple, tuple]" = OrderedDict()
+
+    def get(self, tag: str, objs: Sequence, build):
+        """``build() -> (value, elements)`` on a miss (outside the lock: two
+        threads may both build, one copy stays)."""
+        key = (tag,) + tuple(id(o) for o in objs)
+        with self._lock:
+            hit = self._entries.get(key)
+            if hit is not None:
+                self._entries.move_to_end(key)
+                return hit[1]
+        value, cost = build()
+        with self._lock:
+            if key not in self._entries:
+                self._entries[key] = (tuple(objs), value, cost)
+                self._held += cost
+                while self._held > self._budget and len(self._entries) > 1:
+                    _, (_objs, _v, c) = self._entries.popitem(last=False)
+                    self._held -= c
+            return self._entries[key][1]
+
+
+# device copies of dictionaries' host tables, made once per dictionary and
+# not per call: 32 MB of int32 at the most
+DEVICE_TABLES = _IdCache(budget=1 << 23)
+
+
+def pad_table(values: np.ndarray, size: int) -> np.ndarray:
+    out = np.zeros(size, dtype=np.int32)
+    out[: len(values)] = values
+    return out
+
+
+def _device_tables(tag: str, dictionary: StringDict, host_tables):
+    """Host int32 tables of a dictionary as device arrays, padded to a power
+    of two (a table's length is part of a compiled program's signature)."""
+    def build():
+        size = sigkey.pow2_dim(len(dictionary))
+        tables = tuple(jax.device_put(pad_table(t, size))
+                       for t in host_tables())
+        return tables, len(tables) * size
+
+    return DEVICE_TABLES.get(tag, (dictionary,), build)
+
+
+def hash_tables(dictionary: StringDict):
+    """(hash_hi, hash_lo) of a dictionary on the device."""
+    return _device_tables(
+        "hash", dictionary, lambda: (dictionary.hash_hi, dictionary.hash_lo))
+
+
+def rank_table(dictionary: StringDict) -> jax.Array:
+    return _device_tables("rank", dictionary, lambda: (dictionary.rank,))[0]
+
+
+def code_hash_limbs(codes, hash_hi, hash_lo):
+    """(hi, lo) hash limbs of dictionary codes, given the dictionary's two
+    hash tables as device arrays.  Null rows (code < 0) get the hash of
+    null, (0, 0) — the pair _hash_strings assigns to None dictionary entries
+    — so all nulls land in one group for groupby/sort instead of aliasing
+    the last entry."""
+    c = jnp.maximum(codes, 0)
+    isnull = codes < 0
+    return jnp.where(isnull, 0, hash_hi[c]), jnp.where(isnull, 0, hash_lo[c])
+
+
+@functools.lru_cache(maxsize=None)
+def _hash_limbs_kernel():
+    return jax.jit(code_hash_limbs)  # built at first use, not at import
+
+
+def map_codes(codes, table):
+    """Dictionary codes through an int32 table on the device, null rows
+    (code -1) staying -1 (a bare gather would clamp -1 onto entry 0): codes
+    of one dictionary as codes of a merged one (bridge.merge_dicts' remap),
+    or as sort limbs (StringDict.rank: nulls sort first ascending)."""
+    return jnp.where(codes < 0, -1, table[jnp.maximum(codes, 0)])
+
+
 @dataclasses.dataclass
 class StrCol:
     """Dictionary-encoded string column: int32 codes on device, dict on host."""
@@ -157,15 +260,14 @@ class StrCol:
         return self.codes.shape[0]
 
     def hash_limbs(self):
-        """Two int32 device arrays (hi, lo) of the 64-bit value hash per row.
-        Null rows (code < 0) get the hash of null, (0, 0) — same pair
-        _hash_strings assigns to None dictionary entries — so all nulls land
-        in one group for groupby/sort instead of aliasing the last entry."""
-        c = jnp.maximum(self.codes, 0)
-        isnull = self.codes < 0
-        hi = jnp.where(isnull, 0, jnp.asarray(self.dictionary.hash_hi)[c])
-        lo = jnp.where(isnull, 0, jnp.asarray(self.dictionary.hash_lo)[c])
-        return hi, lo
+        """Two int32 device arrays (hi, lo) of the 64-bit value hash per row
+        (nulls: the hash of null, see code_hash_limbs): one program, over
+        the dictionary's cached device tables."""
+        from quokka_tpu.runtime import compileplane
+
+        return compileplane.aot_kernel_call(
+            "hash_limbs", _hash_limbs_kernel(),
+            (self.codes, *hash_tables(self.dictionary)))
 
     def take(self, idx: jax.Array) -> "StrCol":
         return StrCol(self.codes[idx], self.dictionary)

@@ -23,7 +23,14 @@ import pyarrow.compute as pc
 
 from quokka_tpu import config
 from quokka_tpu.ops import pack
-from quokka_tpu.ops.batch import DeviceBatch, NumCol, StrCol, StringDict, VecCol
+from quokka_tpu.ops.batch import (
+    DeviceBatch,
+    NumCol,
+    StrCol,
+    StringDict,
+    VecCol,
+    map_codes,
+)
 
 _I32_MIN = -(2**31)
 _I32_MAX = 2**31 - 1
@@ -280,8 +287,10 @@ def device_to_arrow(batch: DeviceBatch) -> pa.Table:
 
 def merge_dicts(dicts: Sequence[StringDict]):
     """Merge string dictionaries; returns (merged StringDict, [remap arrays])."""
-    if len(dicts) == 1:
-        return dicts[0], [None]
+    if all(d is dicts[0] for d in dicts):
+        # one dictionary object throughout (parts cut from one batch, or one
+        # cached scan): it is its own merge, and keeps its identity
+        return dicts[0], [None] * len(dicts)
     all_vals = np.concatenate([d.values for d in dicts])
     # np.unique on object arrays with None fails; substitute sentinel.
     # Uniqueness keys are str() reprs (injective per column type); merged
@@ -351,10 +360,7 @@ def concat_batches(batches: Sequence[DeviceBatch]) -> DeviceBatch:
             for c, remap, cnt in zip(cols, remaps, counts):
                 codes = c.codes[:cnt]
                 if remap is not None:
-                    # null rows carry code -1: keep them null (a bare gather
-                    # would clamp -1 onto dictionary entry 0)
-                    remapped = jnp.asarray(remap)[jnp.maximum(codes, 0)]
-                    codes = jnp.where(codes < 0, -1, remapped)
+                    codes = map_codes(codes, jnp.asarray(remap))
                 code_parts.append(codes)
             codes = _pad_device(jnp.concatenate(code_parts), padded)
             out_cols[name] = StrCol(codes, merged)
@@ -416,8 +422,7 @@ def _try_fused_concat(batches, total: int, padded: int):
             for c, remap in zip(cols, remaps):
                 codes = c.codes
                 if remap is not None:
-                    remapped = jnp.asarray(remap)[jnp.maximum(codes, 0)]
-                    codes = jnp.where(codes < 0, -1, remapped)
+                    codes = map_codes(codes, jnp.asarray(remap))
                 parts.append(codes)
             per_col.append((name, "str", tuple(parts)))
             str_meta[name] = merged
@@ -473,8 +478,7 @@ def _concat_batches_device(batches: Sequence[DeviceBatch]) -> DeviceBatch:
             for c, remap in zip(cols, remaps):
                 codes = c.codes
                 if remap is not None:
-                    remapped = jnp.asarray(remap)[jnp.maximum(codes, 0)]
-                    codes = jnp.where(codes < 0, -1, remapped)
+                    codes = map_codes(codes, jnp.asarray(remap))
                 code_parts.append(codes)
             out_cols[name] = StrCol(
                 _pad_device(jnp.concatenate(code_parts), total_padded), merged

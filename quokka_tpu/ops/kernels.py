@@ -29,7 +29,15 @@ from jax import lax
 
 from quokka_tpu import config
 from quokka_tpu.ops import hashtable
-from quokka_tpu.ops.batch import DeviceBatch, NumCol, StrCol, gather_columns, key_limbs
+from quokka_tpu.ops.batch import (
+    DeviceBatch,
+    NumCol,
+    StrCol,
+    gather_columns,
+    key_limbs,
+    map_codes,
+    rank_table,
+)
 
 # ---------------------------------------------------------------------------
 # masking / compaction
@@ -95,22 +103,22 @@ def head(batch: DeviceBatch, k: int) -> DeviceBatch:
 # ---------------------------------------------------------------------------
 
 
-def sort_limbs(batch: DeviceBatch, cols: Sequence[str], descending=None) -> List[jax.Array]:
+def sort_limbs(batch: DeviceBatch, cols: Sequence[str], descending=None,
+               ranks=None) -> List[jax.Array]:
     """Limbs whose ascending lexicographic order == the requested column order.
-    Strings map codes -> dictionary-rank (host argsort of the dict), so string
-    sorts are true lexicographic sorts, not hash-order."""
+    Strings map codes -> dictionary-rank (StringDict.rank, a host argsort of
+    the dict), so string sorts are true lexicographic sorts, not hash-order.
+    ``ranks``: the rank tables as device arrays by column name, for a caller
+    inside a trace (ops/aggtail.py); else each is its dictionary's cached copy."""
     if descending is None:
         descending = [False] * len(cols)
     limbs: List[jax.Array] = []
     for name, desc in zip(cols, descending):
         c = batch.columns[name]
         if isinstance(c, StrCol):
-            order = np.argsort(c.dictionary.values.astype(str), kind="stable")
-            rank = np.empty(len(order), dtype=np.int32)
-            rank[order] = np.arange(len(order), dtype=np.int32)
-            limb = jnp.asarray(rank)[jnp.maximum(c.codes, 0)]
-            # nulls (code -1) sort first ascending (rank -1 < all real ranks)
-            limb = jnp.where(c.codes < 0, -1, limb)
+            rank = (ranks[name] if ranks is not None
+                    else rank_table(c.dictionary))
+            limb = map_codes(c.codes, rank)
             limbs.append(~limb if desc else limb)
         else:
             parts = []
@@ -119,12 +127,9 @@ def sort_limbs(batch: DeviceBatch, cols: Sequence[str], descending=None) -> List
             parts.append(c.data)
             for p in parts:
                 if desc:
-                    if jnp.issubdtype(p.dtype, jnp.floating):
-                        p = -p
-                    elif p.dtype == jnp.bool_:
-                        p = ~p
-                    else:
-                        p = ~p  # bitwise-not reverses signed-int order, no overflow
+                    # bitwise-not reverses signed-int (and bool) order with
+                    # no overflow
+                    p = -p if jnp.issubdtype(p.dtype, jnp.floating) else ~p
                 limbs.append(p)
     return limbs
 

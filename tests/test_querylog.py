@@ -39,7 +39,8 @@ RECORD_KEYS = {
     "offthread.reader.execute", "offthread.bridge.to_device",
     "offthread.emit.result_d2h", "offthread.spill.hbq", "offthread.other",
     "task_s", "tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
-    "compile_misses", "rows_in", "padded_in", "rows_unknown", "scan_hits",
+    "compile_misses", "rows_in", "padded_in", "rows_unknown",
+    "agg_merges_compiled", "agg_merges_general", "scan_hits",
     "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
 }
 IN_DISPATCH = ("runtime.dispatch_self", "executors.exec_self",
@@ -133,6 +134,29 @@ def test_one_flat_record_per_finished_query_and_nothing_pinned(paths):
         # copies: a caller cannot edit the log
         querylog.records()[0]["compiled"].append("x")
         assert "x" not in querylog.records()[0]["compiled"]
+
+
+def test_a_served_q1_folds_its_aggregates_in_compiled_programs(paths):
+    """Q1's partials are a few rows each: every merge of both aggregators
+    and the final tail take the compiled path (ops/aggtail.py), none the
+    op-by-op one; the record says so."""
+    q1 = (QuokkaContext().read_parquet(
+              paths["lineitem"],
+              columns=["l_returnflag", "l_linestatus", "l_quantity",
+                       "l_extendedprice", "l_discount", "l_shipdate"])
+          .filter_sql("l_shipdate <= date '1998-09-02'")
+          .groupby(["l_returnflag", "l_linestatus"],
+                   orderby=["l_returnflag", "l_linestatus"])
+          .agg_sql("sum(l_quantity) as sum_qty, "
+                   "sum(l_extendedprice * (1 - l_discount)) as sum_disc, "
+                   "avg(l_discount) as avg_disc, count(*) as n"))
+    with QueryService(pool_size=2) as svc:
+        df = svc.submit(q1).to_df(timeout=300)
+    assert list(df["l_returnflag"] + df["l_linestatus"]) == sorted(
+        df["l_returnflag"] + df["l_linestatus"]) and df["n"].sum() > 0
+    (rec,) = querylog.records()
+    # two partial channels, two final channels: four merges and two tails
+    assert rec["agg_merges_compiled"] >= 3 and rec["agg_merges_general"] == 0
 
 
 def test_self_times_partition_the_task_time(paths):
@@ -313,8 +337,9 @@ def test_explain_as_dict_has_a_pinned_shape(paths):
     assert snap["operators"]
     for op in snap["operators"]:
         assert always <= set(op)
-        # what is left are the executors' own row notes (join_build_rows...)
-        assert all(k.endswith("_rows")
+        # what is left are the executors' own notes: rows (join_build_rows
+        # ...) and the aggregators' merges by path (ops/aggtail.py)
+        assert all(k.endswith("_rows") or k.startswith("agg_merges_")
                    for k in set(op) - always - sometimes), sorted(op)
         assert op["padded_in"] >= op["rows_in"] >= 0
     for edge in snap["edges"]:
@@ -324,6 +349,8 @@ def test_explain_as_dict_has_a_pinned_shape(paths):
     (rec,) = [r for r in querylog.records() if r["q"] == snap["query_id"]]
     assert rec["rows_in"] == sum(o["rows_in"] for o in snap["operators"])
     assert rec["padded_in"] == sum(o["padded_in"] for o in snap["operators"])
+    assert rec["agg_merges_compiled"] == sum(
+        o.get("agg_merges_compiled", 0) for o in snap["operators"]) > 0
 
 
 def test_a_rehearsed_traced_run_reports_the_six_metrics(tmp_path):

@@ -205,3 +205,63 @@ def test_fused_q1_stage(chip, tmp_path, monkeypatch):
         return chip(dtype, shape)
 
     _compile(builder(), *jax.tree_util.tree_map(at_sf1, args))
+
+
+def test_agg_tail_programs(chip, monkeypatch):
+    """The aggregation tail's two programs (ops/aggtail.py) as the chip's
+    cells ask for them: Q1's merge of 8 x 256-row partials over two string
+    keys and eight sums, Q1's final tail (eight finals, ORDER BY two string
+    keys), and Q3's final tail over a 16,384-row state (ORDER BY a float
+    descending and a date, LIMIT 10), with the TPU's sort group-by."""
+    from quokka_tpu import sqlparse
+    from quokka_tpu.ops import aggtail, strategy
+    from quokka_tpu.ops.expr_compile import plan_aggregation
+
+    monkeypatch.setenv("QK_KERNEL_STRATEGY", "groupby=sort")
+    strategy.reset()
+    try:
+        parts, rows, sums = 8, 256, ("sum",) * 8
+        recombine = aggtail._build_recombine((True, True), sums, rows)
+        _compile(
+            recombine,
+            tuple((chip("int32", rows),) * parts for _ in range(2))
+            + tuple((chip(dt, rows),) * parts
+                    for dt in ("float32",) * 7 + ("int32",)),
+            ((),) * 10,
+            ((chip("int32", (parts, 4)), chip("int32", 4), chip("int32", 4)),
+             (chip("int32", (parts, 2)), chip("int32", 2), chip("int32", 2))),
+            (chip(bool, rows),) * parts)
+        plan = plan_aggregation(sqlparse.parse_select_list(
+            "sum(q) as sum_qty, sum(p) as sum_base, sum(p * (1 - d)) as sum_disc, "
+            "sum(p * (1 - d) * (1 + t)) as sum_charge, avg(q) as avg_qty, "
+            "avg(p) as avg_price, avg(d) as avg_disc, count(*) as n"))
+        num_meta = tuple(
+            (name, "int32" if op == "count" else "float32", False,
+             "i" if op == "count" else "f", None)
+            for name, op, _ in plan.partials)
+        keys = ["rf", "ls"]
+        out_names = keys + [n for n, _ in plan.finals]
+        q1 = aggtail._tail_body(num_meta, keys, plan.finals, None, out_names,
+                                keys, (False, False), keys, None, rows, [])
+        _compile(jax.jit(q1),
+                 tuple(chip(m[1], rows) for m in num_meta),
+                 (chip("int32", 0),) * len(num_meta),
+                 (chip("int32", rows),) * 2,
+                 (chip("int32", 4), chip("int32", 2)), chip(bool, rows))
+        plan3 = plan_aggregation(sqlparse.parse_select_list(
+            "sum(p * (1 - d)) as revenue"))
+        state = 1 << 14
+        meta3 = (("l_orderkey", "int32", False, "i", None),
+                 ("o_orderdate", "int32", False, "d", None),
+                 ("o_shippriority", "int32", False, "i", None),
+                 (plan3.partials[0][0], "float32", False, "f", None))
+        out3 = ["l_orderkey", "o_orderdate", "o_shippriority", "revenue"]
+        q3 = aggtail._tail_body(meta3, [], plan3.finals, None, out3,
+                                ["revenue", "o_orderdate"], (True, False),
+                                [], 10, 256, [])
+        _compile(jax.jit(q3),
+                 tuple(chip(m[1], state) for m in meta3),
+                 (chip("int32", 0),) * 4, (), (), chip(bool, state))
+    finally:
+        monkeypatch.undo()
+        strategy.reset()
