@@ -71,6 +71,10 @@ class InputArrowDataset:
         """Estimated source bytes (query-service admission control)."""
         return self.table.nbytes
 
+    def num_rows(self) -> int:
+        """Rows this scan can deliver at the most (a plan-time bound)."""
+        return self.table.num_rows
+
 
 class _Readahead:
     """One-segment scan readahead: while a channel's current batch executes,
@@ -235,6 +239,15 @@ class InputParquetDataset:
             except OSError:
                 continue
         return total
+
+    def num_rows(self) -> Optional[int]:
+        """Rows this scan can deliver at the most, from the files' footers
+        (row groups a predicate prunes still count: a plan-time bound)."""
+        try:
+            return sum(pq.ParquetFile(f).metadata.num_rows
+                       for f in _expand_paths(self.path))
+        except OSError:
+            return None
 
     def _dict_columns(self, f) -> List[str]:
         cached = getattr(self, "_dict_cols_cache", None)

@@ -18,7 +18,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from quokka_tpu import config
 from quokka_tpu.executors.base import Executor
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops import asof as asof_ops
 from quokka_tpu.ops import bridge, kernels, timewide
 from quokka_tpu.ops.batch import DeviceBatch, NumCol
@@ -143,28 +145,41 @@ class SortedAsofExecutor(Executor):
     """Streaming backward asof join.  Stream 0 = left/trades, stream 1 =
     right/quotes.  Trades are emitted once the quote watermark passes their
     timestamp; the quote buffer is pruned to the last quote per key below the
-    frontier plus everything above it."""
+    frontier plus everything above it.
 
-    # large streams flush in chunks of at least this many ready trades (the
-    # joint sort per flush covers the whole quote buffer)
-    MIN_FLUSH_ROWS = 1 << 19
+    Both sides live in a ``RowBuffer`` (ops/asof.py) whose capacity the plan
+    gives (``capacity``: a ladder rung over each source's row count, from
+    ``AsofJoinNode.lower``), appended in place part by part, and a flush
+    probes one chunk of one size.  So every program the executor asks for is
+    keyed on (capacity rung, part or chunk rung): how many batches a
+    dispatch held and how the two streams interleaved decide how MANY
+    flushes run, never their shapes."""
 
-    # prune the quote buffer only past this many padded rows: pruning costs
+    # large streams flush once this many trades are ready, in chunks of
+    # exactly this many slots (the last, short one under its mask): each
+    # flush's probe searches the whole quote buffer.  A probe costs by the
+    # slot, so a channel pays for half a chunk of padding on average; a
+    # flush costs a quote sort and a take besides.  On the chip 1 << 18
+    # answers ticks_1d 40 % faster than 1 << 19 (PERF.md section 6, PR 28)
+    MIN_FLUSH_ROWS = 1 << 18
+
+    # prune the quote buffer only past this many live rows: pruning costs
     # a full-buffer sort, so below the valve it is pure overhead — keeping
     # already-matched quotes around is semantically harmless for backward
     # asof (they simply lose to later quotes)
     PRUNE_ROWS = 1 << 23
 
-    # asof_probe="coalesced" (ops/strategy.py): on big streams, hold ready
-    # trades until at least this many accumulate so each flush's joint sort
-    # amortizes over one large probe instead of per-dispatch slivers.  Safe
-    # to hold: quotes arrive at/after the watermark that made these trades
-    # ready, so a later flush computes the identical matches.
+    # mid-size streams hold ready trades until at least this many
+    # accumulate so each flush's quote sort amortizes over one worthwhile
+    # probe instead of per-dispatch slivers.  Safe to hold: quotes arrive
+    # at/after the watermark that made these trades ready, so a later flush
+    # computes the identical matches.
     COALESCE_ROWS = 1 << 15
 
     def __init__(self, left_on: str, right_on: str, left_by, right_by,
                  suffix: str = "_2", keep_unmatched: bool = False,
-                 direction: str = "backward"):
+                 direction: str = "backward",
+                 capacity: Tuple[Optional[int], Optional[int]] = (None, None)):
         if direction not in ("backward", "forward"):
             raise ValueError(direction)
         self.direction = direction
@@ -174,27 +189,15 @@ class SortedAsofExecutor(Executor):
         self.right_by = list(right_by or [])
         self.suffix = suffix
         self.keep_unmatched = keep_unmatched
-        self.trades: Optional[DeviceBatch] = None
-        self.quotes: Optional[DeviceBatch] = None
-        # incoming batches buffer in LISTS; the quote buffer concats only
-        # when a flush actually runs a join (the flush-throttle gates pass
-        # on watermarks + running VALID counts first) — eager per-append
-        # concats of a growing buffer were the executor's top cost at scale
-        self._t_parts: List[DeviceBatch] = []
-        self._q_parts: List[DeviceBatch] = []
-        # running valid-row counts: gate decisions key on CONTENT (counts),
-        # never on padded lengths — padding is not preserved across
-        # checkpoint/restore, and a padded-length gate would flip emission
-        # decisions during tape replay (the engine asserts re_emitted ==
-        # emitted)
-        self._t_rows = 0
-        self._q_rows = 0
+        self.capacity = tuple(capacity)
+        self.trades: Optional[asof_ops.RowBuffer] = None
+        self.quotes: Optional[asof_ops.RowBuffer] = None
         self.q_watermark: Optional[float] = None
         self.t_watermark: Optional[float] = None
         self.q_done = False
         self.payload: Optional[List[str]] = None
         self.rename: Dict[str, str] = {}
-        # renamed view of the current quote buffer, cached by buffer
+        # renamed view of the current quote buffer, cached by the view's
         # identity: DeviceBatch.rename builds a NEW object, which would
         # discard the searchsorted strategy's cached quote sort
         # (ops/asof._ss_quote_sorted) on every flush even when no quotes
@@ -202,55 +205,48 @@ class SortedAsofExecutor(Executor):
         self._renamed_src: Optional[DeviceBatch] = None
         self._renamed: Optional[DeviceBatch] = None
 
-    def _materialize_trades(self) -> None:
-        if self._t_parts:
-            parts = ([self.trades] if self.trades is not None else []) + self._t_parts
-            self._t_parts = []
-            self.trades = (
-                bridge.concat_batches(parts) if len(parts) > 1 else parts[0]
-            )
+    # gate decisions key on CONTENT (the buffers' live counts), never on
+    # padded lengths or capacities — padding is not preserved across
+    # checkpoint/restore, and a padded-length gate would flip emission
+    # decisions during tape replay (the engine asserts re_emitted == emitted)
+    @property
+    def _t_rows(self) -> int:
+        return 0 if self.trades is None else self.trades.rows
 
-    def _materialize_quotes(self) -> None:
-        if self._q_parts:
-            parts = ([self.quotes] if self.quotes is not None else []) + self._q_parts
-            self._q_parts = []
-            self.quotes = (
-                bridge.concat_batches(parts) if len(parts) > 1 else parts[0]
-            )
+    @property
+    def _q_rows(self) -> int:
+        return 0 if self.quotes is None else self.quotes.rows
+
+    def _append(self, stream_id: int, part: DeviceBatch) -> None:
+        name = "quotes" if stream_id else "trades"
+        buf = getattr(self, name)
+        if buf is None:
+            cap = self.capacity[stream_id] or part.padded_len
+            # the quotes are the searched side: their key limbs ride along
+            buf = asof_ops.RowBuffer(part, config.bucket_size(cap),
+                                     key=self.right_by if stream_id else ())
+            setattr(self, name, buf)
+        buf.append(part)
 
     def execute(self, batches, stream_id, channel):
         from quokka_tpu.obs import opstats
-        from quokka_tpu.ops import strategy as kstrategy
 
         live = [b for b in batches if b is not None and b.count_valid() > 0]
-        if stream_id == 0:
-            mode = kstrategy.choice("asof_probe")
-            kstrategy.note_used("asof_probe", mode)
-            if mode == "coalesced" and len(live) > 1:
-                # the join probe's bucketed concat path: a dispatch's small
-                # per-partition slices merge cap-aware before buffering
-                from quokka_tpu.executors.sql_execs import _coalesce
-
-                live = _coalesce(live)
-        if stream_id == 1:
+        on = self.right_on if stream_id else self.left_on
+        with tracing.span("asof.append"):
             for b in live:
-                self._q_parts.append(b)
-                self._q_rows += b.count_valid()
-                wm = _time_max(b, self.right_on)
-                if self.q_watermark is None or wm > self.q_watermark:
-                    self.q_watermark = wm
-            # quote side is the asof's build analog (counts already host-
-            # resolved by the live filter above — no extra sync)
-            opstats.note(join_build_rows=sum(b.nrows for b in live))
-            return self._flush()
-        for b in live:
-            self._t_parts.append(b)
-            self._t_rows += b.count_valid()
-            wm = _time_max(b, self.left_on)
-            if self.t_watermark is None or wm > self.t_watermark:
-                self.t_watermark = wm
-        opstats.note(join_probe_rows=sum(
-            b.nrows if b.nrows is not None else b.padded_len for b in live))
+                self._append(stream_id, b)
+                wm = _time_max(b, on)
+                if stream_id:
+                    if self.q_watermark is None or wm > self.q_watermark:
+                        self.q_watermark = wm
+                elif self.t_watermark is None or wm > self.t_watermark:
+                    self.t_watermark = wm
+        # the quote side is the asof's build analog (counts already host-
+        # resolved by the live filter above — no extra sync)
+        rows = sum(b.nrows for b in live)
+        opstats.note(**{"join_build_rows" if stream_id
+                        else "join_probe_rows": rows})
         return self._flush()
 
     def source_done(self, stream_id, channel):
@@ -261,37 +257,47 @@ class SortedAsofExecutor(Executor):
 
     def done(self, channel):
         self.q_done = True
-        return self._flush(final=True)
+        out = self._flush(final=True)
+        # what was emitted shares nothing with the buffers: release them (and
+        # the quote sort cached on their view) now, not when a collector
+        # finds the finished query's graph
+        self.trades = self.quotes = self._renamed = self._renamed_src = None
+        return out
 
     def _setup_payload(self, probe_names):
         if self.payload is None:
-            payload = [c for c in self.quotes.names
+            payload = [c for c in self.quotes.columns
                        if c not in set(self.right_by) and c != self.right_on]
             self.rename = {c: c + self.suffix for c in payload if c in probe_names}
             self.payload = [self.rename.get(c, c) for c in payload]
 
     def _renamed_quotes(self) -> DeviceBatch:
         """The (possibly renamed) quote buffer to join against, one rename
-        per buffer object: repeated flushes of an unchanged buffer reuse
-        the same DeviceBatch, keeping its cached quote-side sort warm."""
+        per view of the buffer: repeated flushes of an unchanged buffer
+        reuse the same DeviceBatch, keeping its cached quote-side sort
+        warm."""
+        quotes = self.quotes.view()
         if not self.rename:
-            return self.quotes
-        if self._renamed_src is not self.quotes:
-            self._renamed_src = self.quotes
-            self._renamed = self.quotes.rename(self.rename)
+            return quotes
+        if self._renamed_src is not quotes:
+            self._renamed_src = quotes
+            self._renamed = quotes.rename(self.rename)
+            limbs = getattr(quotes, "_asof_key_limbs", None)
+            if limbs is not None:
+                self._renamed._asof_key_limbs = limbs
         return self._renamed
 
     def _flush(self, final: bool = False):
-        self._materialize_trades()
-        if self.trades is None or self.trades.count_valid() == 0:
+        """One chunk of ready trades joined and emitted (``final``: every
+        chunk that is left, as a list)."""
+        if not self._t_rows:
             return None
-        if self.quotes is None and not self._q_parts:
+        if not self._q_rows:
             if self.q_done:
-                out, self.trades = self.trades, None
+                out, self.trades = self.trades.view(), None
                 return out if self.keep_unmatched else None
             return None
         if self.direction == "forward":
-            self._materialize_quotes()
             return self._flush_forward()
         if self.q_done:
             safe = float("inf")
@@ -299,64 +305,76 @@ class SortedAsofExecutor(Executor):
             return None
         else:
             safe = self.q_watermark
-        tcol = self.trades.columns[self.left_on]
+        trades = self.trades
+        tcol = trades.columns[self.left_on]
         # strictly below the quote watermark: a future quote batch can still
         # contain quotes at exactly `safe` (ties must win per backward-asof)
         op = "<=" if safe == float("inf") else "<"
-        ready_mask = self.trades.valid & _cmp_time(tcol, safe, op)
+        ready_mask = trades.valid & _cmp_time(tcol, safe, op)
         nready = int(jnp.sum(ready_mask.astype(jnp.int32)))
         if nready == 0:
             return None
-        # each flush pays one joint sort of (ready + ENTIRE quote buffer) —
-        # at scale, emitting per event makes that quadratic-ish.  Large
-        # streams accumulate ready trades into big flushes; small streams
-        # (below the threshold) keep per-event emission.  Gates key on
-        # running VALID counts (content-deterministic across replay); the
-        # quote buffer has not been concatenated yet when they bail
+        # each flush searches the ENTIRE quote buffer (and sorts it, when
+        # quotes arrived since the last) — at scale, emitting per event
+        # makes that quadratic-ish.  Large streams accumulate ready trades
+        # into full chunks; small streams (below the threshold) keep
+        # per-event emission.  Gates key on live counts (content-
+        # deterministic across replay)
         big = self._t_rows + self._q_rows > 4 * self.MIN_FLUSH_ROWS
         if big and not self.q_done and nready < self.MIN_FLUSH_ROWS:
             return None
-        # asof_probe="coalesced": mid-size streams also hold sliver flushes
-        # until a worthwhile probe accumulates (each flush pays a joint sort
-        # over the whole quote buffer).  Content-identical output — quotes
-        # arriving after the hold are at/above the watermark that made these
-        # trades ready, so they can't change a held trade's match.  The gate
-        # keys on VALID counts only (deterministic under tape replay).
+        # mid-size streams also hold sliver flushes until a worthwhile
+        # probe accumulates.  Content-identical output — quotes arriving
+        # after the hold are at/above the watermark that made these trades
+        # ready, so they can't change a held trade's match
         if (
             not self.q_done
             and nready < self.COALESCE_ROWS
             and self._t_rows + self._q_rows > 2 * self.COALESCE_ROWS
         ):
-            from quokka_tpu.ops import strategy as kstrategy
-
-            if kstrategy.choice("asof_probe") == "coalesced":
-                return None
-        self._materialize_quotes()
-        ready = kernels.compact(kernels.apply_mask(self.trades, ready_mask))
-        if ready.count_valid() == 0:
             return None
-        rest = kernels.compact(kernels.apply_mask(self.trades, self.trades.valid & ~ready_mask))
-        self.trades = rest if rest.count_valid() > 0 else None
-        self._t_rows = 0 if self.trades is None else self.trades.count_valid()
-        self._setup_payload(ready.names)
-        quotes = self._renamed_quotes()
-        out = asof_ops.asof_join(
-            ready, quotes, self.left_on, self.right_on,
-            self.left_by, self.right_by, self.payload,
-        )
-        matched = out.columns.pop("__asof_matched__")
-        if not self.keep_unmatched:
-            out = kernels.apply_mask(out, matched.data)
+        # the chunk's slots follow the plan (the capacity), the rows taken
+        # follow the content: replay takes the same rows whatever the rung
+        slots = config.bucket_size(min(self.MIN_FLUSH_ROWS, trades.capacity))
+        outs = []
+        while nready:
+            n = min(nready, self.MIN_FLUSH_ROWS)
+            outs.append(self._join_chunk(ready_mask, n, slots))
+            nready -= n
+            if not final:
+                break
+            ready_mask = ready_mask & self.trades.valid
         # prune only below what BOTH streams have passed: future trades can
         # still arrive below the quote watermark when quotes run ahead —
         # and only past the memory valve (pruning costs a full-buffer sort;
         # the count-based gate keys on content, so replay reproduces it)
-        if self.quotes is not None and self._q_rows >= self.PRUNE_ROWS:
+        # — and only once no ready trade is left waiting for its chunk
+        if self._q_rows >= self.PRUNE_ROWS and not nready:
             prune_to = safe
             if self.t_watermark is not None:
                 prune_to = min(prune_to, self.t_watermark)
             self._prune_quotes(prune_to)
-            self._q_rows = 0 if self.quotes is None else self.quotes.count_valid()
+        return outs if final else outs[0]
+
+    def _join_chunk(self, ready_mask, n: int, slots: int) -> DeviceBatch:
+        from quokka_tpu.obs import opstats
+
+        with tracing.span("asof.emit"):
+            ready = self.trades.take(ready_mask, n, slots)
+            self._setup_payload(ready.names)
+            quotes = self._renamed_quotes()
+        with tracing.span("asof.match"):
+            out = asof_ops.asof_join(
+                ready, quotes, self.left_on, self.right_on,
+                self.left_by, self.right_by, self.payload,
+            )
+        opstats.note(asof_flushes=1, asof_probe_rows=n,
+                     asof_probe_padded=slots,
+                     asof_quote_padded=quotes.padded_len)
+        with tracing.span("asof.emit"):
+            matched = out.columns.pop("__asof_matched__")
+            if not self.keep_unmatched:
+                out = kernels.apply_mask(out, matched.data)
         return out
 
     def _flush_forward(self):
@@ -366,10 +384,11 @@ class SortedAsofExecutor(Executor):
         final (future quotes arrive later in time and can't beat the match).
         To keep the output time-ordered, matched trades are held back until no
         earlier trade remains unmatched."""
-        self._setup_payload(self.trades.names)
+        trades = self.trades.view()
+        self._setup_payload(trades.names)
         quotes = self._renamed_quotes()
         out = asof_ops.asof_join(
-            self.trades, quotes, self.left_on, self.right_on,
+            trades, quotes, self.left_on, self.right_on,
             self.left_by, self.right_by, self.payload, direction="forward",
         )
         matched = out.columns.pop("__asof_matched__").data
@@ -379,44 +398,36 @@ class SortedAsofExecutor(Executor):
             )
             self.trades = None
             self.quotes = None
-            self._t_rows = 0
-            self._q_rows = 0
             return result if result.count_valid() > 0 else None
-        tcol = self.trades.columns[self.left_on]
-        unmatched = self.trades.valid & ~matched
-        emit = self.trades.valid & matched
+        tcol = trades.columns[self.left_on]
+        unmatched = trades.valid & ~matched
+        emit = trades.valid & matched
         if bool(jnp.any(unmatched)):
-            cutoff = _time_min(self.trades, self.left_on, unmatched)
+            cutoff = _time_min(trades, self.left_on, unmatched)
             emit = emit & _cmp_time(tcol, cutoff, "<")
         result = kernels.compact(kernels.apply_mask(out, emit))
-        rest = kernels.compact(
-            kernels.apply_mask(self.trades, self.trades.valid & ~emit)
-        )
-        self.trades = rest if rest.count_valid() > 0 else None
-        self._t_rows = 0 if self.trades is None else self.trades.count_valid()
+        # compact() hands a full batch back as it is: over the buffer's own
+        # arrays, which the next append donates
+        result = self.trades.detach(result)
+        self.trades.keep(~emit)
         # prune quotes below every retained and every possible future trade —
         # forward matches need quote time >= trade time, so those can't match
         bound = self.t_watermark
-        if self.trades is not None:
-            tmin = _time_min(self.trades, self.left_on)
+        if self._t_rows:
+            tmin = _time_min(self.trades.view(), self.left_on)
             bound = tmin if bound is None else min(bound, tmin)
-        if bound is not None and self.quotes is not None:
-            q = self.quotes
-            keep = q.valid & _cmp_time(q.columns[self.right_on], bound, ">=")
-            pruned = kernels.compact(kernels.apply_mask(q, keep))
-            self.quotes = pruned if pruned.count_valid() > 0 else None
-            self._q_rows = 0 if self.quotes is None else self.quotes.count_valid()
+        if bound is not None:
+            self.quotes.keep(
+                _cmp_time(self.quotes.columns[self.right_on], bound, ">="))
         return result if result.count_valid() > 0 else None
 
     def _prune_quotes(self, safe):
         """Drop quotes no future trade can match: everything at/below the
         frontier except the latest quote per key.  Sort-based so it is exact
         for wide (two-limb) time columns — sort_batch keys are limb-aware."""
-        if self.quotes is None or safe == float("inf"):
-            if self.q_done:
-                self.quotes = None
-            return
-        q = self.quotes
+        if safe == float("inf"):
+            return  # the stream is over: the buffers go with the executor
+        q = self.quotes.view()
         qt = q.columns[self.right_on]
         above = q.valid & _cmp_time(qt, safe, ">")
         below = q.valid & ~above
@@ -439,7 +450,7 @@ class SortedAsofExecutor(Executor):
             # key, invalid, or above the frontier
             is_last_below = s_below & ~(next_key_same & next_below)
             keep_s = (s.valid & _cmp_time(st, safe, ">")) | is_last_below
-            pruned = kernels.compact(kernels.apply_mask(s, keep_s))
+            pruned = kernels.apply_mask(s, keep_s)
         else:
             if bool(jnp.any(below)):
                 maxt = _time_max(
@@ -451,29 +462,33 @@ class SortedAsofExecutor(Executor):
                 keep = above | (below & _cmp_time(qt, maxt, "="))
             else:
                 keep = above
-            pruned = kernels.compact(kernels.apply_mask(q, keep))
-        self.quotes = pruned if pruned.count_valid() > 0 else None
+            pruned = kernels.apply_mask(q, keep)
+        # the survivors restart the log, at the capacity it had
+        capacity, self.quotes = self.quotes.capacity, None
+        if pruned.count_valid() > 0:
+            self.quotes = asof_ops.RowBuffer(pruned, capacity,
+                                             key=self.right_by)
+            self.quotes.append(pruned)
 
     def checkpoint(self):
-        self._materialize_trades()  # fold pending parts into the buffers
-        self._materialize_quotes()
+        def table(buf):
+            return None if buf is None else bridge.device_to_arrow(buf.view())
+
         return {
-            "trades": None if self.trades is None else bridge.device_to_arrow(self.trades),
-            "quotes": None if self.quotes is None else bridge.device_to_arrow(self.quotes),
+            "trades": table(self.trades),
+            "quotes": table(self.quotes),
             "q_watermark": self.q_watermark,
             "t_watermark": self.t_watermark,
             "q_done": self.q_done,
         }
 
     def restore(self, state):
-        self._t_parts = []
-        self._q_parts = []
+        self.trades = self.quotes = None
         if state is None:
             return
-        self.trades = None if state["trades"] is None else bridge.arrow_to_device(state["trades"])
-        self.quotes = None if state["quotes"] is None else bridge.arrow_to_device(state["quotes"])
-        self._t_rows = 0 if self.trades is None else self.trades.count_valid()
-        self._q_rows = 0 if self.quotes is None else self.quotes.count_valid()
+        for stream_id, name in enumerate(("trades", "quotes")):
+            if state[name] is not None and state[name].num_rows:
+                self._append(stream_id, bridge.arrow_to_device(state[name]))
         self.q_watermark = state["q_watermark"]
         self.t_watermark = state.get("t_watermark")
         self.q_done = state["q_done"]

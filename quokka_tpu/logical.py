@@ -261,12 +261,15 @@ class StatefulNode(Node):
         self.sorted_by = sorted_output
 
     def lower(self, ctx, graph, actor_of, node_id):
+        self._lower_with(self.executor_factory, ctx, graph, actor_of, node_id)
+
+    def _lower_with(self, executor_factory, ctx, graph, actor_of, node_id):
         sources = {}
         for i, p in enumerate(self.parents):
             part = self.partitioners.get(i, PassThroughPartitioner())
             sources[i] = (actor_of[p], TargetInfo(part))
         actor_of[node_id] = graph.new_exec_node(
-            self.executor_factory,
+            executor_factory,
             sources,
             self.channels or ctx.exec_channels,
             self.stage,
@@ -305,6 +308,27 @@ class AsofJoinNode(StatefulNode):
         return list(parents[0]) + [
             c + self.suffix if c in set(parents[0]) else c for c in rpayload
         ]
+
+    def lower(self, ctx, graph, actor_of, node_id):
+        """The executor learns what the plan knows of its two sources: a
+        ladder rung over each reader's row count (an upper bound on what
+        any channel can be sent), or None where the parent is no reader or
+        the reader cannot say.  Its buffers take that capacity, so the
+        programs it asks for follow the plan (``SortedAsofExecutor``)."""
+        from quokka_tpu import config
+
+        def rung(parent):
+            fn = getattr(graph.actors[actor_of[parent]].reader, "num_rows",
+                         None)
+            rows = None if fn is None else fn()
+            if rows is None or rows > config.MAX_BUCKET:
+                return None  # unknown, or past the ladder: buffers double
+            return config.bucket_size(rows)
+
+        capacity = tuple(rung(p) for p in self.parents)
+        self._lower_with(
+            functools.partial(self.executor_factory, capacity=capacity),
+            ctx, graph, actor_of, node_id)
 
     def describe(self):
         return f"AsofJoin({self.direction} on {self.left_on})"

@@ -31,7 +31,11 @@ Record keys (README "Observability" documents each):
   ``compile_misses``, ``compiled``, ``rows_in``, ``padded_in``,
   ``rows_unknown``, ``agg_merges_compiled``, ``agg_merges_general`` (merges
   and final tails of the aggregators by the path they took:
-  ops/aggtail.py), ``scan_hits``, ``scan_misses``;
+  ops/aggtail.py), ``asof_flushes``, ``asof_probe_rows``,
+  ``asof_probe_padded``, ``asof_quote_padded`` (the streaming asof join's
+  chunk probes: how many, the trades they held, the slots they filled, and
+  the padded quote rows each searched, summed: executors/ts_execs.py),
+  ``scan_hits``, ``scan_misses``;
 - ``pool_size`` and ``park_s_total``, ``loop_s_total``: the service's
   worker threads, and their cumulative counters when the query finished.
 """
@@ -79,14 +83,16 @@ SECONDS = tuple(dict.fromkeys(
     + ["other.sync_block"] + list(OFFTHREAD) + ["task_s"]))
 COUNTS = ("tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
           "compile_misses", "rows_in", "padded_in", "rows_unknown",
-          "agg_merges_compiled", "agg_merges_general",
+          "agg_merges_compiled", "agg_merges_general", "asof_flushes",
+          "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
           "scan_hits", "scan_misses")
 KEYS = (("q", "plan_fp", "status") + STAMPS + SECONDS + COUNTS
         + ("compiled", "pool_size", "park_s_total", "loop_s_total"))
 
 # summed over the operators of the finish-time opstats snapshot
 _FROM_OPSTATS = ("rows_in", "padded_in", "rows_unknown",
-                 "agg_merges_compiled", "agg_merges_general")
+                 "agg_merges_compiled", "agg_merges_general", "asof_flushes",
+                 "asof_probe_rows", "asof_probe_padded", "asof_quote_padded")
 
 _lock = threading.Lock()
 _open: Dict[str, dict] = {}   # live queries' accumulators

@@ -35,7 +35,16 @@ import numpy as np
 
 from quokka_tpu import config
 from quokka_tpu.ops import kernels
-from quokka_tpu.ops.batch import DeviceBatch, NumCol, StrCol, key_limbs
+from quokka_tpu.ops.batch import (
+    DeviceBatch,
+    NumCol,
+    StrCol,
+    column_arrays,
+    flat_arrays,
+    key_limbs,
+    map_codes,
+    rebuild_columns,
+)
 from quokka_tpu.ops.kernels import dense_rank
 
 
@@ -177,7 +186,11 @@ def _ss_quote_sorted(quotes: DeviceBatch, right_on: str,
     key = (tuple(right_by), right_on, wide, str(time_dtype))
     hit = cache.get(key)
     if hit is None:
-        ql = key_limbs(quotes, list(right_by)) if right_by else []
+        carried = getattr(quotes, "_asof_key_limbs", None)
+        if carried is not None and carried[0] == tuple(right_by):
+            ql = list(carried[1])  # RowBuffer.view(): hashed part by part
+        else:
+            ql = key_limbs(quotes, list(right_by)) if right_by else []
         qc = quotes.columns[right_on]
         if wide:
             from quokka_tpu.ops import timewide
@@ -318,6 +331,244 @@ def _asof_match_host(trades, quotes, left_on, right_on, left_by, right_by,
     quote_idx[tidx[hit]] = qidx[res[hit]].astype(np.int32)
     matched[tidx[hit]] = True
     return quote_idx, matched
+
+
+# ---------------------------------------------------------------------------
+# Row buffers of a fixed capacity, appended in place.  The streaming asof
+# executor keeps its trades and its quotes in one each: every program that
+# touches a buffer is keyed on (capacity rung, part or chunk rung), so the
+# set of programs a plan asks for follows the plan and the data's row
+# counts, never which batches happened to arrive together.
+# ---------------------------------------------------------------------------
+
+
+def _row_mask(live: jax.Array, like: jax.Array) -> jax.Array:
+    return live if like.ndim == 1 else live[:, None]
+
+
+def _append_rows(bufs: Tuple[jax.Array, ...], buf_valid: jax.Array,
+                 parts: Tuple[jax.Array, ...], part_valid: jax.Array,
+                 tables: Tuple[jax.Array, ...], end: jax.Array):
+    """Write one part, every slot of it under its own mask, behind slot
+    ``end`` of the buffer: contiguous copies, no gather (a part the exchange
+    cut by a mask stays as sparse in the buffer as it came).  ``tables`` has
+    one entry per array: a remap table for a string column's codes (the
+    part's dictionary in the buffer's), else an empty array.  The caller
+    guarantees ``end`` + the part's length fits: a clamped start would
+    overwrite live rows."""
+    def write(buf, rows):
+        return lax.dynamic_update_slice(
+            buf, rows.astype(buf.dtype), (end,) + (0,) * (buf.ndim - 1))
+
+    return (tuple(write(buf, map_codes(part, table) if table.shape[0]
+                        else part)
+                  for buf, part, table in zip(bufs, parts, tables)),
+            write(buf_valid, part_valid))
+
+
+def _take_rows(arrays: Tuple[jax.Array, ...], valid: jax.Array,
+               ready: jax.Array, n: jax.Array, chunk: int):
+    """The first ``n`` rows of ``ready`` (a subset of ``valid``; ``n`` at
+    most ``chunk`` and at most its count) as a chunk of exactly ``chunk``
+    slots under its own mask, and the buffer's mask without them.  The rows
+    stay where they are: a buffer is a log."""
+    idx = jnp.nonzero(ready, size=chunk, fill_value=0)[0]
+    live = jnp.arange(chunk) < n
+    outs = tuple(
+        jnp.where(_row_mask(live, a), a[idx], jnp.zeros((), a.dtype))
+        for a in arrays)
+    taken = ready & (jnp.cumsum(ready.astype(jnp.int32)) <= n)
+    return outs, live, valid & ~taken
+
+
+@functools.lru_cache(maxsize=None)
+def append_kernel():
+    # built at first use, not at import.  The buffer's arrays are donated:
+    # the write happens in place, and a queue of appends holds one buffer,
+    # not one copy of it per part in flight
+    return jax.jit(_append_rows, donate_argnums=(0, 1))
+
+
+@functools.lru_cache(maxsize=None)
+def take_kernel():
+    return jax.jit(_take_rows, static_argnames=("chunk",))
+
+
+class RowBuffer:
+    """Device rows of one schema in arrays of a fixed capacity (a ladder
+    rung): ``append`` writes a part, slot for slot under its mask, behind
+    the last slot ever written, ``take`` hands out a chunk and masks its
+    rows out.  Rows never move, so arrival order is row order (the asof
+    tie-break relies on it).  ``rows`` (live) and ``end`` (slots written)
+    are host counts.  A buffer that cannot hold a part closes its holes,
+    then doubles: the path of a source whose row count the plan did not
+    know, or whose parts are sparser than one slot in two.
+
+    ``key``: the join's key columns of a searched buffer.  Where one is a
+    string column, each part's key limbs (its rows' value hashes) are
+    appended beside the columns, and ``view()`` carries them
+    (``_asof_key_limbs``): a flush then sorts the buffer without hashing
+    its whole capacity again."""
+
+    def __init__(self, like: DeviceBatch, capacity: int,
+                 key: Sequence[str] = ()):
+        self.capacity = capacity
+        self.key = tuple(key) if any(
+            isinstance(like.columns[k], StrCol) for k in key) else ()
+        self.limbs: List[jax.Array] = []  # made by the first append
+        self.columns = rebuild_columns(like.columns, [
+            jnp.zeros((capacity,) + a.shape[1:], a.dtype)
+            for a in flat_arrays(like.columns)])
+        self.valid = jnp.zeros(capacity, dtype=bool)
+        self.sorted_by = like.sorted_by
+        self.rows = 0
+        self.end = 0
+        self._view: "DeviceBatch | None" = None
+        self._dict_index: dict = {}  # column -> {value: code} of its dict
+
+    def view(self) -> DeviceBatch:
+        """The buffer as a batch: one object until the buffer next changes,
+        so what is cached on it (the quote sort) lives as long as it holds."""
+        if self._view is None:
+            self._view = DeviceBatch(dict(self.columns), self.valid,
+                                     self.rows, self.sorted_by)
+            if self.key:
+                self._view._asof_key_limbs = (self.key, tuple(self.limbs))
+        return self._view
+
+    def _arrays(self) -> List[jax.Array]:
+        return flat_arrays(self.columns) + self.limbs
+
+    def _set(self, arrays, valid, rows: int) -> None:
+        arrays = list(arrays)
+        n = len(arrays) - len(self.limbs)
+        self.columns = rebuild_columns(self.columns, arrays[:n])
+        self.limbs = arrays[n:]
+        self._mask(valid, rows)
+
+    def _mask(self, valid, rows: int) -> None:
+        self.valid = valid
+        self.rows = rows
+        self._view = None
+
+    def _remap_table(self, name: str, part: StrCol) -> np.ndarray:
+        """The part's codes as codes of the buffer's dictionary, which grows
+        at its end only: codes already written keep their meaning."""
+        from quokka_tpu.ops.batch import StringDict, pad_table
+        from quokka_tpu.ops.sigkey import pow2_dim
+
+        cur = self.columns[name]
+        if part.dictionary is cur.dictionary:
+            return np.zeros(0, dtype=np.int32)  # the same codes: no remap
+        size = pow2_dim(len(part.dictionary))
+        index = self._dict_index.get(name)
+        if index is None:
+            index = self._dict_index[name] = {
+                v: i for i, v in enumerate(cur.dictionary.values)}
+        grown = len(index)
+        remap = np.fromiter(
+            (index.setdefault(v, len(index)) for v in part.dictionary.values),
+            dtype=np.int32, count=len(part.dictionary))
+        if len(index) > grown:
+            values = np.empty(len(index), dtype=object)
+            for v, i in index.items():
+                values[i] = v
+            self.columns[name] = StrCol(cur.codes, StringDict(
+                values, binary=cur.dictionary.binary or part.dictionary.binary))
+            self._view = None
+        elif (remap == np.arange(len(remap))).all():
+            return np.zeros(0, dtype=np.int32)  # the same values in order
+        return pad_table(remap, size)
+
+    def _align(self, part: DeviceBatch):
+        """The part's columns in the buffer's order, limb layout and dtypes
+        (the buffer widens, eagerly, where a later part is wider)."""
+        from quokka_tpu.ops import bridge
+
+        cols = {}
+        for name, b in self.columns.items():
+            c = part.columns[name]
+            if isinstance(b, NumCol):
+                b2, c = bridge._align_limbs([b, c])
+                dtype = jnp.promote_types(b2.data.dtype, c.data.dtype)
+                if b2 is not b or dtype != b.data.dtype:
+                    self.columns[name] = NumCol(
+                        b2.data.astype(dtype), b2.kind, hi=b2.hi, unit=b2.unit)
+                    self._view = None
+            cols[name] = c
+        return cols
+
+    def _make_room(self, slots: int) -> None:
+        if self.rows < self.end:  # dead slots: close them first
+            idx = jnp.nonzero(self.valid, size=self.capacity, fill_value=0)[0]
+            live = jnp.arange(self.capacity) < self.rows
+            self._set([jnp.where(_row_mask(live, a), a[idx],
+                                 jnp.zeros((), a.dtype))
+                       for a in self._arrays()], live, self.rows)
+            self.end = self.rows
+        if self.end + slots > self.capacity:
+            cap = config.bucket_size(max(2 * self.capacity,
+                                         self.end + slots))
+            grow = cap - self.capacity
+            self._set([jnp.pad(a, ((0, grow),) + ((0, 0),) * (a.ndim - 1))
+                       for a in self._arrays()],
+                      jnp.pad(self.valid, (0, grow)), self.rows)
+            self.capacity = cap
+
+    def append(self, part: DeviceBatch) -> None:
+        from quokka_tpu.runtime import compileplane
+
+        limbs = key_limbs(part, list(self.key)) if self.key else []
+        if not self.limbs:
+            self.limbs = [jnp.zeros(self.capacity, l.dtype) for l in limbs]
+        if self.end + part.padded_len > self.capacity:
+            self._make_room(part.padded_len)
+        empty = np.zeros(0, dtype=np.int32)
+        parts, tables = [], []
+        for name, c in self._align(part).items():
+            arrays = column_arrays(c)
+            parts += arrays
+            tables += ([self._remap_table(name, c)] if isinstance(c, StrCol)
+                       else [empty] * len(arrays))
+        arrays, valid = compileplane.aot_kernel_call(
+            "asof_write", append_kernel(),
+            (tuple(self._arrays()), self.valid, tuple(parts + limbs),
+             part.valid, tuple(tables + [empty] * len(limbs)),
+             np.int32(self.end)))
+        self._set(arrays, valid, self.rows + part.count_valid())
+        self.end += part.padded_len
+
+    def take(self, ready: jax.Array, n: int, chunk: int) -> DeviceBatch:
+        """The first ``n`` rows of ``ready`` (at most ``chunk``) as a batch
+        of ``chunk`` slots; they leave the buffer."""
+        from quokka_tpu.runtime import compileplane
+
+        arrays, live, valid = compileplane.aot_kernel_call(
+            "asof_take", take_kernel(),
+            (tuple(flat_arrays(self.columns)), self.valid, ready,
+             np.int32(n)), (chunk,))
+        self._mask(valid, self.rows - n)
+        return DeviceBatch(rebuild_columns(self.columns, arrays), live, n,
+                           self.sorted_by)
+
+    def detach(self, batch: DeviceBatch) -> DeviceBatch:
+        """``batch`` with copies of whatever arrays it shares with the
+        buffer (a batch cut from ``view()`` without a gather): the next
+        append donates the buffer's arrays, and a batch already emitted
+        must outlive that."""
+        mine = {id(a) for a in self._arrays()}
+        arrays = flat_arrays(batch.columns)
+        if not any(id(a) in mine for a in arrays):
+            return batch
+        return DeviceBatch(
+            rebuild_columns(batch.columns, [
+                jnp.copy(a) if id(a) in mine else a for a in arrays]),
+            batch.valid, batch.nrows, batch.sorted_by, batch.nrows_dev)
+
+    def keep(self, mask: jax.Array) -> None:
+        """Drop the live rows outside ``mask`` (one blocking count)."""
+        valid = self.valid & mask
+        self._mask(valid, int(jnp.sum(valid.astype(jnp.int32))))
 
 
 def asof_join(

@@ -446,32 +446,41 @@ def _gather_all(arrays, idx):
     return tuple(a[idx] for a in arrays)
 
 
-def gather_columns(columns: Dict[str, "Column"], idx: jax.Array) -> Dict[str, "Column"]:
-    """Row-gather a whole column dict through a single fused XLA program."""
-    arrays: List[jax.Array] = []
-    for c in columns.values():
-        if isinstance(c, StrCol):
-            arrays.append(c.codes)
-        elif isinstance(c, VecCol):
-            arrays.append(c.data)
-        else:
-            if c.hi is not None:
-                arrays.append(c.hi)
-            arrays.append(c.data)
-    from quokka_tpu.runtime import compileplane
+def column_arrays(col: "Column") -> List[jax.Array]:
+    """A column's device arrays: string codes, vector data, a wide
+    column's hi limb before its lo."""
+    if isinstance(col, StrCol):
+        return [col.codes]
+    if isinstance(col, VecCol) or col.hi is None:
+        return [col.data]
+    return [col.hi, col.data]
 
-    gathered = iter(compileplane.aot_kernel_call(
-        "gather", _gather_all, (tuple(arrays), idx)))
+
+def flat_arrays(columns: Dict[str, "Column"]) -> List[jax.Array]:
+    return [a for c in columns.values() for a in column_arrays(c)]
+
+
+def rebuild_columns(columns: Dict[str, "Column"], arrays) -> Dict[str, "Column"]:
+    """Inverse of ``flat_arrays``: the same columns over new arrays."""
+    it = iter(arrays)
     out: Dict[str, Column] = {}
     for n, c in columns.items():
         if isinstance(c, StrCol):
-            out[n] = StrCol(next(gathered), c.dictionary)
+            out[n] = StrCol(next(it), c.dictionary)
         elif isinstance(c, VecCol):
-            out[n] = VecCol(next(gathered))
+            out[n] = VecCol(next(it))
         else:
-            hi = next(gathered) if c.hi is not None else None
-            out[n] = NumCol(next(gathered), c.kind, hi=hi, unit=c.unit)
+            hi = next(it) if c.hi is not None else None
+            out[n] = NumCol(next(it), c.kind, hi=hi, unit=c.unit)
     return out
+
+
+def gather_columns(columns: Dict[str, "Column"], idx: jax.Array) -> Dict[str, "Column"]:
+    """Row-gather a whole column dict through a single fused XLA program."""
+    from quokka_tpu.runtime import compileplane
+
+    return rebuild_columns(columns, compileplane.aot_kernel_call(
+        "gather", _gather_all, (tuple(flat_arrays(columns)), idx)))
 
 
 def key_limbs(batch: DeviceBatch, cols: Sequence[str]) -> List[jax.Array]:

@@ -39,10 +39,6 @@ Operators and choices:
                                     (native O(n+m) host merge vs the
                                      concat+sort+scan device kernel vs the
                                      cached-quote-sort device binary search)
-  asof_probe  eager | coalesced     (per-dispatch asof flushes vs probe-side
-                                     trade batches coalesced through the
-                                     cap-aware _coalesce bucketed path so
-                                     each flush's joint sort amortizes)
   shuffle     masked | compacted    (kernels.split_by_partition modes)
 
 This module and config.py are the ONLY places allowed to probe the platform
@@ -64,7 +60,6 @@ OPS: Dict[str, Tuple[str, ...]] = {
     "groupby": ("sort", "hashtable"),
     "join_build": ("sort", "hashtable"),
     "asof": ("host", "sort", "searchsorted"),
-    "asof_probe": ("eager", "coalesced"),
     "shuffle": ("masked", "compacted"),
 }
 
@@ -76,19 +71,16 @@ OPS: Dict[str, Tuple[str, ...]] = {
 # merge so the benched path needs no host round trip.
 _PLATFORM_DEFAULTS: Dict[str, Dict[str, str]] = {
     "cpu": {"groupby": "hashtable", "join_build": "hashtable",
-            "asof": "host", "asof_probe": "coalesced", "shuffle": "masked"},
+            "asof": "host", "shuffle": "masked"},
     "gpu": {"groupby": "hashtable", "join_build": "hashtable",
-            "asof": "searchsorted", "asof_probe": "coalesced",
-            "shuffle": "masked"},
+            "asof": "searchsorted", "shuffle": "masked"},
     "tpu": {"groupby": "sort", "join_build": "sort",
-            "asof": "searchsorted", "asof_probe": "coalesced",
-            "shuffle": "masked"},
+            "asof": "searchsorted", "shuffle": "masked"},
 }
 _PLATFORM_DEFAULTS["cuda"] = _PLATFORM_DEFAULTS["gpu"]
 _PLATFORM_DEFAULTS["rocm"] = _PLATFORM_DEFAULTS["gpu"]
 _FALLBACK_DEFAULTS = {"groupby": "sort", "join_build": "sort",
-                      "asof": "sort", "asof_probe": "coalesced",
-                      "shuffle": "masked"}
+                      "asof": "sort", "shuffle": "masked"}
 
 _CALIB_VERSION = 1
 
@@ -485,10 +477,6 @@ def calibrate(rows: Optional[int] = None, reps: int = 3,
         "compacted": _time_best(lambda: _shuffle(True), reps),
     }
 
-    # asof_probe is likewise never calibrated: the eager/coalesced tradeoff
-    # is the asof executor's flush cadence under a live stream, which a
-    # standalone kernel microbench cannot observe — the coalesced default
-    # stands, QK_KERNEL_STRATEGY=asof_probe=eager remains for experiments.
     picks: Dict[str, str] = {}
     for op, t in timings.items():
         if t and op != "shuffle":

@@ -77,11 +77,10 @@ class TestCalibrationPersistence:
         monkeypatch.setenv("QK_STRATEGY_DIR", str(tmp_path))
         strategy.reset()
         res = strategy.calibrate(rows=2048, reps=1)
-        # shuffle and asof_probe are never picked by calibration (pipeline
-        # properties, not kernel walls — see calibrate(); shuffle is still
-        # timed for the profile's information)
-        assert set(res["choices"]) == set(strategy.OPS) - {"shuffle",
-                                                           "asof_probe"}
+        # shuffle is never picked by calibration (a pipeline property, not
+        # a kernel wall — see calibrate(); it is still timed for the
+        # profile's information)
+        assert set(res["choices"]) == set(strategy.OPS) - {"shuffle"}
         for op, ch in res["choices"].items():
             assert ch in strategy.OPS[op]
         assert res["timings_s"]["shuffle"].keys() == {"masked", "compacted"}

@@ -114,17 +114,25 @@ def test_join_sorted_build_and_probe(chip):
 
 
 def test_asof_searchsorted(chip):
-    """The asof kernels the TPU takes (the CPU default is the host merge):
-    quotes sorted by (symbol code, wide time hi, lo) at the 4M bucket a
-    channel's share of 6M quotes lands in, probed by a trade batch."""
+    """The asof kernels the TPU takes (the CPU default is the host merge)
+    at the shapes of ``ticks_1d``: a channel's quote buffer at the 8M rung
+    over the table's 6.0M rows, sorted by (symbol hash hi, lo, time) and
+    probed by one chunk of 1<<18 trades; and the program that appends one
+    1<<20-row part (time, symbol codes through a remap table, bid, the
+    symbol's two hash limbs) to it."""
     from quokka_tpu.ops import asof
 
-    q = 4 * N
+    q, chunk = 8 * N, N >> 2
     ops = (chip("int32", q),) * 3
     _compile(asof._ss_sort_quotes, ops, chip(bool, q))
     _compile(asof._ss_probe, ops, chip("int32", q), chip("int32", ()),
-             (chip("int32"),) * 3, chip(bool),
-             steps=q.bit_length(), upper=True, nkey=1)
+             (chip("int32", chunk),) * 3, chip(bool, chunk),
+             steps=q.bit_length(), upper=True, nkey=2)
+    cols = ("int32", "int32", "float32", "int32", "int32")
+    _compile(asof.append_kernel(), tuple(chip(d, q) for d in cols),
+             chip(bool, q), tuple(chip(d) for d in cols), chip(bool),
+             tuple(chip("int32", 128 if i == 1 else 0) for i in range(5)),
+             chip("int32", ()))
 
 
 def test_pack_decode(chip):
