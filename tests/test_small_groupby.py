@@ -1,14 +1,21 @@
-"""Small-key one-hot-matmul group-by fast path (ops/fuse.py FusedPartialAgg)
-vs the general sort+segment path: identical results on nulls-in-keys, empty
-batches, single groups, and high-cardinality fallback."""
+"""Small-key group-by fast path (ops/fuse.py FusedPartialAgg) vs the general
+sort+segment path: identical results on nulls-in-keys, empty batches, single
+groups, and high-cardinality fallback.  Every case of TestSmallGroupby runs
+under both of the path's builders: the scatter one (the CPU's default
+group-by strategy) and the one-hot one the chip runs (strategy ``sort``:
+float sums and count(*) through one matmul, integer sums through a masked
+reduction over the same one-hot)."""
 
+import jax
 import numpy as np
 import pandas as pd
 import pyarrow as pa
 import pytest
 
 from quokka_tpu import QuokkaContext
-from quokka_tpu.ops import fuse
+from quokka_tpu.ops import bridge, fuse, kernels
+from quokka_tpu.ops.expr_compile import evaluate_predicate, plan_aggregation
+from quokka_tpu.sqlparse import parse_expression, parse_select_list
 
 
 def run_agg(t, keys, aggs="sum(v) as sv, count(*) as n, count(v) as nv, avg(v) as av"):
@@ -50,8 +57,44 @@ class TestSmallGroupby:
             }
         )
 
+    @pytest.fixture(autouse=True, params=["default", "sort"])
+    def strategy(self, request, monkeypatch):
+        """The group-by strategy of the case, and a record of every dispatch
+        that took the small-key path (its ``use_tables``: True = the scatter
+        builder, False = the one-hot builder, the only one ``sort`` runs)."""
+        if request.param == "sort":
+            monkeypatch.setenv("QK_KERNEL_STRATEGY", "groupby=sort")
+        else:
+            monkeypatch.delenv("QK_KERNEL_STRATEGY", raising=False)
+        self.small_calls = []
+        call_small = fuse.FusedPartialAgg._call_small
+
+        def recording(agg, batch, pre, pre_exprs, num_inputs, dims, use_tables):
+            self.small_calls.append(use_tables)
+            return call_small(agg, batch, pre, pre_exprs, num_inputs, dims,
+                              use_tables)
+
+        monkeypatch.setattr(fuse.FusedPartialAgg, "_call_small", recording)
+        yield
+        if request.param == "sort":
+            assert not any(self.small_calls)
+
     def _small_used(self):
-        return any(k[0] == "partial_agg_small" for k in fuse._FUSED_PROGRAMS)
+        return bool(self.small_calls)
+
+    def _partial(self, t, keys, aggs, where=None, x64=True):
+        """One batch straight through FusedPartialAgg in this thread (so that
+        ``x64=False`` runs it in the chip's dtypes): the partial columns
+        ``__agg_<i>`` by key, sorted."""
+        with jax.enable_x64(x64):
+            plan = plan_aggregation(parse_select_list(aggs))
+            batch = bridge.arrow_to_device(t)
+            if where is not None:
+                batch = kernels.apply_mask(
+                    batch, evaluate_predicate(parse_expression(where), batch))
+            out = fuse.FusedPartialAgg(keys, plan)(batch)
+            got = bridge.device_to_arrow(out).to_pandas()
+        return got.sort_values(keys).reset_index(drop=True)
 
     def test_matches_oracle_with_null_values(self):
         t = self._table()
@@ -126,8 +169,96 @@ class TestSmallGroupby:
         t = pa.table({"flag": k1, "v": r.uniform(0, 10, n).round(3)})
         got = run_agg(t, ["flag"])
         exp = oracle(t, ["flag"])
+        assert not self.small_calls
         np.testing.assert_allclose(got.sv.to_numpy(), exp.sv.to_numpy(), rtol=1e-9)
         assert got.n.tolist() == exp.n.tolist()
+
+    def test_count_and_avg_skip_null_integers(self):
+        """count(v), avg(v) and sum(v) of a nullable integer column: three
+        integer partials (sum(__nn0(v)), sum(__nncount(v)) twice over)."""
+        r = np.random.default_rng(3)
+        n = 20000
+        v = pa.array(r.integers(-50, 1000, n), mask=r.random(n) < 0.2)
+        t = pa.table({
+            "flag": np.array(["A", "B", "C"])[r.integers(0, 3, n)],
+            "status": np.array(["X", "Y"])[r.integers(0, 2, n)],
+            "v": v,
+        })
+        got = run_agg(t, ["flag", "status"])
+        exp = oracle(t, ["flag", "status"])
+        assert self._small_used()
+        assert got.sv.astype("int64").tolist() == exp.sv.astype("int64").tolist()
+        assert got.n.tolist() == exp.n.tolist()
+        assert got.nv.tolist() == exp.nv.tolist()
+        assert (exp.nv < exp.n).all()
+        np.testing.assert_allclose(got.av.to_numpy(), exp.av.to_numpy(), rtol=1e-12)
+
+    @pytest.mark.parametrize("lo,hi", [(0, 5000), (-5000, 1000)],
+                             ids=["past_2_24", "negative"])
+    def test_integer_sum_exact_in_the_chips_dtypes(self, lo, hi):
+        """x64 off, as on the chip: an int32 sum whose group totals pass 2^24
+        (a float32 detour would lose the low bits) and one below zero."""
+        r = np.random.default_rng(4)
+        n = 30000
+        t = pa.table({
+            "flag": np.array(["A", "B"])[r.integers(0, 2, n)],
+            "q": r.integers(lo, hi, n) | 1,  # odd totals need every bit
+        })
+        got = self._partial(t, ["flag"], "sum(q) as sq, count(*) as n",
+                            x64=False)
+        exp = t.to_pandas().groupby("flag").agg(
+            sq=("q", "sum"), n=("q", "size")).reset_index()
+        assert self._small_used()
+        assert str(got["__agg_0"].dtype) == "int32"
+        assert (exp.sq.abs() > 1 << 24).all()
+        assert got["__agg_0"].tolist() == exp.sq.tolist()
+        assert got["__agg_1"].tolist() == exp.n.tolist()
+
+    def test_masked_and_padded_rows_fall_in_the_dump_bucket(self):
+        """Rows a filter masked out and the bucket's padding contribute to
+        no group, whatever their values; null keys are a group of their own."""
+        r = np.random.default_rng(5)
+        n = 5000  # padded to 8192: 3192 padding rows
+        flag = np.array(["A", "B", "C"], dtype=object)[r.integers(0, 3, n)]
+        flag[r.random(n) < 0.1] = None
+        t = pa.table({
+            "flag": pa.array(flag, type=pa.string()),
+            "q": r.integers(1, 1 << 20, n),
+        })
+        got = self._partial(t, ["flag"], "sum(q) as sq, count(q) as nq, "
+                            "count(*) as n", where="q > 500000")
+        d = t.to_pandas()
+        exp = (d[d.q > 500000].groupby("flag", dropna=False)
+               .agg(sq=("q", "sum"), n=("q", "size")).reset_index()
+               .sort_values("flag").reset_index(drop=True))
+        assert self._small_used()
+        assert len(got) == len(exp) == 4 and got.flag.isna().sum() == 1
+        # the nn0 sum, the nncount sum and count(*); both sorts put the
+        # null group last
+        assert got["__agg_0"].tolist() == exp.sq.tolist()
+        assert got["__agg_1"].tolist() == exp.n.tolist()
+        assert got["__agg_2"].tolist() == exp.n.tolist()
+
+    @pytest.mark.parametrize("distinct,small", [(127, True), (128, False)])
+    def test_dictionary_at_the_bucket_gate(self, distinct, small):
+        """127 values + the null slot = 128 buckets + the dump bucket: the
+        widest one-hot the path admits; one value more doubles the canonical
+        dim and the general path takes the batch."""
+        assert fuse._SMALL_GROUPBY_MAX_BUCKETS == 256
+        r = np.random.default_rng(6)
+        n = 20000
+        t = pa.table({
+            "flag": np.array([f"k{i:03d}" for i in range(distinct)])[
+                r.permutation(np.arange(n) % distinct)],
+            "q": r.integers(-1000, 1000, n),
+        })
+        got = self._partial(t, ["flag"], "sum(q) as sq, count(*) as n")
+        exp = (t.to_pandas().groupby("flag")
+               .agg(sq=("q", "sum"), n=("q", "size")).reset_index())
+        assert self._small_used() == small
+        assert len(got) == distinct
+        assert got["__agg_0"].tolist() == exp.sq.tolist()
+        assert got["__agg_1"].tolist() == exp.n.tolist()
 
 
 class TestAdaptivePartialAgg:

@@ -73,6 +73,7 @@ def _compile(jit_fn, *args, **static):
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes)
     assert total < 16 << 30, f"{total} bytes do not fit one v5e chip"
+    return compiled
 
 
 def test_compact_and_split(chip):
@@ -163,7 +164,11 @@ def test_pack_decode(chip):
 def test_fused_q1_stage(chip, tmp_path, monkeypatch):
     """The whole-stage Q1 partial aggregate (ops/fuse.py small-key path,
     one one-hot matmul on the MXU): the engine builds it for a toy Q1 here,
-    and the SAME builder is lowered at the SF1 bucket in the chip's dtypes."""
+    and the SAME builder is lowered at the SF1 bucket in the chip's dtypes.
+    The plan holds an integer partial (avg's sum(__nncount(x))): the
+    compiled module must reduce it over the one-hot and hold no scatter
+    (three scatter-adds were 98 % of this program's 27.5 ms a batch on the
+    chip), without writing the rows x buckets integer select out."""
     from quokka_tpu import QuokkaContext
     from quokka_tpu.runtime import compileplane
 
@@ -177,6 +182,9 @@ def test_fused_q1_stage(chip, tmp_path, monkeypatch):
 
     monkeypatch.setattr(compileplane, "acquire", recording_acquire)
     monkeypatch.setenv("QUOKKA_AOT_CACHE_DIR", str(tmp_path / "aot"))
+    # the TPU's group-by strategy: under the CPU's default (hashtable) the
+    # engine would hand over _build_small_scatter, which no chip runs
+    monkeypatch.setenv("QK_KERNEL_STRATEGY", "groupby=sort")
     # a fresh program store: a program another test already installed would
     # never reach acquire
     monkeypatch.setattr(compileplane, "PROGRAMS", {})
@@ -200,7 +208,7 @@ def test_fused_q1_stage(chip, tmp_path, monkeypatch):
                     "as charge, avg(l_discount) as d, count(*) as n")
            .collect())
     assert len(got) == 6 and captured, (got, list(captured))
-    builder, args = next(iter(captured.values()))
+    key, (builder, args) = next(iter(captured.items()))
 
     rows = args[-1].shape[0]  # the toy batch's padded length (valid mask)
 
@@ -212,7 +220,15 @@ def test_fused_q1_stage(chip, tmp_path, monkeypatch):
             str(a.dtype), str(a.dtype))
         return chip(dtype, shape)
 
-    _compile(builder(), *jax.tree_util.tree_map(at_sf1, args))
+    *_, pre_exprs, _partials, use_tables, form = key
+    assert (use_tables, form) == (False, "onehot_reduce"), key
+    assert any("__nncount" in sql for _, sql in pre_exprs), key
+    compiled = _compile(builder(), *jax.tree_util.tree_map(at_sf1, args))
+    assert "scatter" not in compiled.as_text()
+    # the scatter form read 0 bytes of HBM temporaries here (the row arrays
+    # and the pred[rows, 17] one-hot live in the compiler's fast memory);
+    # a written-out s32[rows, 16] select would be 64 MiB
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
 
 
 def test_agg_tail_programs(chip, monkeypatch):
