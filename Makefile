@@ -4,7 +4,7 @@
 PY ?= python
 export JAX_PLATFORMS ?= cpu
 
-.PHONY: lint lint-baseline verify-static plan-fuzz test test-slow sanitize-demo service-smoke chaos-smoke obs-smoke bench-check bench-trend shuffle-smoke fusion-smoke warmup-smoke multichip-smoke stream-smoke mem-smoke explain-smoke health-smoke adapt-smoke resume-smoke durability-smoke devprof-smoke chip-smoke-rehearse verify
+.PHONY: lint lint-baseline verify-static plan-fuzz test test-slow sanitize-demo chaos-smoke obs-smoke shuffle-smoke fusion-smoke warmup-smoke stream-smoke mem-smoke explain-smoke health-smoke adapt-smoke resume-smoke durability-smoke chip-smoke-rehearse verify
 
 # engine-invariant static analysis; exits nonzero on findings beyond the
 # checked-in baseline (quokka_tpu/analysis/baseline.json)
@@ -56,26 +56,11 @@ sanitize-demo:
 stall-demo:
 	QK_COORD_TIMEOUT=20 $(PY) tests/sanitize_deadlock_case.py
 
-# query-service smoke: tiny-SF TPC-H queries submitted 2-way through a
-# persistent QueryService; exits nonzero if the concurrent run wedges, a
-# query fails, or a result comes back empty
-service-smoke:
-	QUOKKA_BENCH_SF=0.01 QUOKKA_BENCH_CACHE=/tmp/quokka_tpu_bench_smoke \
-		$(PY) bench.py --service --smoke
-
 # observability smoke: a profiled query's critical-path buckets must sum to
 # the measured wall time within 10%, and /metrics + /status must serve a
 # live 2-query service run (Prometheus text with per-query histograms)
 obs-smoke:
 	$(PY) -m quokka_tpu.obs.smoke
-
-# perf-regression gate: run the bench and compare against the newest
-# BENCH_r*.json (override with CHECK_ARGS="--against path --threshold 0.2"
-# or compare two artifacts offline with CHECK_ARGS="--current path").
-# Exits nonzero when any metric regresses beyond its threshold, printing
-# the regressed queries' critical-path diffs.
-bench-check:
-	$(PY) bench.py --check $(CHECK_ARGS)
 
 # shuffle data-plane smoke: a seeded Q3-shaped join+aggregate (two hash
 # exchanges) run twice; the warm run must show ZERO blocking host readbacks
@@ -98,18 +83,6 @@ fusion-smoke:
 # executable persistence, runtime/compileplane.py)
 warmup-smoke:
 	$(PY) -m quokka_tpu.runtime.warmup_smoke
-
-# timed multichip smoke: tiny-SF TPC-H Q1/Q3/Q5 + tick-asof through the
-# mesh execution plane on 8 XLA-forced host devices, each timed against the
-# single-device engine.  Exits nonzero unless the scaling artifact is
-# written, every line records the kernel strategies that ran
-# (ops/strategy.py), the timed shuffle path stays at ZERO blocking host
-# syncs, and no query fell back from the mesh to the embedded engine.
-multichip-smoke:
-	JAX_PLATFORMS=cpu \
-	QUOKKA_BENCH_SF=0.01 QUOKKA_BENCH_CACHE=/tmp/quokka_tpu_bench_mc \
-		QUOKKA_MULTICHIP_OUT=/tmp/MULTICHIP_timed_smoke.json \
-		$(PY) bench.py --multichip --smoke
 
 # CPU rehearsal of chip_smoke.py (the chip itself is reached only through the
 # builder's chip tool: `chiprun -- python chip_smoke.py`): every phase at
@@ -147,15 +120,6 @@ mem-smoke:
 # under the plan fingerprint
 explain-smoke:
 	$(PY) -m quokka_tpu.obs.explain_smoke
-
-# device-profiling smoke: a Q3-shaped service query under an isolated
-# devprof dir — calibrated peaks persisted per backend fingerprint (foreign
-# fingerprints rejected wholesale), static flops/bytes figures for EVERY
-# compiled program (fused stages included), finite roofline efficiency for
-# every attributed operator, ZERO added host syncs, and a warm re-plan
-# whose broadcast decision quotes a seconds(roofline)-basis estimate
-devprof-smoke:
-	$(PY) -m quokka_tpu.obs.devprof_smoke
 
 # adaptive-planning smoke: a cold plan decides from hints/samples, the warm
 # re-plan must FLIP >= 1 decision from the persisted cardinality profile
@@ -196,14 +160,7 @@ durability-smoke: resume-smoke stream-smoke chaos-smoke
 health-smoke:
 	$(PY) -m quokka_tpu.obs.health_smoke
 
-# cross-round perf trajectory: every committed BENCH_r*.json as one table
-# (vs_baseline per round + slope per metric); exits nonzero when a metric
-# declined strictly monotonically over its last 3 consecutive rounds — the
-# slow leak each individual bench-check stayed inside its threshold on
-bench-trend:
-	$(PY) bench.py --trend $(TREND_ARGS)
-
 # the pre-merge aggregate: static analysis, tier-1 tests, and the
 # observability smokes a PR most often touches.  Heavier planes (chaos,
-# resume, streaming, multichip) keep their own entry points above.
-verify: verify-static test explain-smoke devprof-smoke
+# resume, streaming) keep their own entry points above.
+verify: verify-static test explain-smoke

@@ -42,7 +42,7 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 RTOL = 1e-3  # float32 sums on the chip vs float64 in pandas
-# Cut from the 1.0 bench.py calls its size, by the run's time limit alone: a
+# Cut from SF 1.0 (the benchmark's size) by the run's time limit alone: a
 # cold run is compilation (multi-operand sorts cost the TPU's compiler one
 # to three minutes each, whatever the row count), and at SF 1 the ~105
 # programs of these phases take about 25 minutes to compile on the chip's
@@ -203,7 +203,7 @@ def make_data(args) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# the queries (the DataStream calls bench.py times) and their plain references
+# the queries (copied into benchmarks/queries/) and their plain references
 # ---------------------------------------------------------------------------
 
 

@@ -51,7 +51,7 @@ Zero-baseline rules (no suppression file — a violation fails tier-1):
   appears in any node's schema.
 
 Pass-level instrumentation lives in ``optimizer.optimize``: under
-``QK_PLAN_VERIFY=1`` (default-on in tests and bench.py) every pass's
+``QK_PLAN_VERIFY=1`` (default-on in tests) every pass's
 (before, after) plan pair is verified and a violation raises
 ``PlanInvariantError`` naming the pass and the offending node.  All checks
 run at PLAN time — never on the push path.
@@ -90,7 +90,7 @@ RULES = {
              "non-broadcast unordered joins; salt column reserved",
 }
 
-# plan-time verification cost, surfaced per-query in bench.py detail
+# plan-time verification cost, read by optimizer.optimize and the tests
 # (acceptance: <= 5 ms per query at plan time)
 VERIFY_STATS = {"plans": 0, "checks": 0, "ms_total": 0.0, "ms_last_plan": 0.0}
 _CUR_MS = [0.0]
@@ -491,8 +491,8 @@ def _tables():
 
 def corpus() -> List[Tuple[str, "callable"]]:
     """(name, build(qc) -> DataStream) for every plannable query shape in
-    the tier-1 tests and bench.py — the CLI plans each one with the full
-    pass pipeline and verifies every intermediate plan."""
+    the tier-1 tests and the benchmark's cells — the CLI plans each one
+    with the full pass pipeline and verifies every intermediate plan."""
     from quokka_tpu.expression import col
     from quokka_tpu.windows import TumblingWindow
 

@@ -104,10 +104,10 @@ Rule ids:
                                 I/O outside (obs/progress.py
                                 ``_profile_for`` is the pattern)
   QK027 adhoc-wall-timing       bare ``time.time()``/``time.perf_counter()``
-                                deltas used for timing outside ``obs/`` and
-                                bench.py — a hand-rolled timer is invisible
+                                deltas used for timing outside ``obs/``
+                                — a hand-rolled timer is invisible
                                 to the span aggregator (obs/spans.py), the
-                                flight recorder and the bench breakdown;
+                                flight recorder and the query records;
                                 durations route through obs.span()
                                 (its ``dur``/``self_s`` after the block),
                                 deliberate low-level sites baseline with a
@@ -1253,8 +1253,8 @@ def check_raw_len_cache_key(tree: ast.Module, path: str, rel: str,
                             src_lines: Sequence[str]) -> List[Finding]:
     """The compile plane's whole premise is ONE canonical key space: a jit
     cache key built from a raw batch length fragments per 2x rung and per
-    call site, exactly the 11-15-compiles-per-query warmup BENCH_r05
-    measured.  Flags, outside ops/sigkey.py: (a) sig/key-named tuples
+    call site, exactly the 11-15-compiles-per-query warmup join queries
+    once paid.  Flags, outside ops/sigkey.py: (a) sig/key-named tuples
     embedding .padded_len or .shape[...], (b) .get()/subscript access on
     *_PROGRAMS/*_CACHE receivers whose key embeds one.  Canonical lengths
     come from sigkey.bucket_rows/batch_sig/aval_sig/make_key."""
@@ -1792,9 +1792,9 @@ check_obs_lock_blocking_io._needs_flow = True
 
 # the clock calls whose subtraction means "someone hand-rolled a timer"
 _QK027_TIMER_CALLS = ("time.time", "time.perf_counter", "perf_counter")
-# the obs plane OWNS timing (spans, opstats, critpath, history, devprof);
-# bench.py is the other sanctioned owner but lives outside quokka_tpu/ and
-# is never scanned
+# the obs plane OWNS timing (spans, opstats, critpath, history);
+# benchmarks/ keeps its own clock but lives outside quokka_tpu/ and is
+# never scanned
 _QK027_EXEMPT_DIRS = ("quokka_tpu/obs/",)
 
 

@@ -23,8 +23,8 @@ production path):
   fails a run when real backend compiles happened after warmup — the
   static-shape discipline says a warmed query shape never recompiles, and a
   silent recompile is both a perf cliff and a symptom of an unstable jit
-  signature.  bench.py raises on ``real_compiles_timed_runs > 0`` under
-  sanitize mode.
+  signature.  ``benchmarks/run.py`` reports the same count as
+  ``compiles_in_window``.
 """
 
 from __future__ import annotations

@@ -52,7 +52,7 @@ def _host_fingerprint() -> str:
 # sets no other directory); QUOKKA_JAX_CACHE_DIR is the tests' scratch
 # override; otherwise one fixed path inside the checkout.  CACHE_ROOT is
 # also the root of the AOT executable store, the plan ledger
-# (runtime/compileplane.py) and the strategy/devprof/mem/card profile
+# (runtime/compileplane.py) and the strategy/mem/card profile
 # stores, so all of them move together.
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CACHE_ROOT = (

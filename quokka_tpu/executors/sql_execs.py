@@ -444,8 +444,8 @@ class BuildProbeJoinExecutor(Executor):
                               _batch_nbytes(b))
         self.build_unique = join_ops.build_keys_unique(b, self.right_on)
         # the strategy that will serve every probe batch of this build is
-        # decided here — stamp it into the flight timeline so critpath /
-        # bench_obs can attribute the probe pipeline to the kernel family
+        # decided here — stamp it into the flight timeline so critpath
+        # can attribute the probe pipeline to the kernel family
         # that actually ran (ops/strategy.py matrix)
         from quokka_tpu.obs import RECORDER
         from quokka_tpu.ops import strategy as kstrategy

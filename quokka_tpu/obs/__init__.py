@@ -35,7 +35,7 @@ Env vars (the full table is in README "Observability"):
   Chrome trace at run end.
 - ``QK_DUMP_DIR``: where stall dumps land (default
   ``<tmp>/quokka_tpu_dumps``).
-- ``QUOKKA_TRACE=1``: print the span summary at bench end (unchanged).
+- ``QUOKKA_TRACE=1``: aggregate span totals into ``spans.stats()``.
 - ``QK_COORD_TIMEOUT``: coordinator run timeout seconds (default 600).
 - ``QK_CHAOS``: seeded fault-injection spec (quokka_tpu/chaos).  Every
   injected fault lands here as a ``chaos.*`` event, every checksum
@@ -52,7 +52,6 @@ import sys
 from quokka_tpu.obs import (
     alerts,
     critpath,
-    devprof,
     explain,
     export,
     history,

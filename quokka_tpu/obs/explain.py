@@ -7,8 +7,8 @@ the three artifacts the doctor workflow reads:
   selectivity, padded-waste, time share, executor-noted figures like join
   build/probe rows), a skew report per exchange edge (max/mean channel
   rows, flagged above ``QK_SKEW_RATIO``), and the top-N hot operators;
-- ``operators_detail(snap)``: the compact per-operator dict list bench.py
-  embeds as ``detail.operators`` in every bench line;
+- ``operators_detail(snap)``: the compact per-operator dict list, the
+  machine-readable twin of ``render``;
 - ``QueryHandle.explain()`` (service/session.py) serves ``render`` over the
   live ledger while the query runs and over the finish-time snapshot after.
 
@@ -80,20 +80,10 @@ def _decision_line(d: dict) -> str:
                 f"QK_BROADCAST_BYTES={_fmt_bytes(d['threshold_bytes'])}")
         elif d.get("threshold_rows") is not None:
             bits.append(f"threshold_rows={_fmt_rows(d['threshold_rows'])}")
-        if d.get("est_s_basis"):
-            bits.append(
-                f"broadcast_s={d.get('broadcast_s')}"
-                f" partition_s={d.get('partition_s')}"
-                f" [{d['est_s_basis']}"
-                + (f", probe {d['probe_s_basis']}]"
-                   if d.get("probe_s_basis") else "]"))
         return "  ".join(bits)
     if kind == "join_order":
-        line = (f"join_order [{d.get('basis')}]: "
+        return (f"join_order [{d.get('basis')}]: "
                 + " | ".join(d.get("after") or []))
-        if d.get("est_s_basis"):
-            line += f"  est_s_basis={d['est_s_basis']}"
-        return line
     if kind == "channels":
         return (f"channels {d.get('node')}: {d.get('default')}"
                 f"->{d.get('channels')}  basis={d.get('basis')}"
@@ -113,7 +103,7 @@ def _decision_line(d: dict) -> str:
 
 def render(snap: Optional[dict], top_n: int = 5) -> str:
     """The human EXPLAIN ANALYZE report for one query's snapshot (what
-    ``QueryHandle.explain()`` and ``bench.py --measure`` print)."""
+    ``QueryHandle.explain()`` prints)."""
     if not snap:
         return "explain: no operator statistics recorded"
     lines = [
@@ -136,29 +126,6 @@ def render(snap: Optional[dict], top_n: int = 5) -> str:
                 f"rows={_fmt_rows(e['rows_total'])} "
                 f"max={_fmt_rows(e['rows_max'])} mean={e['rows_mean']:.0f} "
                 f"ratio={e['skew_ratio']:.2f}{flag}")
-    eff = snap.get("efficiency")
-    if eff and eff.get("operators"):
-        peaks = eff.get("peaks")
-        head = "device efficiency"
-        if peaks:
-            head += (f" (peaks: {peaks['peak_flops_s']:.3g} FLOP/s, "
-                     f"{peaks['peak_bw_bytes_s']:.3g} B/s)")
-        else:
-            head += " (uncalibrated: run devprof.calibrate())"
-        lines.append(head + ":")
-        for r in eff["operators"]:
-            bits = [f"a{r['actor']} {r['op']}"]
-            if r.get("achieved_flops_s") is not None:
-                bits.append(f"flops/s={r['achieved_flops_s']:.3g}")
-            if r.get("achieved_bw_s") is not None:
-                bits.append(f"bw={r['achieved_bw_s']:.3g}B/s")
-            if r.get("intensity") is not None:
-                bits.append(f"intensity={r['intensity']:.2f}")
-            if r.get("efficiency") is not None:
-                bits.append(f"roofline={r['efficiency']:.1%}")
-            bits.append(f"programs={r['program_dispatches']}")
-            flag = "  ** BELOW QK_EFF_FLOOR **" if r.get("flagged") else ""
-            lines.append("  " + "  ".join(bits) + flag)
     planner = snap.get("planner") or []
     if planner:
         lines.append("planner decisions:")
@@ -178,8 +145,8 @@ def render(snap: Optional[dict], top_n: int = 5) -> str:
 
 
 def operators_detail(snap: Optional[dict]) -> Optional[dict]:
-    """The compact machine-readable digest bench.py embeds as
-    ``detail.operators``: per-operator actuals + the per-edge skew report."""
+    """The compact machine-readable digest: per-operator actuals + the
+    per-edge skew report."""
     if not snap or not snap.get("operators"):
         return None
     ops: List[dict] = []
@@ -207,29 +174,9 @@ def operators_detail(snap: Optional[dict]) -> Optional[dict]:
              "ratio": e["skew_ratio"], "skewed": e["skewed"]}
             for e in snap["edges"]],
         "rows_unknown": snap.get("rows_unknown", 0),
-        # plan-time choices + runtime adaptations (bench detail.plan's
-        # "planner" section; same records explain() renders)
+        # plan-time choices + runtime adaptations (the same records
+        # explain() renders)
         "planner": [dict(d) for d in snap.get("planner") or []],
-    }
-
-
-def efficiency_detail(snap: Optional[dict]) -> Optional[dict]:
-    """The compact device-efficiency digest bench.py embeds as
-    ``detail.efficiency``: calibrated peaks + per-operator achieved rates
-    and roofline percentages (obs/devprof.py attach)."""
-    if not snap:
-        return None
-    eff = snap.get("efficiency")
-    if not eff or not eff.get("operators"):
-        return None
-    return {
-        "peaks": eff.get("peaks"),
-        "operators": [
-            {k: r.get(k)
-             for k in ("actor", "op", "time_s", "flops", "bytes",
-                       "intensity", "achieved_flops_s", "achieved_bw_s",
-                       "efficiency", "program_dispatches", "flagged")}
-            for r in eff["operators"]],
     }
 
 

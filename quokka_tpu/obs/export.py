@@ -94,10 +94,6 @@ _LABEL_FAMILIES: Tuple[Tuple[str, str, str, str], ...] = (
     ("gauge", "progress.fraction.", "quokka_progress_fraction", "query"),
     ("gauge", "progress.eta_s.", "quokka_progress_eta_seconds", "query"),
     ("counter", "alert.", "quokka_alerts_fired", "rule"),
-    # device-efficiency plane (obs/devprof.py): per-(query, operator)
-    # roofline-efficiency gauges ("<qid>.a<actor>"), created at snapshot
-    # time and GC'd with the query like the opstats twins
-    ("gauge", "devprof.eff.", "quokka_devprof_roofline_efficiency", "op"),
 )
 
 # Aggregate instruments that ALSO exist as a labeled per-query family: the
@@ -125,10 +121,6 @@ _EXACT_FAMILIES: Dict[Tuple[str, str], str] = {
     ("gauge", "shuffle.skew"): "quokka_shuffle_skew_ratio_max",
     ("counter", "opstats.size_hint_drift_bytes"):
         "quokka_opstats_size_hint_drift_bytes",
-    # calibrated device peaks (obs/devprof.py calibrate): process-wide,
-    # not per-query, so they must not share the labeled devprof family
-    ("gauge", "devprof.peak_flops"): "quokka_devprof_peak_flops",
-    ("gauge", "devprof.peak_bw_bytes"): "quokka_devprof_peak_bw_bytes",
 }
 
 
@@ -331,12 +323,6 @@ class MetricsServer:
             "chaos": {k.split(".", 1)[1]: v for k, v in snap.items()
                       if k.startswith("chaos.")},
         }
-        try:
-            from quokka_tpu.obs import devprof
-
-            out["devprof"] = devprof.summary()
-        except Exception as e:  # noqa: BLE001 — profiling must not 500
-            out["devprof"] = {"error": repr(e)}  # /status
         svc = self.service
         if svc is not None:
             try:

@@ -308,7 +308,7 @@ class MemLedger:
 
     def reset_peak(self) -> None:
         """Re-arm the aggregate high-water mark at the current live total
-        (bench.py brackets each measured query with this)."""
+        (a caller brackets each measured query with this)."""
         with self._lock:
             self._peak = self._live
             pairs = self._gauge_pairs(None, None)

@@ -4,8 +4,8 @@ Replaces the ad-hoc ``_metrics`` dict that used to live inline in
 runtime/engine.py with two layers:
 
 - a process-wide ``Registry`` of named ``Counter``/``Gauge`` instruments
-  (cache hits, rpc calls, bytes pushed, ...) that bench.py snapshots into
-  its per-query breakdown JSON;
+  (cache hits, rpc calls, bytes pushed, ...) that ``/metrics`` renders
+  and the history sampler snapshots;
 - ``EngineMetrics``: the per-(actor, channel) {tasks, rows, bytes}
   accounting every engine/worker flushes through the control store —
   byte-identical snapshot shape to the old ``_metrics``/``_flush_metrics``

@@ -104,8 +104,7 @@ class OpStats:
         # gauge is the max over LIVE queries (recomputed at GC so a
         # /health skew alert clears without a process restart)
         self._skew_worst: Dict[str, float] = {}
-        # most recently finished query's snapshot (what bench reads after a
-        # one-shot run's cleanup)
+        # most recently finished query's snapshot (last_finished)
         self._last: Optional[dict] = None
 
     # -- plan registration ---------------------------------------------------
@@ -351,12 +350,6 @@ class OpStats:
                 return last if last and last.get("query_id") == qid else None
             snap = self._render_locked(qid, plan, thresh, top_n)
         self._export_gauges(qid, snap)
-        # device-efficiency join (obs/devprof.py): static program costs vs
-        # the measured per-operator seconds above — outside the lock, no
-        # device reads
-        from quokka_tpu.obs import devprof
-
-        devprof.attach(qid, snap)
         return snap
 
     def _render_locked(self, qid: str, plan: dict, thresh: float,
@@ -515,8 +508,8 @@ class OpStats:
                 f"{top['time_s']:.3f}s rows={top['rows_out']}")
 
     def last_finished(self) -> Optional[dict]:
-        """The most recently GC'd query's snapshot (what bench.py reads
-        after a one-shot run's cleanup)."""
+        """The most recently GC'd query's snapshot (what memplane's OOM
+        bundle and a test read after a one-shot run's cleanup)."""
         with self._lock:
             return self._last
 
@@ -599,10 +592,6 @@ class OpStats:
         # drops to the worst LIVE query (0 when idle), so /health alerts
         # clear without a restart
         obs.REGISTRY.gauge("shuffle.skew").set(live_worst)
-        from quokka_tpu.obs import devprof
-
-        devprof.on_query_finished(qid, plan_fp or (plan or {}).get("plan_fp"),
-                                  snap or {})
         fp = plan_fp or (plan or {}).get("plan_fp")
         if snap is not None:
             record_cardinalities(fp, snap)

@@ -27,7 +27,7 @@ gate covers the whole collection path.
 Surfaces: ``QueryHandle.progress()``, the per-session ``progress``/``eta_s``
 columns in ``QueryService.stats()`` (hence ``/status``), the
 ``progress.fraction.<qid>`` / ``progress.eta_s.<qid>`` gauges on
-``/metrics`` (GC'd with the query), ``bench.py --measure`` detail, and —
+``/metrics`` (GC'd with the query), and —
 pane-frontier based — ``StreamingHandle.progress()``.
 """
 
@@ -60,8 +60,8 @@ class ProgressTracker:
         self._lock = threading.Lock()
         # qid -> {fraction, rate, last_t, profile, profile_loaded, gauges}
         self._q: Dict[str, dict] = {}
-        # most recently finished query's final snapshot (what bench.py
-        # reads after a one-shot run's cleanup — the opstats _last idiom)
+        # most recently finished query's final snapshot (readable after a
+        # one-shot run's cleanup — the opstats _last idiom)
         self._last: Optional[dict] = None
 
     # -- estimation ----------------------------------------------------------
@@ -186,8 +186,7 @@ class ProgressTracker:
         return snap
 
     def last_finished(self) -> Optional[dict]:
-        """The most recently GC'd query's final progress snapshot (what
-        ``bench.py --measure`` embeds in detail.progress)."""
+        """The most recently GC'd query's final progress snapshot."""
         with self._lock:
             return self._last
 

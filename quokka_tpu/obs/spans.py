@@ -4,7 +4,7 @@ The span API that used to live in utils/tracing.py (which now re-exports
 this module).  Four consumers share one ``span(...)`` call site:
 
 - the aggregate summary (``QUOKKA_TRACE=1`` or ``set_enabled(True)``):
-  name -> (count, total seconds), printed by bench.py at run end — the
+  name -> (count, total seconds), read through ``stats()`` — the
   replacement for the reference's print_if_profile timestamp prints
   (pyquokka/core.py:20-30);
 - the flight recorder: every span lands as a duration event in the ring
@@ -77,8 +77,8 @@ def enabled() -> bool:
 
 
 def set_enabled(on: bool) -> None:
-    """Turn aggregate collection on programmatically (bench.py does this so
-    its breakdown JSON is populated even without QUOKKA_TRACE=1)."""
+    """Turn aggregate collection on programmatically (a test's lever:
+    ``stats()`` is populated even without QUOKKA_TRACE=1)."""
     global _enabled
     _enabled = bool(on)
 
@@ -255,7 +255,7 @@ def current_task() -> Dict[str, str]:
 
 
 def stats() -> Dict[str, Dict[str, float]]:
-    """Structured snapshot: name -> {count, total_s} (bench breakdown)."""
+    """Structured snapshot: name -> {count, total_s}."""
     with _lock:
         return {name: {"count": n, "total_s": round(total, 6)}
                 for name, (n, total) in _stats.items()}

@@ -165,10 +165,6 @@ def _dispatch_program(sig, builder, args):
         # record the use under the current plan even on a warm hit (a new
         # plan reusing another's programs must still prewarm them all)
         compileplane.note_program(sig)
-    from quokka_tpu.obs import devprof
-
-    # charge the program's static flops/bytes to the current operator
-    devprof.on_dispatch(sig)
     try:
         return fn(*args)
     except compileplane.AotMismatch:

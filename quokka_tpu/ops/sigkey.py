@@ -2,8 +2,8 @@
 dimensions come from.
 
 Every fused/AOT program in the engine is cached by a structural signature
-(padded length, column dtypes, expression text, strategy flags).  BENCH_r05
-showed that space fragmenting: 11-15 real compiles per join query during
+(padded length, column dtypes, expression text, strategy flags).  That
+space used to fragment: 11-15 real compiles per join query during
 warmup, because each call site derived its own key from raw batch
 properties — one program per 2x padded-length rung, per redundant
 kind-char, per exact dictionary size.  This module collapses the key space:
@@ -113,7 +113,7 @@ def aval_sig(args) -> Tuple:
 
 # ---------------------------------------------------------------------------
 # signature ledger: every distinct program key, by kind — makes cache-key
-# cardinality observable (tests pin a budget; bench/prewarm read it)
+# cardinality observable (tests pin a budget; prewarm reads it)
 # ---------------------------------------------------------------------------
 
 _ledger_lock = threading.Lock()

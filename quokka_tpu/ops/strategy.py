@@ -24,10 +24,8 @@ and the decision is MEASURED, not asserted:
     4. static per-platform safe defaults (the pre-PR-8 gates).
 
 - ``note_used(op, choice)`` records what actually RAN (dispatch sites call
-  it), feeding ``strategy.<op>.<choice>`` counters and the per-query
-  ``detail.strategy`` map bench.py emits; ``bench.py --check`` fails when a
-  benched line records a choice its platform gates off
-  (``invalid_for_platform``).
+  it), feeding the ``strategy.<op>.<choice>`` counters on ``/metrics``;
+  ``invalid_for_platform`` names a recorded choice its platform gates off.
 
 Operators and choices:
 
@@ -270,7 +268,7 @@ def sources() -> Dict[str, str]:
 
 
 # ---------------------------------------------------------------------------
-# what actually ran (bench honesty)
+# what actually ran
 # ---------------------------------------------------------------------------
 
 
@@ -307,9 +305,8 @@ def invalid_for_platform(platform: str, op: str,
                          ran: str) -> Optional[str]:
     """Why a recorded (op, choice) could never be the production path on
     `platform`, or None when it is legitimate.  ``ran`` may be a '+'-joined
-    multi-value from used_snapshot; every component must be runnable.  This
-    is the bench --check honesty gate: the r5 verdict's top finding was a
-    benched host-asof that a TPU will never run."""
+    multi-value from used_snapshot; every component must be runnable: a
+    timed host-asof is one a TPU will never run."""
     parts = ran.split("+") if ran else [ran]
     if op not in OPS or any(p not in OPS.get(op, ()) for p in parts):
         return (f"unknown strategy {op}={ran!r} — the bench recorded a "

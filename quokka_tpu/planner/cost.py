@@ -44,28 +44,6 @@ BASIS_SAMPLED = "sampled"
 BASIS_HINT = "hint"
 _RANK = {BASIS_MEASURED: 2, BASIS_SAMPLED: 1, BASIS_HINT: 0}
 
-# seconds-basis ladder (the devprof plane's extension of the precedence
-# above): a *measured* figure is a scan time this exact source signature
-# actually took on this backend; *roofline* converts estimated bytes
-# through the calibrated/observed device bandwidth (obs/devprof.py);
-# *hint* divides by a nominal 1 GB/s when nothing is calibrated.  A
-# conversion can never be stronger than the cardinality estimate it
-# converts, so the final basis is capped by the rows/bytes basis rank.
-SECONDS_MEASURED = "seconds(measured)"
-SECONDS_ROOFLINE = "seconds(roofline)"
-SECONDS_HINT = "seconds(hint)"
-_SRANK = {SECONDS_MEASURED: 2, SECONDS_ROOFLINE: 1, SECONDS_HINT: 0}
-_SBY_RANK = {2: SECONDS_MEASURED, 1: SECONDS_ROOFLINE, 0: SECONDS_HINT}
-_NOMINAL_BW = 1e9
-
-
-def seconds_usable(basis: str) -> bool:
-    """Decision passes prefer seconds over abstract rows×bytes only when
-    the figure is at least roofline-grade — a nominal-bandwidth guess is
-    not evidence."""
-    return _SRANK.get(basis, 0) >= _SRANK[SECONDS_ROOFLINE]
-
-
 def _weaker(a: str, b: str) -> str:
     """The weaker of two bases — a derived figure is only as strong as its
     weakest input."""
@@ -120,17 +98,6 @@ class Estimate:
         return DEFAULT_COL_BYTES * max(ncols, 1)
 
 
-@dataclasses.dataclass
-class SecondsEstimate:
-    """One node's estimated device seconds, the basis that produced the
-    conversion (``seconds(measured)``/``seconds(roofline)``/
-    ``seconds(hint)``), and the cardinality estimate it converted."""
-
-    seconds: float
-    basis: str
-    est: Estimate
-
-
 def _hint_bytes(reader) -> Optional[int]:
     fn = getattr(reader, "size_hint", None)
     if fn is None:
@@ -159,7 +126,6 @@ class CostModel:
             profile = opstats.measured_sources()
         self.profile = profile or {}
         self._memo: Dict[int, Estimate] = {}
-        self._smemo: Dict[int, SecondsEstimate] = {}
 
     # -- sources -------------------------------------------------------------
 
@@ -277,42 +243,6 @@ class CostModel:
                                rows * DEFAULT_COL_BYTES * len(m.schema),
                                cur.basis)
         return cur
-
-    # -- seconds basis (obs/devprof.py calibration) ---------------------------
-
-    def estimate_seconds(self, nid: int) -> SecondsEstimate:
-        """Predicted device seconds for one node's output, with strict
-        precedence: a directly measured scan time for this exact source
-        signature > the roofline conversion (estimated bytes over the
-        calibrated/observed bandwidth) > a nominal-bandwidth hint.  The
-        basis is capped by the cardinality basis: converting guessed bytes
-        through a calibrated peak still yields ``seconds(hint)``."""
-        if nid in self._smemo:
-            return self._smemo[nid]
-        from quokka_tpu.obs import devprof
-
-        est = self.build_bytes(nid)
-        node = self.sub[nid]
-        nbytes = est.bytes or 0.0
-        seconds: Optional[float] = None
-        conv = SECONDS_HINT
-        if isinstance(node, logical.SourceNode):
-            rec = devprof.measured_source_seconds(
-                source_signature(node.reader, node.predicate,
-                                 node.projection))
-            if rec is not None:
-                seconds, conv = rec[0], SECONDS_MEASURED
-        if seconds is None:
-            bw = devprof.planning_bw()
-            if bw:
-                seconds, conv = nbytes / bw, SECONDS_ROOFLINE
-            else:
-                seconds, conv = nbytes / _NOMINAL_BW, SECONDS_HINT
-        cap = _RANK.get(est.basis, 0)
-        if _SRANK[conv] > cap:
-            conv = _SBY_RANK[cap]
-        self._smemo[nid] = out = SecondsEstimate(seconds, conv, est)
-        return out
 
     # -- convenience ----------------------------------------------------------
 

@@ -37,9 +37,6 @@ from quokka_tpu.planner import cost as cost_mod
 # a channel is worth its compile/dispatch overhead only past this many rows
 ROWS_PER_CHANNEL = 1 << 17
 
-# nominal exchange fan-out a broadcast build pays (ships to every channel)
-BROADCAST_FANOUT = 2.0
-
 # reserved by the runtime salting rewrite; no user plan may emit it
 SALT_COLUMN = "__qk_salt"
 
@@ -93,30 +90,12 @@ def choose_broadcast_cost(sub: Dict[int, logical.Node], sink_id: int) -> None:
         est = model.build_bytes(node.parents[1])
         if est.basis == cost_mod.BASIS_MEASURED:
             limit = config.broadcast_bytes_threshold()
-            fits = est.bytes is not None and est.bytes <= limit
-            extra = {}
-            # seconds refinement (devprof-calibrated): a build that fits
-            # the byte budget still only broadcasts when shipping it
-            # everywhere is predicted no slower than partitioning both
-            # sides.  Strictly more conservative than the byte threshold
-            # alone — it can only flip broadcast->partition.
-            build_s = model.estimate_seconds(node.parents[1])
-            if cost_mod.seconds_usable(build_s.basis):
-                probe_s = model.estimate_seconds(node.parents[0])
-                bcast_s = build_s.seconds * BROADCAST_FANOUT
-                part_s = build_s.seconds + probe_s.seconds
-                extra = {"est_s_basis": build_s.basis,
-                         "probe_s_basis": probe_s.basis,
-                         "broadcast_s": round(bcast_s, 6),
-                         "partition_s": round(part_s, 6)}
-                if fits:
-                    fits = bcast_s <= part_s
-            node.broadcast = fits
+            node.broadcast = est.bytes is not None and est.bytes <= limit
             record("broadcast", node=node.describe(),
                    choice="broadcast" if node.broadcast else "partition",
                    basis=est.basis, build_rows=round(est.rows),
                    build_bytes=round(est.bytes or 0),
-                   threshold_bytes=limit, **extra)
+                   threshold_bytes=limit)
             continue
         rows = optimizer._estimate_subtree(sub, node.parents[1], cat)
         if rows is not None and rows <= optimizer.BROADCAST_THRESHOLD:
@@ -143,27 +122,15 @@ def reorder_joins_cost(sub: Dict[int, logical.Node], sink_id: int) -> None:
         est = model.estimate(nid)
         if est.basis == cost_mod.BASIS_HINT:
             return None
-        # prefer predicted device seconds when the conversion is at least
-        # roofline-grade (devprof calibrated); seconds are monotone in
-        # bytes so this orders wide-but-short builds after narrow ones
-        sec = model.estimate_seconds(nid)
-        if cost_mod.seconds_usable(sec.basis):
-            return sec.seconds
         return est.rows
-
-    def _fmt(nid: int) -> str:
-        sec = model.estimate_seconds(nid)
-        return (f"{sub[nid].describe()}"
-                f" (~{round(model.estimate(nid).rows)} rows,"
-                f" ~{sec.seconds:.4f}s {sec.basis})")
 
     def on_reorder(chain_ids, before, after, basis):
         record("join_order", chain=[sub[j].describe() for j in chain_ids],
                before=[sub[b].describe() for b in before],
-               after=[_fmt(b) for b in after],
-               basis=basis,
-               est_s_basis=(model.estimate_seconds(after[0]).basis
-                            if after else None))
+               after=[f"{sub[a].describe()}"
+                      f" (~{round(model.estimate(a).rows)} rows)"
+                      for a in after],
+               basis=basis)
 
     optimizer.reorder_joins(sub, sink_id, estimate=estimate,
                             on_reorder=on_reorder,

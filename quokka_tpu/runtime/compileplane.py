@@ -2,8 +2,8 @@
 plan-driven pre-warm.
 
 The engine's static-shape discipline means a query shape compiles a finite
-program set and then reuses it forever — but BENCH_r05 showed warmup still
-costing 3-6x steady state: every fused program paid trace + lower +
+program set and then reuses it forever — but warmup still cost 3-6x
+steady state: every fused program paid trace + lower +
 compile-or-cache-load serialized with its first dispatch.  This module makes
 compilation a first-class, front-loaded concern with three layers:
 
@@ -618,10 +618,6 @@ def _acquire(key: Tuple, builder: Callable[[], object], args,
             # rebuilds from its own CURRENT builder.
             prog = AotProgram(loaded[1])
             PROGRAMS[key] = prog
-            from quokka_tpu.obs import devprof
-
-            # replay the persisted static-cost sidecar (no re-analysis)
-            devprof.load_cost(key, path)
             return prog, "cache_hit"
     _count("miss")
     fn = builder()
@@ -634,12 +630,6 @@ def _acquire(key: Tuple, builder: Callable[[], object], args,
             hits = compilestats.thread_cache_hits()
             compiled = lowered.compile()
             prog = AotProgram(compiled, builder=lambda: fn)
-            from quokka_tpu.obs import devprof
-
-            # static flops/bytes from the fresh executable, persisted in a
-            # sidecar next to the AOT artifact under the same key
-            devprof.record_cost(key, compiled,
-                                _entry_path(key, create=True))
             # an executable the XLA persistent cache answered re-serializes
             # without its kernels: the copy loads, then fails its first
             # run with an asynchronous NOT_FOUND no call site can catch.
@@ -681,9 +671,6 @@ def aot_kernel_call(kind: str, jit_fn, args: Tuple, statics: Tuple = ()):
                 return jit_fn
         prog = acquire(key, builder, args,
                        lowerer=lambda: jit_fn.lower(*args, *statics))
-    from quokka_tpu.obs import devprof
-
-    devprof.on_dispatch(key)
     try:
         return prog(*args)
     except AotMismatch:
@@ -723,9 +710,6 @@ def _install_hash(h: str) -> bool:
             _KEY_BY_HASH[h] = key
         if key not in PROGRAMS:
             PROGRAMS[key] = AotProgram(compiled, prewarmed=True)
-        from quokka_tpu.obs import devprof
-
-        devprof.load_cost(key, path)
         ok = True
         return True
     finally:

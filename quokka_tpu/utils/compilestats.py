@@ -5,7 +5,8 @@ kernel set once and then reuse it forever — across batches within a run,
 across runs within a process (jit caches), and across processes (the
 persistent compilation cache, config.py).  These counters make reuse
 observable: `snapshot()["backend_compiles"]` staying flat across repeated
-runs IS the proof, and bench.py reports the per-phase deltas.
+runs IS the proof, and ``benchmarks/run.py`` reports the deltas around its
+warm-up passes and its window.
 
 Counter meanings:
 - backend_compiles / backend_compile_seconds: compile_or_get_cached calls —

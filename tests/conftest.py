@@ -31,11 +31,6 @@ os.environ.setdefault("QK_STRATEGY_DIR", "")
 # box with populated caches would flip est_bytes in admission tests.
 os.environ.setdefault("QK_MEMPROFILE_DIR", "")
 os.environ.setdefault("QK_CARDPROFILE_DIR", "")
-# Same again for the device-profile plane (obs/devprof.py calibrated peaks
-# + observed throughputs): a calibrated developer box would flip the cost
-# model's seconds basis from hint to roofline under tests.  Tests that
-# exercise calibration point QK_DEVPROF_DIR at a tmp dir and reset().
-os.environ.setdefault("QK_DEVPROF_DIR", "")
 # Plan-invariant verification (analysis/planck.py QK021-QK024) is default-ON
 # for every test: each optimizer pass's (before, after) plan pair is checked
 # and a violation fails the test naming the pass and offending node.

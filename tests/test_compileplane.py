@@ -128,6 +128,18 @@ def test_aot_roundtrip_bit_exact(aot_dir):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_a_real_compile_leaves_the_executable_and_nothing_beside_it(aot_dir):
+    """The AOT directory holds one ``<key hash>.aot`` per persisted program
+    and no sidecar: nothing analyses an executable as it is compiled."""
+    key = sigkey.make_key("t_alone", _RUN_TOKEN, 11, ((8,), "float32"))
+    args = (jnp.arange(8.0, dtype=jnp.float32),
+            jnp.ones(8, dtype=jnp.float32))
+    compileplane.acquire(key, functools.partial(_toy_builder, 11), args)
+    compileplane.drain_writes()
+    left = os.listdir(compileplane._aot_dir())
+    assert left and all(f.endswith(".aot") for f in left), left
+
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -428,8 +440,8 @@ def test_backend_fingerprint_shape():
 # ---------------------------------------------------------------------------
 
 # Checked-in budget: distinct fused/kernel program keys a Q3-shaped
-# join+join+groupby query may create.  BENCH_r05 measured 11-15 REAL
-# compiles per join query from signature fragmentation; the canonical
+# join+join+groupby query may create.  Signature fragmentation once cost
+# 11-15 REAL compiles per join query; the canonical
 # ladder + normalized column signatures hold the whole per-kind key space
 # to this budget.  If this fails after a change, either the change leaks
 # signature cardinality (fix it) or it legitimately adds a program kind

@@ -290,3 +290,67 @@ class TestLiveServiceScrape:
         with pytest.raises((urllib.error.URLError, ConnectionError,
                             OSError)):
             _scrape(svc.metrics_server.url("/metrics"), timeout=2)
+
+
+# -- what a finished query leaves on each surface ----------------------------
+
+
+@pytest.fixture(scope="module")
+def after_a_query():
+    """One service query with the sidecar on: (/status after it finished,
+    the families that carried a sample labelled with the query while it
+    ran, the handle's explain dict)."""
+    def labelled(svc, qid):
+        _, _, text = _scrape(svc.metrics_server.url("/metrics"))
+        assert _valid_exposition(text)
+        found, family = set(), None
+        for line in text.splitlines():
+            if line.startswith("# TYPE "):
+                family = line.split()[2]
+            elif f'"{qid}' in line:
+                found.add(family)
+        return found
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("QK_METRICS_PORT", "0")
+        with QueryService(pool_size=2) as svc:
+            h = svc.submit(_slow_query(QuokkaContext()))
+            families = set()
+            while not h.done:
+                families |= labelled(svc, h.query_id)
+                time.sleep(0.01)
+            assert len(h.to_df(timeout=300)) == 16
+            _, _, body = _scrape(svc.metrics_server.url("/status"))
+            return json.loads(body), families, h.explain(as_dict=True)
+
+
+def test_status_is_the_process_and_its_service(after_a_query):
+    """No plane hangs a digest of its own on /status: what is there is the
+    process, the recorder, the incident counters and the service."""
+    status, _, _ = after_a_query
+    assert set(status) == {"pid", "time", "uptime_s", "obs",
+                           "integrity_corrupt", "chaos", "service"}
+
+
+def test_every_family_a_running_query_labels_is_documented(after_a_query):
+    """test_metrics_doc.py holds the README's table to the source; this
+    holds it to what a running query really puts on /metrics under its own
+    label (a family of a deleted plane would show here first).  Only the
+    query's own samples: the registry is the process's, and other tests'
+    instruments are in it."""
+    import test_metrics_doc
+
+    _, families, _ = after_a_query
+    assert "quokka_task_latency_seconds" in families
+    assert families <= test_metrics_doc._documented_families(), sorted(
+        families - test_metrics_doc._documented_families())
+
+
+def test_explain_dict_is_operators_edges_and_decisions(after_a_query):
+    """The machine-readable EXPLAIN carries what the operators counted and
+    what the planner chose; no section derived from host seconds."""
+    _, _, snap = after_a_query
+    assert {"query_id", "operators", "edges", "planner",
+            "top_operators"} <= set(snap)
+    assert "efficiency" not in snap
+    assert all("efficiency" not in op for op in snap["operators"])

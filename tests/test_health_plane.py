@@ -3,11 +3,7 @@ answers (cold size_hint fallback, warm cardprofile blend), the monotone
 clamp under out-of-order ledger views, ETA math on a synthetic timeline,
 GC idempotence; the alert rules' known-answer matrix with edge-triggered
 counting and the ok/degraded/critical fold; the history ring's rate math
-and depth eviction; and bench --trend's monotone-decline gate."""
-
-import importlib.util
-import json
-import os
+and depth eviction."""
 
 import pytest
 
@@ -311,62 +307,3 @@ class TestHistoryRing:
         assert sample["t"] == 1.0
         assert {"counters", "gauges", "histograms"} <= set(sample)
         assert obs.REGISTRY.snapshot()["history.samples"] == before + 1
-
-
-# ---------------------------------------------------------------------------
-# bench --trend: the cross-round decline gate
-# ---------------------------------------------------------------------------
-
-_BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "bench.py")
-
-
-@pytest.fixture(scope="module")
-def bench():
-    spec = importlib.util.spec_from_file_location("qk_bench", _BENCH)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _write_round(dirpath, n, values):
-    lines = [{"metric": m, "value": v, "unit": "x", "vs_baseline": v,
-              "detail": {}} for m, v in values.items()]
-    path = os.path.join(str(dirpath), f"BENCH_r{n:02d}.json")
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump(lines, f)
-
-
-class TestBenchTrend:
-    def test_monotone_decline_over_window_exits_nonzero(self, bench,
-                                                        tmp_path, capsys):
-        for i, v in enumerate((1.0, 0.9, 0.8), start=1):
-            _write_round(tmp_path, i, {"m_leak": v, "m_fine": 1.0})
-        rc = bench.trend_main(["--dir", str(tmp_path)])
-        out = capsys.readouterr().out
-        assert rc == 1
-        assert "TREND REGRESSION" in out and "m_leak" in out
-        assert "DECLINING" in out
-
-    def test_clean_trajectory_exits_zero(self, bench, tmp_path, capsys):
-        for i, v in enumerate((0.8, 0.9, 0.85), start=1):
-            _write_round(tmp_path, i, {"m": v})
-        rc = bench.trend_main(["--dir", str(tmp_path)])
-        assert rc == 0
-        assert "clean" in capsys.readouterr().out
-
-    def test_decline_across_recording_gap_is_not_attributed(self, bench,
-                                                            tmp_path,
-                                                            capsys):
-        # m declines 1.0 -> 0.9 -> 0.8 but round 2 never recorded it: the
-        # gap spans a potential box re-baseline, so the gate must not trip
-        _write_round(tmp_path, 1, {"m": 1.0, "anchor": 1.0})
-        _write_round(tmp_path, 2, {"anchor": 1.0})
-        _write_round(tmp_path, 3, {"m": 0.9, "anchor": 1.0})
-        _write_round(tmp_path, 4, {"m": 0.8, "anchor": 1.0})
-        rc = bench.trend_main(["--dir", str(tmp_path)])
-        assert rc == 0, capsys.readouterr().out
-
-    def test_too_few_artifacts_is_a_usage_error(self, bench, tmp_path):
-        _write_round(tmp_path, 1, {"m": 1.0})
-        assert bench.trend_main(["--dir", str(tmp_path)]) == 2

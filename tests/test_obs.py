@@ -285,32 +285,6 @@ def test_resolve_timeout_env_and_explicit(monkeypatch):
     assert _resolve_timeout(None) == DEFAULT_RUN_TIMEOUT
 
 
-# -- bench breakdown ---------------------------------------------------------
-
-
-def test_bench_span_breakdown_buckets():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "qk_bench", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    br = bench._span_breakdown({
-        "reader.execute": {"count": 2, "total_s": 1.0},
-        "bridge.to_device": {"count": 2, "total_s": 0.5},
-        "emit.result_d2h": {"count": 1, "total_s": 0.25},
-        "exec.AggExecutor": {"count": 3, "total_s": 2.0},
-        # push/spill are TRANSFER (exchange bookkeeping + HBQ spill d2h),
-        # matching the critical-path profiler's attribution
-        "push.input": {"count": 2, "total_s": 0.5},
-        "spill.hbq": {"count": 1, "total_s": 0.25},
-        "misc.thing": {"count": 1, "total_s": 0.125},
-    })
-    assert br == {"read_s": 1.0, "transfer_s": 1.5, "compute_s": 2.0,
-                  "other_s": 0.125}
-
-
 # -- acceptance e2e: wedged two-worker run -> flight dump --------------------
 
 

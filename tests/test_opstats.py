@@ -243,6 +243,43 @@ class TestExplainRendering:
         assert explain.operators_detail(None) is None
         assert explain.skew_flags(None) == []
 
+    def test_snapshot_is_the_operators_own_figures(self, ledger):
+        """No section joins static program costs against host seconds: the
+        snapshot and its rendering carry what the operators counted."""
+        _feed(ledger)
+        snap = ledger.snapshot("qtest")
+        assert "efficiency" not in snap
+        assert "efficiency" not in (ledger.on_query_gc("qtest") or {})
+        assert "efficiency" not in explain.render(snap)
+
+
+class TestSkewGaugeReset:
+    def test_global_skew_gauge_tracks_live_queries_only(self):
+        """Regression: the global shuffle.skew gauge was a process-lifetime
+        ratchet (set(max(old, new))) — one skewed query pinned it forever
+        and /health skew alerts never cleared.  It must drop to the worst
+        LIVE query at GC, and to 0 when idle."""
+        s = OpStats()
+        for qid in ("qa", "qb"):
+            s.register_plan(_Graph(qid, {
+                0: _Actor("input", targets=(1,), reader=_Reader(1 << 20)),
+                1: _Actor("exec", stage=1),
+            }))
+        # qa: 900/100 over 2 channels -> ratio 1.8; qb: 600/400 -> 1.2
+        s.edge("qa", 0, 1, 0, 900)
+        s.edge("qa", 0, 1, 1, 100)
+        s.edge("qb", 0, 1, 0, 600)
+        s.edge("qb", 0, 1, 1, 400)
+        s.snapshot("qa")
+        s.snapshot("qb")
+        g = obs.REGISTRY.gauge("shuffle.skew")
+        assert g.value == pytest.approx(1.8)
+        s.on_query_gc("qa")
+        assert g.value == pytest.approx(1.2)  # worst LIVE query, not ratchet
+        s.on_query_gc("qb")
+        assert g.value == 0.0
+        s.reset()
+
 
 def test_concurrent_recording_is_consistent(ledger):
     """The hot-path mutators race from engine worker threads; totals must

@@ -13,6 +13,9 @@ Three layers:
   colliding with the reserved salt name.
 """
 
+import os
+import sys
+
 import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
@@ -160,14 +163,14 @@ class TestPrecedence:
 
 
 class TestPlanFlip:
-    def _optimize(self, pq_env, monkeypatch, profile):
+    def _optimize(self, pq_env, monkeypatch, profile, how="inner"):
         from quokka_tpu.obs import opstats
 
         monkeypatch.setattr(opstats, "measured_sources", lambda: profile)
         fp, dp = pq_env
         ctx = QuokkaContext()
         q = ctx.read_parquet(fp).join(ctx.read_parquet(dp),
-                                      left_on="fk", right_on="pk")
+                                      left_on="fk", right_on="pk", how=how)
         sub, sid = _subplan(q)
         decide.begin_decisions()
         optimizer.optimize(sub, sid)
@@ -205,6 +208,28 @@ class TestPlanFlip:
         rec = [d for d in log if d["kind"] == "broadcast"][0]
         assert rec["basis"] == cost.BASIS_MEASURED
         assert rec["choice"] == "broadcast"
+
+
+    @pytest.mark.parametrize("how", ["inner", "semi", "anti", "left"])
+    @pytest.mark.parametrize("side", ["under", "over"])
+    def test_measured_build_follows_the_byte_rule(self, pq_env, monkeypatch,
+                                                  how, side):
+        """The choice is the measured build's bytes against
+        QK_BROADCAST_BYTES and nothing else: no estimate of seconds enters
+        it, and the record says so."""
+        limit = 1 << 20
+        monkeypatch.setenv("QK_BROADCAST_BYTES", str(limit))
+        nbytes = limit if side == "under" else limit + 1
+        joins, log = self._optimize(
+            pq_env, monkeypatch, _AnySig({"rows": 1000, "bytes": nbytes}),
+            how=how)
+        assert joins and joins[0].broadcast is (side == "under")
+        rec = [d for d in log if d["kind"] == "broadcast"]
+        assert rec == [{
+            "kind": "broadcast", "node": joins[0].describe(),
+            "choice": "broadcast" if side == "under" else "partition",
+            "basis": cost.BASIS_MEASURED, "build_rows": 1000,
+            "build_bytes": nbytes, "threshold_bytes": limit}]
 
 
 # -- the TPC-H flip: a recorded cardprofile flips Q3's orders build -----------
@@ -288,6 +313,95 @@ class TestTPCHQ3Flip:
         assert cs["n"].equals(ws["n"])
         assert np.allclose(cs["revenue"].to_numpy(),
                            ws["revenue"].to_numpy(), rtol=1e-9)
+
+
+# -- the benchmark's Q3 at its rehearsal size: the plan PR 29 recorded -------
+
+# benchmarks/queries/q3.py over benchmarks/datagen/tpch.py at sf 0.01, seed
+# 7, the first parameter set of the seed: the optimized plan's nodes in
+# lowering order and the choices behind them, as commit 30d78c1 planned it
+Q3_PLAN = [
+    "Source(InputParquetDataset, filter=(l_shipdate > date '1995-03-26'))",
+    "Source(InputParquetDataset, filter=(o_orderdate < date '1995-03-26'))",
+    "Source(InputParquetDataset, filter=(c_mktsegment = 'BUILDING'))",
+    "FusedStage(\n"
+    "  BroadcastJoin(inner, ['l_orderkey']=['o_orderkey'])\n"
+    "  BroadcastJoin(inner, ['o_custkey']=['c_custkey'])\n"
+    "  Agg(keys=['l_orderkey', 'o_orderdate', 'o_shippriority'],"
+    " out=['revenue'])\n)",
+    "TopK(['revenue'], k=10)",
+    "Collect",
+]
+Q3_JOINS = ["BroadcastJoin(inner, ['l_orderkey']=['o_orderkey'])",
+            "BroadcastJoin(inner, ['o_custkey']=['c_custkey'])"]
+# rows and bytes that run measured for the three scans (its cardprofile)
+Q3_MEASURED = {"l_shipdate": (31657, 1114112), "o_orderdate": (7378, 278528),
+               "c_mktsegment": (302, 36864)}
+
+
+@pytest.fixture(scope="module")
+def bench_q3(tmp_path_factory):
+    """plan() -> (the optimized plan's nodes in lowering order, the decision
+    records, the plan), over the benchmark's own files, read and not
+    touched."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        from harness import loadgen, spec, tables
+
+        config = spec.load_json(
+            os.path.join(bench, "configs", "tpch_sf1.json"))
+        traffic = spec.load_json(os.path.join(bench, "traffic", "q3_s2.json"))
+        gen = config["datagen"]
+        paths = tables.ensure(str(tmp_path_factory.mktemp("bench_q3") / "t"),
+                              gen["module"], gen["rehearsal_args"], 7)
+        q3 = spec.load_module("queries", "q3")
+        params = loadgen.plan(traffic, 7)["q3"][0]
+    finally:
+        sys.path.remove(bench)
+    svc = config["service"]
+
+    def plan():
+        ctx = QuokkaContext(io_channels=svc["io_channels"],
+                            exec_channels=svc["exec_channels"])
+        sub, sink = ctx._prepare_plan(q3.build(ctx, paths, params).node_id)
+        return ([sub[n].describe() for n in ctx._toposort(sub, sink)],
+                decide.take_decisions(), sub)
+
+    return plan
+
+
+def test_q3_rehearsal_plan_is_the_recorded_one(bench_q3, monkeypatch):
+    from quokka_tpu.obs import opstats
+
+    nodes, cold, sub = bench_q3()
+    assert nodes == Q3_PLAN
+    assert [(d["node"], d["choice"], d["basis"]) for d in cold] == [
+        (j, "broadcast", cost.BASIS_SAMPLED) for j in Q3_JOINS]
+    # the same plan once the scans have been measured: ordered and chosen
+    # by measured rows and bytes, with no key of seconds in any record
+    profile = {}
+    for n in sub.values():
+        if isinstance(n, logical.SourceNode):
+            rows, nbytes = next(v for k, v in Q3_MEASURED.items()
+                                if k in n.predicate.sql())
+            profile[cost.source_signature(
+                n.reader, n.predicate, n.projection)] = {
+                    "rows": rows, "bytes": nbytes}
+    assert len(profile) == 3
+    monkeypatch.setattr(opstats, "measured_sources", lambda: profile)
+    nodes, warm, _ = bench_q3()
+    assert nodes == Q3_PLAN
+    assert [d for d in warm if d["kind"] == "broadcast"] == [
+        {"kind": "broadcast", "node": j, "choice": "broadcast",
+         "basis": cost.BASIS_MEASURED, "build_rows": rows,
+         "build_bytes": nbytes, "threshold_bytes": 8 << 20}
+        for j, (rows, nbytes) in zip(
+            Q3_JOINS, (Q3_MEASURED["o_orderdate"],
+                       Q3_MEASURED["c_mktsegment"]))]
+    assert not [k for d in warm for k in d
+                if k.endswith("_s") or k.endswith("_s_basis")]
 
 
 # -- QK026: adaptive-exchange legality ----------------------------------------

@@ -355,9 +355,9 @@ class TestNamespacedStore:
 
 def test_two_way_runs_both_queries_concurrently(tpch_paths):
     """A two-worker service overlaps two submitted queries.  (Whether the
-    overlap beats running them back to back is a speed: `bench.py
-    --service` measures it; as a pass/fail host-clock race it failed on
-    any loaded box.)"""
+    overlap beats running them back to back is a speed: the benchmark's
+    two-client cells measure it on the chip; as a pass/fail host-clock race
+    it failed on any loaded box.)"""
     with QueryService(pool_size=2) as svc:
         h1 = svc.submit(q1_stream(QuokkaContext(), tpch_paths))
         h2 = svc.submit(q3_stream(QuokkaContext(), tpch_paths))
