@@ -434,15 +434,18 @@ class BuildProbeJoinExecutor(Executor):
             payload = [self.rename.get(c, c) for c in payload]
         self.payload = payload
         self.build = b
+        self.build_unique = join_ops.build_keys_unique(b, self.right_on)
         # build-side hash state is the largest single device residency a
-        # join pins; ledger it (query attribution happens at graph level —
-        # executors do not know their query id) and retire in done()
+        # join pins (the batch and, for a dense integer key, its
+        # direct-address table); ledger it (query attribution happens at
+        # graph level — executors do not know their query id) and retire
+        # in done()
         from quokka_tpu.obs import memplane
         from quokka_tpu.runtime.cache import _batch_nbytes
 
-        memplane.LEDGER.track(("join_build", id(self)), memplane.SITE_BUILD,
-                              _batch_nbytes(b))
-        self.build_unique = join_ops.build_keys_unique(b, self.right_on)
+        memplane.LEDGER.track(
+            ("join_build", id(self)), memplane.SITE_BUILD,
+            _batch_nbytes(b) + join_ops.direct_table_nbytes(b, self.right_on))
         # the strategy that will serve every probe batch of this build is
         # decided here — stamp it into the flight timeline so critpath
         # can attribute the probe pipeline to the kernel family
@@ -610,6 +613,10 @@ class BuildProbeJoinExecutor(Executor):
         memplane.LEDGER.retire(("join_build", id(self)))
         if self._disk:
             return self._disk_join()
+        # what the probes emitted shares nothing with the build: release it
+        # (and the key sort and direct-address table cached on it) now, not
+        # when a collector finds the finished query's graph
+        self.build = None
         return None
 
     def _probe(self, live):

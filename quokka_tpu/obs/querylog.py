@@ -35,7 +35,9 @@ Record keys (README "Observability" documents each):
   ``asof_probe_padded``, ``asof_quote_padded`` (the streaming asof join's
   chunk probes: how many, the trades they held, the slots they filled, and
   the padded quote rows each searched, summed: executors/ts_execs.py),
-  ``scan_hits``, ``scan_misses``;
+  ``join_probe_direct``, ``join_probe_search`` (padded probe slots the
+  sort branch of ``hash_join_pk`` answered from a direct-address table and
+  by binary search: ops/join.py), ``scan_hits``, ``scan_misses``;
 - ``pool_size`` and ``park_s_total``, ``loop_s_total``: the service's
   worker threads, and their cumulative counters when the query finished.
 """
@@ -85,14 +87,16 @@ COUNTS = ("tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
           "compile_misses", "rows_in", "padded_in", "rows_unknown",
           "agg_merges_compiled", "agg_merges_general", "asof_flushes",
           "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
-          "scan_hits", "scan_misses")
+          "join_probe_direct", "join_probe_search", "scan_hits",
+          "scan_misses")
 KEYS = (("q", "plan_fp", "status") + STAMPS + SECONDS + COUNTS
         + ("compiled", "pool_size", "park_s_total", "loop_s_total"))
 
 # summed over the operators of the finish-time opstats snapshot
 _FROM_OPSTATS = ("rows_in", "padded_in", "rows_unknown",
                  "agg_merges_compiled", "agg_merges_general", "asof_flushes",
-                 "asof_probe_rows", "asof_probe_padded", "asof_quote_padded")
+                 "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
+                 "join_probe_direct", "join_probe_search")
 
 _lock = threading.Lock()
 _open: Dict[str, dict] = {}   # live queries' accumulators

@@ -7,7 +7,9 @@ paths:
 
 - ``hash_join_pk``: build side has unique keys (the common TPC-H case —
   dimension/PK build sides).  Output is probe-aligned and mask-based: no host
-  sync, stays fully on device.
+  sync, stays fully on device.  On the sort branch a build whose key is one
+  dense integer limb is probed through a direct-address table (one gather a
+  probe row), every other build by binary search.
 - ``hash_join_general``: many-to-many.  Output size is computed on device and
   synced to the host once per batch to pick the output bucket, then a jitted
   expansion kernel gathers (probe_idx, build_idx) pairs.
@@ -19,7 +21,7 @@ Reference behavior being matched: BuildProbeJoinExecutor semantics
 from __future__ import annotations
 
 import functools
-from typing import List, Sequence, Tuple
+from typing import List, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,8 +87,9 @@ def _pk_probe_sorted(sorted_limbs, perm, n_valid, probe_limbs, probe_ok,
                      steps: int):
     """Probe a PRESORTED build with a vectorized lexicographic lower-bound:
     `steps` unrolled halvings, each one gather per limb — ~20 p-sized gathers
-    instead of re-sorting probe+build jointly per batch (the dominant join
-    cost at scale; a 2M-row multi-operand sort is ~100x a 1M gather)."""
+    instead of re-sorting probe+build jointly per batch.  The probe for
+    every key `_direct_table_cached` cannot hold: several limbs, strings,
+    floats, sparse or wide integers."""
     p = probe_limbs[0].shape[0]
     lo = jnp.zeros(p, dtype=jnp.int32)
     hi = jnp.broadcast_to(n_valid.astype(jnp.int32), (p,))
@@ -108,13 +111,21 @@ def _pk_probe_sorted(sorted_limbs, perm, n_valid, probe_limbs, probe_ok,
     return build_idx, matched
 
 
+def _batch_cache(build: DeviceBatch, attr: str) -> dict:
+    """A dict kept ON the batch object under `attr`, keyed by key columns:
+    what a finalized build derives once and every probe batch reuses."""
+    cache = getattr(build, attr, None)
+    if cache is None:
+        cache = {}
+        setattr(build, attr, cache)
+    return cache
+
+
 def _build_sorted_cached(build: DeviceBatch, build_keys: Sequence[str]):
     """Sorted-key view of a build table, cached ON the batch object: the
     probe executor joins the same finalized build against every probe batch
     (sql_execs.BuildProbeJoinExecutor), so the sort is paid once."""
-    cache = getattr(build, "_pk_sorted_cache", None)
-    if cache is None:
-        cache = build._pk_sorted_cache = {}
+    cache = _batch_cache(build, "_pk_sorted_cache")
     key = tuple(build_keys)
     hit = cache.get(key)
     if hit is None:
@@ -123,6 +134,128 @@ def _build_sorted_cached(build: DeviceBatch, build_keys: Sequence[str]):
         hit = cache[key] = compileplane.aot_kernel_call(
             "sort_build_keys", _sort_build_keys, (tuple(limbs), ok))
     return hit
+
+
+# A direct-address table may take this many int32 slots per padded build
+# row.  It is memory against gathers: 4 B x 32 = 128 B a build slot, a few
+# times the build's own columns, and never more than MAX_BUCKET's 64 MB.
+# dbgen's order keys use one value in four and Q3's date filter plus the
+# two-way exchange keep one order in four of those per channel (6.0 M of
+# span over 524,288 slots: 16), so 32 leaves one ladder rung for a filter
+# twice as selective; a build thinner than that is sparse and keeps the
+# search, whose ~20 gathers a row then cost less than the table's bytes.
+DIRECT_SLOTS_PER_BUILD_ROW = 32
+
+
+@jax.jit
+def _sorted_build_stats(sorted_limbs, n_valid):
+    """What the host reads once per finalised build, in one transfer: any
+    adjacent equal key pair within the valid prefix of the build sort, the
+    valid count, and the first limb's smallest and largest valid value
+    (the sort put them first and last)."""
+    n = sorted_limbs[0].shape[0]
+    eq = jnp.ones(n, dtype=bool)
+    for limb in sorted_limbs:
+        eq = eq & (limb == jnp.roll(limb, 1))
+    iota = jnp.arange(n, dtype=jnp.int32)
+    dup = jnp.any(eq & (iota >= 1) & (iota < n_valid))
+    first = sorted_limbs[0]
+    return dup, n_valid, first[0], first[jnp.maximum(n_valid - 1, 0)]
+
+
+class _BuildStats(NamedTuple):
+    dup: bool        # two valid rows share a key
+    n_ok: int        # valid rows with a non-null key
+    kmin: object     # the first limb's least and greatest valid value, as
+    kmax: object     # host numbers (python ints for an integer limb)
+    kmin_dev: jax.Array  # ... and as the device scalars they were read from
+    kmax_dev: jax.Array
+
+
+def _build_stats_cached(build: DeviceBatch,
+                        build_keys: Sequence[str]) -> _BuildStats:
+    """The one blocking read a finalised build pays on the sort branch,
+    cached on the batch and shared by `build_keys_unique` and the probe's
+    choice between table and search."""
+    cache = _batch_cache(build, "_pk_stats_cache")
+    key = tuple(build_keys)
+    hit = cache.get(key)
+    if hit is None:
+        sorted_limbs, _perm, n_valid = _build_sorted_cached(build, build_keys)
+        stats = _sorted_build_stats(tuple(sorted_limbs), n_valid)
+        dup, n_ok, kmin, kmax = jax.device_get(stats)
+        hit = cache[key] = _BuildStats(
+            bool(dup), int(n_ok), kmin.item(), kmax.item(), *stats[2:])
+    return hit
+
+
+@functools.partial(jax.jit, static_argnames=("size",))
+def _pk_direct_build(sorted_key, perm, n_valid, kmin, size: int):
+    """slot[key - kmin] = the build row holding `key`, -1 where none does.
+    One scatter over the build sort: the valid prefix's offsets ascend and
+    are unique (the caller saw no duplicate), and the invalid tail is sent
+    past the table's end, ascending too, where `drop` discards it."""
+    iota = jnp.arange(sorted_key.shape[0], dtype=jnp.int32)
+    slot = jnp.where(iota < n_valid, (sorted_key - kmin).astype(jnp.int32),
+                     size + iota)
+    return jnp.full(size, -1, dtype=jnp.int32).at[slot].set(
+        perm, mode="drop", indices_are_sorted=True, unique_indices=True)
+
+
+def _direct_table_cached(build: DeviceBatch, build_keys: Sequence[str]):
+    """(table, kmin, kmax) for a build whose key is one dense integer limb,
+    else None; decided once per build from what `_build_stats_cached` read
+    and cached on the batch.  The address is the key, so a probe row pays
+    one gather where the search pays ~20."""
+    cache = _batch_cache(build, "_pk_direct_cache")
+    key = tuple(build_keys)
+    if key not in cache:
+        cache[key] = _direct_table(build, build_keys)
+    return cache[key]
+
+
+def _direct_table(build: DeviceBatch, build_keys: Sequence[str]):
+    col = build.columns[build_keys[0]]
+    if not (len(build_keys) == 1 and isinstance(col, NumCol)
+            and col.kind in ("i", "d") and col.hi is None
+            and jnp.issubdtype(col.data.dtype, jnp.integer)):
+        return None
+    st = _build_stats_cached(build, build_keys)
+    span = st.kmax - st.kmin + 1  # python ints: no overflow
+    limit = min(config.MAX_BUCKET,
+                DIRECT_SLOTS_PER_BUILD_ROW * build.padded_len)
+    # the span first (`bucket_size` refuses one beyond MAX_BUCKET), then
+    # its rung: below the ladder's knee the next rung is 4x away
+    if st.n_ok == 0 or st.dup or span > limit:
+        return None
+    size = config.bucket_size(span)
+    if size > limit:
+        return None
+    (sorted_key,), perm, n_valid = _build_sorted_cached(build, build_keys)
+    table = compileplane.aot_kernel_call(
+        "pk_direct_build", _pk_direct_build,
+        (sorted_key, perm, n_valid, st.kmin_dev), (size,))
+    return table, st.kmin_dev, st.kmax_dev
+
+
+def direct_table_nbytes(build: DeviceBatch, build_keys: Sequence[str]) -> int:
+    """Device bytes of the direct-address table this build holds (0 without
+    one; reads the cache, never builds)."""
+    hit = getattr(build, "_pk_direct_cache", {}).get(tuple(build_keys))
+    return 0 if hit is None else int(hit[0].nbytes)
+
+
+@jax.jit
+def _pk_probe_direct(table, kmin, kmax, probe_key, probe_ok):
+    """Probe a direct-address table: one gather a probe row.  `matched` is
+    `_pk_probe_sorted`'s bit for bit, and so is `build_idx` on every matched
+    row (an unmatched row reads build row 0 under a false mask)."""
+    in_range = probe_ok & (probe_key >= kmin) & (probe_key <= kmax)
+    # the difference is only used where it fits the table: a wrap (a key
+    # far outside [kmin, kmax]) is masked, never an address
+    off = jnp.where(in_range, probe_key - kmin, 0).astype(jnp.int32)
+    slot = table[off]
+    return jnp.maximum(slot, 0), in_range & (slot >= 0)
 
 
 @functools.partial(jax.jit, static_argnames=("p",))
@@ -151,7 +284,10 @@ def hash_join_pk(
     """Join where build keys are unique.  Probe-aligned; the probe path has
     no host sync.  The cached build pays ONE scalar d2h per build batch (the
     hash-table convergence check, hashtable.build_table) — a diverged build
-    is remembered on the batch and every probe takes the sort path."""
+    is remembered on the batch and every probe takes the sort path, whose
+    one read per build (`_build_stats_cached`) also decides between the
+    direct-address table and the binary search."""
+    from quokka_tpu.obs import opstats
     from quokka_tpu.ops import strategy as kstrategy
 
     probe_limbs = key_limbs(probe, probe_keys)
@@ -182,15 +318,22 @@ def hash_join_pk(
         sorted_limbs, perm, n_valid = _build_sorted_cached(build, build_keys)
         assert len(probe_limbs) == len(sorted_limbs), \
             "join key column types must match"
-        steps = max(1, int(np.ceil(np.log2(max(2, build.padded_len)))) + 1)
-        build_idx, matched = compileplane.aot_kernel_call(
-            "pk_probe_sorted", _pk_probe_sorted,
-            (tuple(sorted_limbs), perm, n_valid,
-             tuple(l.astype(s.dtype)
-                   for l, s in zip(probe_limbs, sorted_limbs)),
-             probe_ok),
-            (steps,),
-        )
+        probe_limbs = tuple(l.astype(s.dtype)
+                            for l, s in zip(probe_limbs, sorted_limbs))
+        direct = _direct_table_cached(build, build_keys)
+        if direct is not None:
+            build_idx, matched = compileplane.aot_kernel_call(
+                "pk_probe_direct", _pk_probe_direct,
+                (*direct, probe_limbs[0], probe_ok))
+            opstats.note(join_probe_direct=probe.padded_len)
+        else:
+            steps = max(1, int(np.ceil(np.log2(max(2, build.padded_len)))) + 1)
+            build_idx, matched = compileplane.aot_kernel_call(
+                "pk_probe_sorted", _pk_probe_sorted,
+                (tuple(sorted_limbs), perm, n_valid, probe_limbs, probe_ok),
+                (steps,),
+            )
+            opstats.note(join_probe_search=probe.padded_len)
     if how == "semi":
         return kernels.apply_mask(probe, matched)
     if how == "anti":
@@ -332,18 +475,6 @@ def _distinct_from_table(tbl, ok):
             jnp.sum(ok.astype(jnp.int32)))
 
 
-@jax.jit
-def _sorted_has_dup(sorted_limbs, n_valid):
-    """Any adjacent equal key pair within the valid prefix of a build sort."""
-    dup = jnp.zeros((), dtype=bool)
-    eq = jnp.ones(sorted_limbs[0].shape[0], dtype=bool)
-    for limb in sorted_limbs:
-        eq = eq & (limb == jnp.roll(limb, 1))
-    iota = jnp.arange(sorted_limbs[0].shape[0], dtype=jnp.int32)
-    dup = jnp.any(eq & (iota >= 1) & (iota < n_valid))
-    return dup, n_valid
-
-
 def build_keys_unique(build: DeviceBatch, build_keys: Sequence[str]) -> bool:
     """Host-synced check whether the build side is PK-unique (decides fast
     path).  Called once per finalized build table, not per probe batch.
@@ -374,6 +505,9 @@ def build_keys_unique(build: DeviceBatch, build_keys: Sequence[str]) -> bool:
             distinct, n_ok = _distinct_from_table(table.tbl, ok)
             distinct, n_ok = int(distinct), int(n_ok)
             return distinct == n_ok and nvalid - n_ok <= 1
-    sorted_limbs, _perm, n_ok_dev = _build_sorted_cached(build, build_keys)
-    dup, n_ok = _sorted_has_dup(tuple(sorted_limbs), n_ok_dev)
-    return (not bool(dup)) and nvalid - int(n_ok) <= 1
+    st = _build_stats_cached(build, build_keys)
+    unique = (not st.dup) and nvalid - st.n_ok <= 1
+    if unique:
+        # the table the probes will use, built before the first one arrives
+        _direct_table_cached(build, build_keys)
+    return unique

@@ -451,6 +451,15 @@ SIG_BUDGETS = {
     "partial_agg_small": 2,
     "predicate": 3,
     "pk_probe_sorted": 4,
+    # the sort branch's direct-address table (ISSUE 31; read by
+    # tests/test_join_direct.py, which runs this plan with join_build=sort).
+    # The probe is keyed on (table rung, probe rung) where the search is on
+    # (build rung, probe rung, steps): the same two dimensions, the same 4
+    # (one table rung x the probe rungs `_coalesce` makes of four 16,384-row
+    # scan batches).  The build is keyed on (build rung, table rung): one a
+    # join, 2 where the two channels' shares of the build straddle a rung.
+    "pk_probe_direct": 4,
+    "pk_direct_build": 2,
     "ht_probe": 4,
     "gather": 24,
     "fused_concat": 10,

@@ -41,7 +41,8 @@ RECORD_KEYS = {
     "task_s", "tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
     "compile_misses", "rows_in", "padded_in", "rows_unknown",
     "agg_merges_compiled", "agg_merges_general", "asof_flushes",
-    "asof_probe_rows", "asof_probe_padded", "asof_quote_padded", "scan_hits",
+    "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
+    "join_probe_direct", "join_probe_search", "scan_hits",
     "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
 }
 IN_DISPATCH = ("runtime.dispatch_self", "executors.exec_self",

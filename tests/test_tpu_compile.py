@@ -17,6 +17,7 @@ one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +113,24 @@ def test_join_sorted_build_and_probe(chip):
     _compile(join._pk_probe_sorted, (chip("int32", b),), chip("int32", b),
              chip("int32", ()), (chip("int32"),), chip(bool),
              steps=b.bit_length())
+    # the same build's direct-address table (ISSUE 31): order keys span
+    # 6.0M, the 1<<23 rung.  What the claim rests on, read off the compiled
+    # text: the probe is ONE gather of the probe's length and no loop; the
+    # build is ONE scatter the compiler was told is sorted and unique (what
+    # it costs, only the chip says: PERF.md section 6, PR 31), and no sort
+    t = 8 * N
+    scalar = chip("int32", ())
+    probe = _compile(join._pk_probe_direct, chip("int32", t), scalar, scalar,
+                     chip("int32"), chip(bool)).as_text()
+    assert len(re.findall(r" gather\(", probe)) == 1
+    assert re.search(rf"s32\[{N}\]\S* gather\(", probe)
+    assert not re.search(r" (while|sort|scatter)\(", probe)
+    build = _compile(join._pk_direct_build, chip("int32", b),
+                     chip("int32", b), scalar, scalar, size=t).as_text()
+    scatters = re.findall(r" scatter\(.*", build)
+    assert len(scatters) == 1
+    assert "indices_are_sorted=true, unique_indices=true" in scatters[0]
+    assert not re.search(r" (while|sort|gather)\(", build)
 
 
 def test_asof_searchsorted(chip):
