@@ -15,6 +15,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 
 from quokka_tpu import config
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops import aggtail, bridge, kernels
 from quokka_tpu.ops import join as join_ops
 from quokka_tpu.ops.batch import DeviceBatch, NumCol
@@ -131,6 +132,12 @@ class SelectingStorageExecutor(StorageExecutor):
     """Terminal collect that also projects to the plan schema (picklable —
     the sink factory crosses process boundaries in the multi-worker runtime)."""
 
+    # one result batch a dispatch: nothing downstream reads a concatenated
+    # whole (each batch is copied to the host and the frames are joined
+    # there), and which of the channels' results are ready together follows
+    # timing, so a concat here is a program whose shape follows arrival
+    MAX_PIPELINE_BATCHES = 1
+
     def __init__(self, schema: Sequence[str]):
         self.schema = list(schema)
 
@@ -156,8 +163,9 @@ class _FoldsPartials:
         if not self._buffer:
             return  # state alone is already folded
         parts, self._buffer = self._buffer, []
-        self.state = aggtail.recombine(
-            self.keys, self.plan.recombine, parts, self.state)
+        with tracing.span("groupby.merge"):
+            self.state = aggtail.recombine(
+                self.keys, self.plan.recombine, parts, self.state)
 
 
 class PartialAggExecutor(_FoldsPartials, Executor):
@@ -168,7 +176,11 @@ class PartialAggExecutor(_FoldsPartials, Executor):
     # merge cadence: per-batch partials are buffered (uncompacted, with an
     # async live-count already in flight) and folded into the running state
     # every K batches — by merge time the counts have landed on the host, so
-    # compaction costs no blocking device round trip
+    # compaction costs no blocking device round trip.  A merge holds exactly
+    # K partials (the last one of a channel what is left): how many batches
+    # a dispatch happened to carry decides nothing, so the merges' shapes
+    # follow the channel's batch count and the partials' group counts, which
+    # the plan and the table fix
     MERGE_EVERY = 8
 
     # adaptive bailout: when the FIRST batch's group count is close to its
@@ -253,8 +265,8 @@ class PartialAggExecutor(_FoldsPartials, Executor):
                         groups >= self.PASSTHROUGH_RATIO * rows
                     )
             self._buffer.append(g)
-        if len(self._buffer) >= self.MERGE_EVERY:
-            self._merge()
+            if len(self._buffer) >= self.MERGE_EVERY:
+                self._merge()
         if not outs:
             return None
         return bridge.concat_batches(outs) if len(outs) > 1 else outs[0]
@@ -338,10 +350,11 @@ class FinalAggExecutor(_FoldsPartials, Executor):
                     cols[pname] = np.array([np.nan])
             self.state = bridge.arrow_to_device(pa.table(cols))
         g, self.state = self.state, None
-        out = aggtail.final_tail(g, self.keys, self.plan, self.having,
-                                 self.order_by, self.limit)
-        aggtail.note_path(out is not None)
-        return out if out is not None else self._tail_general(g)
+        with tracing.span("groupby.final"):
+            out = aggtail.final_tail(g, self.keys, self.plan, self.having,
+                                     self.order_by, self.limit)
+            aggtail.note_path(out is not None)
+            return out if out is not None else self._tail_general(g)
 
     def _tail_general(self, g: DeviceBatch) -> DeviceBatch:
         """The tail op by op: a large state, or finals that need the host."""

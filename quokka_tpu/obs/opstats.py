@@ -290,6 +290,12 @@ class OpStats:
                 return
             d = self._notes.setdefault(key, {})
             for name, v in figures.items():
+                if hasattr(v, "copy_to_host_async"):
+                    # a device count whose host copy is in flight: read at
+                    # the query's snapshot, never here
+                    d.setdefault(name, 0)
+                    self._pending.append(("note", key, name, v))
+                    continue
                 with contextlib.suppress(TypeError, ValueError):
                     d[name] = d.get(name, 0) + int(v)
 
@@ -308,19 +314,26 @@ class OpStats:
             _CUR.key = prev
 
     # -- deferred device-count resolution ------------------------------------
-    def resolve_pending(self) -> None:
+    def resolve_pending(self, notes_of: Optional[str] = None) -> None:
         """Turn queued device scalars into ints (their async host copies
         have long landed by the flush cadence) and fold them in.  A scalar
-        that fails to resolve is dropped — diagnostics never raise."""
+        that fails to resolve is dropped — diagnostics never raise.  An
+        executor's noted count may be the output of a program launched a
+        moment ago, behind a long device queue: those wait for a snapshot
+        of their own query (``notes_of``), never for the flush cadence."""
         with self._lock:
-            pend, self._pending = self._pending, []
+            later = [p for p in self._pending
+                     if p[0] == "note" and p[1][0] != notes_of]
+            pend = [p for p in self._pending
+                    if p[0] != "note" or p[1][0] == notes_of]
+            self._pending = later
         if not pend:
             return
         resolved = []
         for ent in pend:
             with contextlib.suppress(Exception):
-                if ent[0] == "op":
-                    resolved.append(("op", ent[1], ent[2], int(ent[3])))
+                if ent[0] in ("op", "note"):
+                    resolved.append((ent[0], ent[1], ent[2], int(ent[3])))
                 else:
                     resolved.append(("edge", ent[1], int(ent[2])))
         with self._lock:
@@ -329,6 +342,10 @@ class OpStats:
                     _, key, field, n = ent
                     if key[0] in self._plans:
                         self._rec(key)[field] += n
+                elif ent[0] == "note":
+                    _, key, name, n = ent
+                    if key in self._notes:
+                        self._notes[key][name] += n
                 else:
                     _, (qid, src, tgt, ch), n = ent
                     if qid in self._plans:
@@ -341,7 +358,7 @@ class OpStats:
         skew figures, top-N hot operators).  None for an unregistered id.
         Also refreshes the per-query ``opstats.*``/``shuffle.skew.*`` gauges
         (created here, GC'd in ``on_query_gc``)."""
-        self.resolve_pending()
+        self.resolve_pending(notes_of=qid)
         thresh = skew_ratio_threshold()
         with self._lock:
             plan = self._plans.get(qid)

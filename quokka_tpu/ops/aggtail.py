@@ -257,6 +257,10 @@ def _recombine_compiled(keys, ops, parts: List[DeviceBatch]) -> DeviceBatch:
     key_outs, agg_outs, gvalid, num = _dispatch_program(sig, builder, (
         tuple(data_parts), tuple(hi_parts), tuple(tables),
         tuple(p.valid for p in parts)))
+    if keys:
+        from quokka_tpu.obs import opstats
+
+        opstats.note(groupby_sort_slots=total, groupby_groups_out=num)
     cols = {}
     for name, (data, hi) in zip(keys, key_outs):
         c0 = first.columns[name]

@@ -47,6 +47,8 @@ from quokka_tpu.expression import (
 import numpy as np
 
 from quokka_tpu import config
+from quokka_tpu.obs import opstats
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops import expr_compile, kernels, sigkey
 from quokka_tpu.ops import strategy as kstrategy
 from quokka_tpu.ops.batch import DeviceBatch, NumCol, StrCol, gather_columns
@@ -196,8 +198,15 @@ class FusedPartialAgg:
       a 256-row bucket instead of the input's padded length (so everything
       downstream — shuffle, concat, recombine — shrinks by ~4000x).
     - GENERAL PATH: multi-operand lax.sort on key limbs + contiguous segment
-      reduces (random-order scatter-adds serialize badly on TPU: about
-      9 ms per 1<<20 updates on a v5e, one update at a time)."""
+      reduces.  On a v5e the sort is the cheap part, 1.4 ms a 1<<20-slot
+      batch; each segment reduction into 1<<20 segments still lowers to a
+      scatter the chip runs one update at a time, 8.8-9.2 ms per 1<<20
+      updates with sorted, contiguous ids (the random-order scatter-add of
+      PR 29 read 9 ms: ``indices_are_sorted`` buys nothing there), and each
+      gather by the sort's permutation 7.3-8.8 ms: 61.4 ms a batch for
+      three sums (PERF.md section 5, ``h2o_g1_1e7.q5_s2``).  Its program is
+      ``fused_groupby`` (module ``jit_fused_groupby``), apart from the
+      small-key path's and the predicate's ``fused``."""
 
     def __init__(self, keys: List[str], plan):
         self.keys = keys
@@ -281,14 +290,23 @@ class FusedPartialAgg:
             tuple((p, op, tmp) for p, op, tmp in self.plan.partials),
             bool(self.keys),
             use_tables,  # strategy is baked into the program
+            # names the program's function: keys carry no version of the
+            # code, so a persisted executable under the key without this
+            # part goes on showing a trace the module name ``jit_fused``
+            "fused_groupby",
         )
         kstrategy.note_used("groupby", gb_choice)
         builder = lambda: self._build(  # noqa: E731 — deferred to cache miss
             pre_exprs, list(num_inputs), sorted(pre.bound), len(key_limbs))
-        return self._invoke(
-            sig, builder, batch, pre, num_inputs, tuple(key_limbs),
-            batch.padded_len,
-        )
+        with tracing.span("groupby.partial"):
+            out = self._invoke(
+                sig, builder, batch, pre, num_inputs, tuple(key_limbs),
+                batch.padded_len,
+            )
+        if self.keys:  # a global aggregate sorts nothing
+            opstats.note(groupby_sort_slots=batch.padded_len,
+                         groupby_groups_out=out.nrows_dev)
+        return out
 
     def _invoke(self, sig, builder, batch, pre, num_inputs, key_arrays,
                 out_pad):
@@ -319,7 +337,7 @@ class FusedPartialAgg:
         has_keys = bool(self.keys)
 
         @jax.jit
-        def fused(num_arrays, hi_arrays, bound_arrays, limbs, valid):
+        def fused_groupby(num_arrays, hi_arrays, bound_arrays, limbs, valid):
             n = valid.shape[0]
             cols = {}
             for name, arr, hi in zip(num_names, num_arrays, hi_arrays):
@@ -345,7 +363,7 @@ class FusedPartialAgg:
                 outs, counts, rep = kernels._segment_aggs(ranks, valid, arrays, ops)
             return (*outs, rep, num)
 
-        return fused
+        return fused_groupby
 
     def _call_small(self, batch, pre, pre_exprs, num_inputs, dims,
                     use_tables: bool):

@@ -336,6 +336,10 @@ def groupby_aggregate(
     for (name, _, _), arr in zip(aggs, outs):
         cols[name] = NumCol(arr, "f" if jnp.issubdtype(arr.dtype, jnp.floating) else "i")
     group_valid = jnp.arange(n) < num
+    if keys:
+        from quokka_tpu.obs import opstats
+
+        opstats.note(groupby_sort_slots=n, groupby_groups_out=num)
     return DeviceBatch(cols, group_valid, None, None).note_count(num)
 
 

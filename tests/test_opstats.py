@@ -165,6 +165,39 @@ class TestLedger:
         op1 = next(o for o in snap["operators"] if o["actor"] == 1)
         assert op1["join_build_rows"] == 42
 
+    def test_note_defers_a_device_count_to_the_snapshot(self, ledger):
+        """A device scalar handed to ``note`` is never read in the dispatch:
+        it waits with the rows' counts and lands in the snapshot, summed
+        with the host-known figures of the same name."""
+        class _Scalar(_Dev):
+            def __int__(self):
+                reads.append(self._n)
+                return self._n
+
+            def copy_to_host_async(self):
+                pass
+
+        qid, reads = "qnotedev", []
+        ledger.register_plan(_two_stage_graph(qid))
+        orig = opstats.OPSTATS
+        opstats.OPSTATS = ledger
+        try:
+            with ledger.current_op(qid, 1, 0):
+                opstats.note(groupby_groups_out=_Scalar(70),
+                             groupby_sort_slots=128)
+                opstats.note(groupby_groups_out=5)
+                opstats.note(groupby_groups_out=_Scalar(25))
+            ledger.resolve_pending()  # the flush cadence leaves them be
+            assert reads == []
+            with ledger.current_op("ghost", 1, 0):
+                opstats.note(groupby_groups_out=_Scalar(999))  # unregistered
+        finally:
+            opstats.OPSTATS = orig
+        snap = ledger.snapshot(qid)
+        op1 = next(o for o in snap["operators"] if o["actor"] == 1)
+        assert op1["groupby_groups_out"] == 100 and sorted(reads) == [25, 70]
+        assert op1["groupby_sort_slots"] == 128
+
     def test_gc_drops_state_keeps_last_snapshot(self, ledger):
         _feed(ledger)
         snap = ledger.on_query_gc("qtest", plan_fp=None)

@@ -96,6 +96,26 @@ def test_sort_groupby(chip):
              (chip("float32"), chip("int32")), ("sum", "count"), chip(bool))
 
 
+def test_sort_groupby_at_the_h2o_q5_shape(chip):
+    """``h2o_g1_1e7.q5_s2``'s partial and its largest merge: one int32 key
+    limb, sums of two int32 columns and a float32 one over 1<<20 slots.
+    What the chip's trace charges it (PERF.md section 5, PR 32) is in the
+    module's text: one sort (1.4 ms), the five segment reductions (count,
+    representative, three sums) as ``scatter``s (8.8-9.2 ms each, sorted
+    ids or not) and the values by the permutation as three ``gather``s.
+    The ``perf_opt`` that turns them into scans changes these counts."""
+    from quokka_tpu.ops import kernels
+
+    compiled = _compile(
+        kernels.sorted_groupby, (chip("int32"),),
+        (chip("int32"), chip("int32"), chip("float32")),
+        ("sum", "sum", "sum"), chip(bool))
+    text = compiled.as_text()
+    ops = {op: len(re.findall(rf"\b{op}\(", text))
+           for op in ("sort", "scatter", "gather")}
+    assert ops == {"sort": 1, "scatter": 5, "gather": 3}
+
+
 def test_sort_and_top_k(chip):
     from quokka_tpu.ops import kernels
 
