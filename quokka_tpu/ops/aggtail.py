@@ -249,6 +249,7 @@ def _recombine_compiled(keys, ops, parts: List[DeviceBatch]) -> DeviceBatch:
         tuple(sigkey.col_sig(n, first.columns[n]) for n in names),
         tuple((int(t[0].shape[1]), int(t[1].shape[0])) for t in tables),
         len(keys), op_names, out_rows, gb_choice,
+        kernels.SORTED_GROUPBY_FORM,  # the traced group-by body
     )
     kstrategy.note_used("groupby", gb_choice)
     str_keys = tuple(isinstance(first.columns[k], StrCol) for k in keys)

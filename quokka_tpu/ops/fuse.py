@@ -197,14 +197,15 @@ class FusedPartialAgg:
       too (Q1 has three).  No sort and no scatter, and the output batch is
       a 256-row bucket instead of the input's padded length (so everything
       downstream — shuffle, concat, recombine — shrinks by ~4000x).
-    - GENERAL PATH: multi-operand lax.sort on key limbs + contiguous segment
-      reduces.  On a v5e the sort is the cheap part, 1.4 ms a 1<<20-slot
-      batch; each segment reduction into 1<<20 segments still lowers to a
-      scatter the chip runs one update at a time, 8.8-9.2 ms per 1<<20
-      updates with sorted, contiguous ids (the random-order scatter-add of
-      PR 29 read 9 ms: ``indices_are_sorted`` buys nothing there), and each
-      gather by the sort's permutation 7.3-8.8 ms: 61.4 ms a batch for
-      three sums (PERF.md section 5, ``h2o_g1_1e7.q5_s2``).  Its program is
+    - GENERAL PATH: ``kernels.sorted_groupby``: sort, scans, sort.  One stable
+      multi-operand lax.sort on the key limbs carries the aggregates'
+      inputs, the contiguous segments reduce by prefix sums and segmented
+      scans, a second sort on one key compacts the groups to rank order.
+      On a v5e a 1<<20-slot batch with three sums is 4.99 ms, the two sorts
+      2.34 and 1.92 of it (PERF.md section 5, ``h2o_g1_1e7.q5_s2``, PR 33);
+      as segment reductions (scatters at 8.8-9.2 ms per 1<<20 updates,
+      sorted ids or not) and gathers by the permutation (7.3-8.8 ms each)
+      it was 61.4.  Its program is
       ``fused_groupby`` (module ``jit_fused_groupby``), apart from the
       small-key path's and the predicate's ``fused``."""
 
@@ -294,6 +295,7 @@ class FusedPartialAgg:
             # code, so a persisted executable under the key without this
             # part goes on showing a trace the module name ``jit_fused``
             "fused_groupby",
+            kernels.SORTED_GROUPBY_FORM,  # the traced group-by body
         )
         kstrategy.note_used("groupby", gb_choice)
         builder = lambda: self._build(  # noqa: E731 — deferred to cache miss

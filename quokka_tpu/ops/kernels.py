@@ -5,11 +5,13 @@ Design notes (TPU-first):
   and carry a validity mask.  Filtering flips mask bits; compaction (which needs
   a host sync for the live count) happens only at batch boundaries (shuffle,
   output), mirroring where the reference engine synchronizes anyway.
-- Group-by uses a sort + segment-reduce plan ("dense rank"): sort rows by key
-  limbs, mark group starts, prefix-sum to get dense segment ids, then
-  jax.ops.segment_* with num_segments = padded length.  This replaces the
-  hash-table group-bys Polars does on CPU (SURVEY.md section 2.2) with a plan that
-  maps onto XLA's sort and scatter-add, which tile well on TPU.
+- Group-by is "sort, scans, sort" (sorted_groupby): sort rows by key limbs
+  with the aggregates' inputs as sort operands, mark group starts, reduce the
+  contiguous segments by prefix sums and segmented scans, and compact to dense
+  rank order by a second sort.  This replaces the hash-table group-bys Polars
+  does on CPU (SURVEY.md section 2.2) with a plan made of XLA's sort and scans:
+  on the TPU a sort of 1<<20 rows costs about a millisecond, while a scatter
+  or a gather costs 7-9 ns an element whatever the order of its indices.
 - Multi-column / string / wide-int keys are lists of 32-bit "limbs"
   (ops/batch.key_limbs); lexicographic multi-operand lax.sort handles them
   without 64-bit device ints.
@@ -170,69 +172,146 @@ def dense_rank(limbs: Sequence[jax.Array], valid: jax.Array):
 
 AGG_OPS = ("sum", "count", "min", "max", "mean", "first")
 
+# Names ``sorted_groupby``'s body in the key of every persisted program that
+# traces it (``partial_agg`` in ops/fuse.py, ``agg_recombine`` in
+# ops/aggtail.py): program keys carry no version of the code, so an edit of
+# the body changes this string, or the AOT store goes on running the old
+# executable under the unchanged key.
+SORTED_GROUPBY_FORM = "sort_scan_sort"
+
+
+def _reduce_to_segment_starts(x, rank, combine, ident):
+    """Segmented reduction over sorted, contiguous segments as log2(n)
+    shifted steps: after the loop the FIRST row of each segment (rows of
+    equal ``rank``) holds ``combine`` over the whole segment.  A row only
+    ever reads rows of its own segment, so a group's rounding error grows
+    with the group (a pairwise tree) and not with the rows before it, and a
+    NaN or an inf stays in its group."""
+    n = x.shape[0]
+    ident = jnp.asarray(ident, x.dtype)
+    d = 1
+    while d < n:
+        same = jnp.concatenate([rank[d:], jnp.full(d, -2, rank.dtype)]) == rank
+        ahead = jnp.concatenate([x[d:], jnp.full(d, ident, x.dtype)])
+        x = combine(x, jnp.where(same, ahead, ident))
+        d *= 2
+    return x
+
+
+def _diff_next(at_starts, live, end):
+    """``at_starts[r]`` is a running quantity read where group ``r`` starts
+    and ``end`` the same quantity past the last group: a group's own share is
+    the next group's reading less its own (0 for the slots past ``live``)."""
+    e = jnp.where(live, at_starts, end)
+    return jnp.concatenate([e[1:], end[None]]) - e
+
 
 @functools.partial(jax.jit, static_argnames=("ops",))
 def sorted_groupby(limbs: Tuple[jax.Array, ...], arrays: Tuple[jax.Array, ...],
                    ops: Tuple[str, ...], valid: jax.Array):
-    """Group-by-aggregate in sorted segment order.
+    """Group-by-aggregate in sorted segment order: sort, scans, sort.
 
-    One multi-operand sort, then segment reductions over CONTIGUOUS segments
-    (indices_are_sorted=True) — this avoids random-order scatter-adds, which
-    serialize badly on TPU.  Returns (agg_outputs, counts, rep_indices, num):
-    outputs indexed by dense rank, `rep` maps rank -> an original row index
-    holding the group's key values."""
+    No indexed op (a ``scatter`` or a ``gather`` costs the TPU 7-9 ns an
+    element, sorted ids or not; a sort of 1<<20 rows about a millisecond):
+
+    1. one multi-operand sort by (invalid, *limbs) that carries the row
+       index and the aggregates' inputs, so nothing is gathered by the
+       permutation.  ``lax.sort`` is stable (``is_stable=True`` is its
+       default), so the row index ascends inside a group and the one at a
+       segment's first row is the group's least original index: ``rep``;
+    2. reductions over the contiguous segments as scans: integer sums and
+       counts are a prefix sum differenced at the group boundaries (exact
+       under two's-complement wrap whenever the group's own sum fits);
+       float sums and every min / max are a segmented scan
+       (``_reduce_to_segment_starts``), never a differenced float prefix;
+    3. compaction to rank order as a second sort on one key that puts the
+       segments' first rows first, in order: slot r holds group r.
+
+    Returns (agg_outputs, counts, rep_indices, num): outputs indexed by dense
+    rank and padded to the input length, ``rep`` maps rank -> the least
+    original row index of the group (it holds the group's key values)."""
     n = valid.shape[0]
     iota = jnp.arange(n, dtype=jnp.int32)
     inv = (~valid).astype(jnp.int32)
-    sorted_ops = lax.sort([inv, *limbs, iota], num_keys=1 + len(limbs))
-    perm = sorted_ops[-1]
+    # what each aggregate reads, (reduction, input), and the inputs that ride
+    # the sort, each once; count(*) and the count of an integer column read
+    # the segment lengths alone
+    wants, slot_of, carried = [], {}, []
+    for arr, op in zip(arrays, ops):
+        if op not in AGG_OPS:
+            raise ValueError(f"unknown agg {op}")
+        if op == "count" and not jnp.issubdtype(arr.dtype, jnp.floating):
+            wants.append(None)
+            continue
+        if id(arr) not in slot_of:
+            slot_of[id(arr)] = len(carried)
+            carried.append(arr)
+        kind = {"count": "notnan", "mean": "sum"}.get(op, op)
+        wants.append((kind, slot_of[id(arr)]))
+    nk = 1 + len(limbs)
+    sorted_ops = lax.sort([inv, *limbs, iota, *carried], num_keys=nk)
     valid_s = sorted_ops[0] == 0
+    perm = sorted_ops[nk]
     changed = jnp.zeros(n, dtype=bool)
-    for limb_sorted in sorted_ops[1:-1]:
+    for limb_sorted in sorted_ops[1:nk]:
         changed = changed | (limb_sorted != jnp.roll(limb_sorted, 1))
     starts = valid_s & (changed | (iota == 0))
-    ranks_sorted = jnp.maximum(jnp.cumsum(starts.astype(jnp.int32)) - 1, 0)
-    num = jnp.max(jnp.where(valid_s, ranks_sorted, -1)) + 1
-    counts = jax.ops.segment_sum(
-        valid_s.astype(jnp.int32), ranks_sorted, num_segments=n, indices_are_sorted=True
-    )
-    rep = jax.ops.segment_min(
-        jnp.where(valid_s, perm, n - 1), ranks_sorted, num_segments=n,
-        indices_are_sorted=True,
-    )
-    outs = []
-    for arr, op in zip(arrays, ops):
-        arr_s = arr[perm]
-        if op == "count":
-            if jnp.issubdtype(arr.dtype, jnp.floating):
-                c = jax.ops.segment_sum(
-                    (valid_s & ~jnp.isnan(arr_s)).astype(jnp.int32),
-                    ranks_sorted, num_segments=n, indices_are_sorted=True,
-                )
+    # invalid rows sort last and keep the last group's rank: every column
+    # below holds its reduction's identity there
+    rank = jnp.cumsum(starts.astype(jnp.int32)) - 1
+    num = rank[-1] + 1
+    nvalid = jnp.sum(valid_s, dtype=jnp.int32)
+
+    # one column per distinct want, to be read where each segment starts.
+    # ``tails[i]`` says how: ("prefix", its reading past the last row) is
+    # differenced; ("value", what an empty segment reduces to) is taken
+    col_of, cols, tails = {}, [], []
+    for want in dict.fromkeys(w for w in wants if w is not None):
+        kind, slot = want
+        x = sorted_ops[nk + 1 + slot]
+        zero = jnp.zeros((), x.dtype)
+        floating = jnp.issubdtype(x.dtype, jnp.floating)
+        if kind == "first":
+            c, tail = x, ("value", zero)
+        elif kind == "notnan" or (kind == "sum" and not floating):
+            if kind == "notnan":
+                x = (valid_s & ~jnp.isnan(x)).astype(jnp.int32)
             else:
-                c = counts
-            outs.append(c)
-        elif op == "sum":
-            x = jnp.where(valid_s, arr_s, jnp.zeros((), arr.dtype))
-            outs.append(jax.ops.segment_sum(x, ranks_sorted, num_segments=n,
-                                            indices_are_sorted=True))
-        elif op == "mean":
-            x = jnp.where(valid_s, arr_s, jnp.zeros((), arr.dtype))
-            s = jax.ops.segment_sum(x, ranks_sorted, num_segments=n,
-                                    indices_are_sorted=True)
-            outs.append(s / jnp.maximum(counts, 1).astype(s.dtype))
-        elif op == "min":
-            x = jnp.where(valid_s, arr_s, _max_sentinel(arr.dtype))
-            outs.append(jax.ops.segment_min(x, ranks_sorted, num_segments=n,
-                                            indices_are_sorted=True))
-        elif op == "max":
-            x = jnp.where(valid_s, arr_s, _min_sentinel(arr.dtype))
-            outs.append(jax.ops.segment_max(x, ranks_sorted, num_segments=n,
-                                            indices_are_sorted=True))
-        elif op == "first":
-            outs.append(arr[rep])
-        else:
-            raise ValueError(f"unknown agg {op}")
+                x = jnp.where(valid_s, x, zero)
+            incl = jnp.cumsum(x, dtype=x.dtype)
+            c, tail = incl - x, ("prefix", incl[-1])
+        else:  # a float sum, a min or a max: never a differenced prefix
+            combine, ident = {
+                "sum": (jnp.add, zero),
+                "min": (jnp.minimum, _max_sentinel(x.dtype)),
+                "max": (jnp.maximum, _min_sentinel(x.dtype)),
+            }[kind]
+            c = _reduce_to_segment_starts(
+                jnp.where(valid_s, x, ident), rank, combine, ident)
+            tail = ("value", ident)
+        col_of[want] = len(cols)
+        cols.append(c)
+        tails.append(tail)
+
+    # keys are unique, so the second sort needs no stability
+    order = jnp.where(starts, iota, n + iota)
+    pos, rep, *cols = lax.sort([order, perm, *cols], num_keys=1,
+                               is_stable=False)
+    live = iota < num
+    counts = _diff_next(pos, live, nvalid)
+    rep = jnp.where(live, rep, n - 1)
+
+    outs = []
+    for op, want in zip(ops, wants):
+        if want is None:
+            outs.append(counts)
+            continue
+        c, (how, at_end) = cols[col_of[want]], tails[col_of[want]]
+        out = (_diff_next(c, live, at_end) if how == "prefix"
+               else jnp.where(live, c, at_end))
+        if op == "mean":
+            out = out / jnp.maximum(counts, 1).astype(out.dtype)
+        outs.append(out)
     return tuple(outs), counts, rep, num
 
 

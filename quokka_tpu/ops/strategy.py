@@ -29,7 +29,8 @@ and the decision is MEASURED, not asserted:
 
 Operators and choices:
 
-  groupby     sort | hashtable      (kernels.sorted_groupby vs
+  groupby     sort | hashtable      (kernels.sorted_groupby: sort, scans,
+                                     sort, no indexed op, vs
                                      hashtable.hash_groupby)
   join_build  sort | hashtable      (join._pk_probe_sorted vs
                                      hashtable build_table/pk_probe)

@@ -86,34 +86,43 @@ def test_compact_and_split(chip):
     _compile(kernels._mask_and_count, chip(bool), chip(bool))
 
 
+def _indexed_ops(compiled):
+    text = compiled.as_text()
+    return {op: len(re.findall(rf"\b{op}\(", text))
+            for op in ("sort", "scatter", "gather")}
+
+
 def test_sort_groupby(chip):
     """The sort group-by the TPU takes where the CPU takes a hash table:
     one key limb, a float sum and a count (the tick query's per-symbol
-    aggregate at one scan batch)."""
+    aggregate at one scan batch).  Sort, scans, sort: nothing indexed."""
     from quokka_tpu.ops import kernels
 
-    _compile(kernels.sorted_groupby, (chip("int32"),),
-             (chip("float32"), chip("int32")), ("sum", "count"), chip(bool))
+    compiled = _compile(
+        kernels.sorted_groupby, (chip("int32"),),
+        (chip("float32"), chip("int32")), ("sum", "count"), chip(bool))
+    assert _indexed_ops(compiled) == {"sort": 2, "scatter": 0, "gather": 0}
 
 
 def test_sort_groupby_at_the_h2o_q5_shape(chip):
     """``h2o_g1_1e7.q5_s2``'s partial and its largest merge: one int32 key
     limb, sums of two int32 columns and a float32 one over 1<<20 slots.
-    What the chip's trace charges it (PERF.md section 5, PR 32) is in the
-    module's text: one sort (1.4 ms), the five segment reductions (count,
-    representative, three sums) as ``scatter``s (8.8-9.2 ms each, sorted
-    ids or not) and the values by the permutation as three ``gather``s.
-    The ``perf_opt`` that turns them into scans changes these counts."""
+    What the chip's trace charges it is in the module's text.  Until PR 33
+    one sort, five ``scatter``s (the segment reductions, 8.8-9.2 ms each,
+    sorted ids or not) and three ``gather``s (the values by the
+    permutation, 7.3-7.5 ms each): 61.4 ms a batch.  Since PR 33 (PERF.md
+    section 6) the values ride a six-operand sort, the reductions are
+    prefix sums and one segmented scan, and a five-operand sort on one key
+    compacts to rank order: two sorts and no indexed op, 4.99 ms a batch
+    on the chip (the sorts 2.34 and 1.92 ms, three ``cumsum``s 0.18 each,
+    the float sum's 20 shifted steps under 0.1)."""
     from quokka_tpu.ops import kernels
 
     compiled = _compile(
         kernels.sorted_groupby, (chip("int32"),),
         (chip("int32"), chip("int32"), chip("float32")),
         ("sum", "sum", "sum"), chip(bool))
-    text = compiled.as_text()
-    ops = {op: len(re.findall(rf"\b{op}\(", text))
-           for op in ("sort", "scatter", "gather")}
-    assert ops == {"sort": 1, "scatter": 5, "gather": 3}
+    assert _indexed_ops(compiled) == {"sort": 2, "scatter": 0, "gather": 0}
 
 
 def test_sort_and_top_k(chip):
