@@ -23,14 +23,12 @@ from quokka_tpu.ops.expr_compile import AggPlan, evaluate_predicate, evaluate_to
 from quokka_tpu.executors.base import Executor
 
 
-def _coalesce(live: List[DeviceBatch],
-              cap_rows: int = 1 << 22) -> List[DeviceBatch]:
+def _coalesce(live: List[DeviceBatch], cap_rows: int) -> List[DeviceBatch]:
     """Concat a dispatch's ready batches into few compacted batches so the
-    per-batch kernel chains (group-by sort, join probe) run once over a
-    bucketed whole instead of once per per-partition slice.  Bounded by
-    accumulated PADDED rows so one group can never overflow MAX_BUCKET (or
-    spike device memory) regardless of how many batches the planner
-    delivered."""
+    group-by's per-batch kernel chain runs once over a bucketed whole
+    instead of once per per-partition slice.  Bounded by accumulated PADDED
+    rows: only batches small enough that the chain's launches cost more than
+    the concat are joined (the join probe takes every batch alone)."""
     if len(live) <= 1:
         return live
     groups: List[List[DeviceBatch]] = []
@@ -44,6 +42,25 @@ def _coalesce(live: List[DeviceBatch],
         acc += b.padded_len
     groups.append(cur)
     return [bridge.concat_batches(g) if len(g) > 1 else g[0] for g in groups]
+
+
+# `kernels.compact_if_large`'s threshold: below it the blocking read of the
+# live count costs more than the slack rows
+SHRINK_ABOVE = 1 << 16
+
+
+def _shrunk(batch: DeviceBatch) -> DeviceBatch:
+    """A large batch whose live rows would fit a bucket a quarter of its
+    padded length or smaller (what a selective join or filter leaves of a
+    scan batch), compacted to that bucket; every other batch as it is.  The
+    compaction (one mask scan, one gather a column at the small bucket) is
+    repaid by the first gather or search over the padded length it saves;
+    at half the length it is not.  The count is the batch's own, so the
+    shape is a function of the table and the plan."""
+    if (batch.padded_len <= SHRINK_ABOVE or
+            4 * config.bucket_size(batch.count_valid()) > batch.padded_len):
+        return batch
+    return kernels.compact(batch)
 
 
 class UDFExecutor(Executor):
@@ -387,6 +404,11 @@ class BuildProbeJoinExecutor(Executor):
     completes before the first probe batch arrives (the reference asserts the
     same invariant, sql_executors.py:357)."""
 
+    # one probe batch a dispatch, so one output a dispatch and no concat of
+    # outputs: which batches are ready together follows timing, and a
+    # program over their concat would have a shape that does too
+    MAX_PIPELINE_BATCHES = 1
+
     def __init__(
         self,
         left_on: Sequence[str],
@@ -475,7 +497,7 @@ class BuildProbeJoinExecutor(Executor):
         # record (padded length — host-known, never a device sync)
         from quokka_tpu.obs import opstats
 
-        opstats.note(join_build_rows=b.padded_len)
+        opstats.note(join_build_rows=b.padded_len, join_builds=1)
 
     def execute(self, batches, stream_id, channel):
         live = [b for b in batches if b is not None]
@@ -634,16 +656,12 @@ class BuildProbeJoinExecutor(Executor):
 
     def _probe(self, live):
         if self.build is None and self.build_parts:
-            self._finalize_build(live[0].names)
+            with tracing.span("join.build"):
+                self._finalize_build(live[0].names)
         from quokka_tpu.obs import opstats
 
         opstats.note(join_probe_rows=sum(
             b.nrows if b.nrows is not None else b.padded_len for b in live))
-        # vectorized probe pipeline: the dispatch's whole ready set flows
-        # through ONE bucketed join call instead of one kernel chain per
-        # per-partition batch (their async live counts have landed by now,
-        # so the concat compacts without blocking round trips)
-        live = _coalesce(live)
         if self.build is None:
             # No build batch ever arrived on this channel.  Engine.push always
             # delivers every hash partition (even zero-valid ones), so this
@@ -676,18 +694,22 @@ class BuildProbeJoinExecutor(Executor):
             return None
         # empty-but-schema'd build: anti/left fall through — the general join
         # kernel handles a zero-valid build (every probe row unmatched)
+        # every batch is probed alone, at a shape its own content fixes: a
+        # concat of whatever one dispatch carried would make the probe's and
+        # every later program's shape follow arrival
         outs = []
-        for probe in live:
-            if self.build_unique and self.how in ("inner", "semi", "anti"):
-                out = join_ops.hash_join_pk(
-                    probe, self.build, self.left_on, self.right_on, self.how, self.payload
-                )
-            else:
-                out = join_ops.hash_join_general(
-                    probe, self.build, self.left_on, self.right_on, self.how, self.payload
-                )
-            if out is not None:
-                outs.append(out)
+        with tracing.span("join.probe"):
+            for probe in map(_shrunk, live):
+                if self.build_unique and self.how in ("inner", "semi", "anti"):
+                    out = join_ops.hash_join_pk(
+                        probe, self.build, self.left_on, self.right_on,
+                        self.how, self.payload)
+                else:
+                    out = join_ops.hash_join_general(
+                        probe, self.build, self.left_on, self.right_on,
+                        self.how, self.payload)
+                if out is not None:
+                    outs.append(out)
         if not outs:
             return None
         return bridge.concat_batches(outs) if len(outs) > 1 else outs[0]

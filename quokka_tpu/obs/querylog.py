@@ -37,7 +37,11 @@ Record keys (README "Observability" documents each):
   the padded quote rows each searched, summed: executors/ts_execs.py),
   ``join_probe_direct``, ``join_probe_search`` (padded probe slots the
   sort branch of ``hash_join_pk`` answered from a direct-address table and
-  by binary search: ops/join.py), ``groupby_sort_slots``,
+  by binary search: ops/join.py), ``join_probe_general`` (padded probe slots
+  through the many-to-many join), ``join_builds`` (builds the join executors
+  finalised, one a join and channel: executors/sql_execs.py),
+  ``str_pred_dict_rows`` (dictionary entries a string predicate walked on
+  the host: ops/expr_compile.py), ``groupby_sort_slots``,
   ``groupby_groups_out`` (the general group-by: padded slots its partial
   aggregates and merges sorted, and the groups they emitted: ops/fuse.py,
   ops/aggtail.py, ops/kernels.py), ``scan_hits``, ``scan_misses``;
@@ -90,7 +94,8 @@ COUNTS = ("tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
           "compile_misses", "rows_in", "padded_in", "rows_unknown",
           "agg_merges_compiled", "agg_merges_general", "asof_flushes",
           "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
-          "join_probe_direct", "join_probe_search", "groupby_sort_slots",
+          "join_probe_direct", "join_probe_search", "join_probe_general",
+          "join_builds", "str_pred_dict_rows", "groupby_sort_slots",
           "groupby_groups_out", "scan_hits", "scan_misses")
 KEYS = (("q", "plan_fp", "status") + STAMPS + SECONDS + COUNTS
         + ("compiled", "pool_size", "park_s_total", "loop_s_total"))
@@ -100,6 +105,7 @@ _FROM_OPSTATS = ("rows_in", "padded_in", "rows_unknown",
                  "agg_merges_compiled", "agg_merges_general", "asof_flushes",
                  "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
                  "join_probe_direct", "join_probe_search",
+                 "join_probe_general", "join_builds", "str_pred_dict_rows",
                  "groupby_sort_slots", "groupby_groups_out")
 
 _lock = threading.Lock()

@@ -51,7 +51,8 @@ _now = time.perf_counter
 
 # in-dispatch span name -> the query record's layer key (obs/querylog.py)
 _LAYER_PREFIXES = (
-    (("exec.", "done.", "asof.", "groupby."), "executors.exec_self"),
+    (("exec.", "done.", "asof.", "groupby.", "join."),
+     "executors.exec_self"),
     (("push.",), "runtime.push"),
     (("reader.execute", "bridge.to_device", "prefetch.wait"), "io.read"),
     (("emit.",), "emit.d2h"),

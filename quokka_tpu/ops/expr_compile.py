@@ -476,6 +476,11 @@ def _str_op(e: StrOp, batch: DeviceBatch):
     if not isinstance(v, StrCol):
         raise CompileError(f"str op {e.op} on non-string")
     vals = v.dictionary.values
+    if e.op != "hash":
+        # every other op walks the batch's dictionary on the host
+        from quokka_tpu.obs import opstats
+
+        opstats.note(str_pred_dict_rows=len(vals))
     svals = vals.astype(str)
     if e.op == "like":
         rx = re.compile(_like_to_regex(e.args[0]))

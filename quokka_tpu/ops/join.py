@@ -423,7 +423,10 @@ def hash_join_general(
     build_payload: Sequence[str] = (),
 ) -> DeviceBatch:
     """Many-to-many join.  One host sync per batch for the output bucket."""
+    from quokka_tpu.obs import opstats
+
     p = probe.padded_len
+    opstats.note(join_probe_general=p)
     limbs, valid = _concat_limbs(probe, build, probe_keys, build_keys)
     if how in ("semi", "anti"):
         match_count, *_ = _mm_plan(tuple(limbs), valid, p)

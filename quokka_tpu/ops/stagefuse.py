@@ -229,7 +229,7 @@ class FusedStageExecutor(Executor):
 
     # one fused dispatch does the work of the whole member chain: drain a
     # wider slice of the ready queue per task than the per-operator default
-    # so the interior joins/aggs run over bigger coalesced wholes
+    # (a stage that holds a join takes the join's cap: see __init__)
     MAX_PIPELINE_BATCHES = 32
 
     def __init__(self, spec: StageSpec):
@@ -238,6 +238,12 @@ class FusedStageExecutor(Executor):
         self.labels = [label for label, _ in spec.steps]
         self.routing = spec.routing
         self.OP_NAME = "FusedStage[" + ">".join(self.labels) + "]"
+        # the tightest cap a member states is the stage's: the head member
+        # receives what the stage was dispatched with
+        caps = [m.MAX_PIPELINE_BATCHES for m in self.steps
+                if hasattr(m, "MAX_PIPELINE_BATCHES")]
+        if caps:
+            self.MAX_PIPELINE_BATCHES = min(caps)
 
     @property
     def SUPPORTS_CHECKPOINT(self) -> bool:

@@ -1003,7 +1003,11 @@ class Engine:
         batch = self._take_prefetched(info, task, seq)
         rows_raw = self._rows_of(batch)  # pre-predicate: what the reader read
         if info.predicate is not None:
-            with tracing.span("source.predicate"):
+            # the source is the operator a predicate's notes belong to
+            with tracing.span("source.predicate"), \
+                    opstats.OPSTATS.current_op(
+                        getattr(self.g, "query_id", None), task.actor,
+                        task.channel):
                 batch = info.predicate(batch)
         if getattr(info.reader, "UNBOUNDED", False):
             batch = self._stamp_input_wm(info, task.actor, task.channel,

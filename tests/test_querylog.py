@@ -42,7 +42,8 @@ RECORD_KEYS = {
     "compile_misses", "rows_in", "padded_in", "rows_unknown",
     "agg_merges_compiled", "agg_merges_general", "asof_flushes",
     "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
-    "join_probe_direct", "join_probe_search", "groupby_sort_slots",
+    "join_probe_direct", "join_probe_search", "join_probe_general",
+    "join_builds", "str_pred_dict_rows", "groupby_sort_slots",
     "groupby_groups_out", "scan_hits", "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
 }
 IN_DISPATCH = ("runtime.dispatch_self", "executors.exec_self",
@@ -340,10 +341,11 @@ def test_explain_as_dict_has_a_pinned_shape(paths):
     for op in snap["operators"]:
         assert always <= set(op)
         # what is left are the executors' own notes: rows (join_build_rows
-        # ...), the aggregators' merges by path (ops/aggtail.py) and the
-        # general group-by's sorted slots and emitted groups
+        # ...), the aggregators' merges by path (ops/aggtail.py), the
+        # general group-by's sorted slots and emitted groups, and the joins'
+        # builds and probe slots
         assert all(k.endswith("_rows") or k.startswith(("agg_merges_",
-                                                        "groupby_"))
+                                                        "groupby_", "join_"))
                    for k in set(op) - always - sometimes), sorted(op)
         assert op["padded_in"] >= op["rows_in"] >= 0
     for edge in snap["edges"]:

@@ -162,6 +162,23 @@ def test_join_sorted_build_and_probe(chip):
     assert not re.search(r" (while|sort|gather)\(", build)
 
 
+def test_join_search_on_a_two_column_key_at_the_q9_shape(chip):
+    """Q9's partsupp build (800 K rows over two channels: the 1<<19 bucket,
+    two key limbs) searched by what the semi join leaves of a lineitem
+    batch once it is compacted (ISSUE 34: the 65,536 rung): a gather a limb
+    a halving and the last lookup, none of the probe's length times the
+    build's."""
+    from quokka_tpu.ops import join
+
+    b, p = N >> 1, 1 << 16
+    limbs = (chip("int32", b), chip("int32", b))
+    text = _compile(join._pk_probe_sorted, limbs, chip("int32", b),
+                    chip("int32", ()), (chip("int32", p), chip("int32", p)),
+                    chip(bool, p), steps=b.bit_length()).as_text()
+    assert not re.search(r" (while|sort|scatter)\(", text)
+    assert re.search(rf"s32\[{p}\]\S* gather\(", text)
+
+
 def test_asof_searchsorted(chip):
     """The asof kernels the TPU takes (the CPU default is the host merge)
     at the shapes of ``ticks_1d``: a channel's quote buffer at the 8M rung
