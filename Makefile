@@ -87,12 +87,12 @@ warmup-smoke:
 # CPU rehearsal of chip_smoke.py (the chip itself is reached only through the
 # builder's chip tool: `chiprun -- python chip_smoke.py`): every phase at
 # SF 0.01 with x64 off and the kernel strategies the TPU picks (sort
-# group-by, sorted join build, searchsorted asof), so the chip's branches
+# group-by, sorted join build, sort asof), so the chip's branches
 # run here first.  Its last line names the platform jax reported (cpu) and
 # "rehearsal": true — it cannot pass for a chip run.
 chip-smoke-rehearse:
 	JAX_PLATFORMS=cpu \
-	QK_KERNEL_STRATEGY=groupby=sort,join_build=sort,asof=searchsorted \
+	QK_KERNEL_STRATEGY=groupby=sort,join_build=sort,asof=sort \
 		$(PY) chip_smoke.py --rehearse --sf 0.01
 
 # streaming-plane smoke: a continuous asof join + a continuous windowed

@@ -35,6 +35,8 @@ Record keys (README "Observability" documents each):
   ``asof_probe_padded``, ``asof_quote_padded`` (the streaming asof join's
   chunk probes: how many, the trades they held, the slots they filled, and
   the padded quote rows each searched, summed: executors/ts_execs.py),
+  ``asof_match_sort``, ``asof_match_search`` (``asof_join`` calls the device
+  merge answered, and the device binary search: ops/asof.py),
   ``join_probe_direct``, ``join_probe_search`` (padded probe slots the
   sort branch of ``hash_join_pk`` answered from a direct-address table and
   by binary search: ops/join.py), ``join_probe_general`` (padded probe slots
@@ -94,6 +96,7 @@ COUNTS = ("tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
           "compile_misses", "rows_in", "padded_in", "rows_unknown",
           "agg_merges_compiled", "agg_merges_general", "asof_flushes",
           "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
+          "asof_match_sort", "asof_match_search",
           "join_probe_direct", "join_probe_search", "join_probe_general",
           "join_builds", "str_pred_dict_rows", "groupby_sort_slots",
           "groupby_groups_out", "scan_hits", "scan_misses")
@@ -104,6 +107,7 @@ KEYS = (("q", "plan_fp", "status") + STAMPS + SECONDS + COUNTS
 _FROM_OPSTATS = ("rows_in", "padded_in", "rows_unknown",
                  "agg_merges_compiled", "agg_merges_general", "asof_flushes",
                  "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
+                 "asof_match_sort", "asof_match_search",
                  "join_probe_direct", "join_probe_search",
                  "join_probe_general", "join_builds", "str_pred_dict_rows",
                  "groupby_sort_slots", "groupby_groups_out")

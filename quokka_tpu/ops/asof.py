@@ -4,17 +4,20 @@ The reference's SortedAsofExecutor walks trade/quote frontiers sequentially
 per batch (pyquokka/executors/ts_executors.py:324-383).  Three strategies
 (ops/strategy.py picks per backend; each records what actually ran):
 
-- ``sort``: concatenate both sides, sort once by (key, time, side), then a
-  segmented fill-forward scan (jax.lax.associative_scan) carries the most
-  recent quote position within each key segment onto every trade row.  One
-  sort + one log-depth scan — no sequential loop.
+- ``sort``: a merge.  Both sides are concatenated and sorted once by (key,
+  time, side, row); two running maxima over the sorted order give every
+  trade the position of the latest quote of its own key; a second sort on
+  one key brings the trade slots back to the front in their order.  One
+  program a flush (``asof_match``), no ``gather`` or ``scatter`` over the
+  quote slots, no sequential loop.  Costs by the slot of both sides
+  together: the TPU's default (a gather there costs 8-13 ns an element, a
+  sort of a million rows a millisecond).
 - ``searchsorted``: sort ONLY the quotes by (key, time) — cached on the
   quote batch, so repeated flushes against an unchanged buffer pay it once —
   and resolve every trade with a vectorized lexicographic binary search
   (upper bound for backward, lower bound for forward).  ~log2(q) gathers per
-  limb instead of an (n+m)-row multi-operand sort per flush, and no
-  concat-sized intermediates.  Fully device-resident: the accelerator
-  default.
+  limb and trade instead of an (n+m)-row multi-operand sort per flush, and
+  no concat-sized intermediates.  Fully device-resident: the GPU's default.
 - ``host``: the native O(n+m) sequential merge (native/columnar.cpp),
   profitable only where np.asarray of a device array is zero-copy (CPU).
 
@@ -34,7 +37,6 @@ from jax import lax
 import numpy as np
 
 from quokka_tpu import config
-from quokka_tpu.ops import kernels
 from quokka_tpu.ops.batch import (
     DeviceBatch,
     NumCol,
@@ -45,12 +47,14 @@ from quokka_tpu.ops.batch import (
     map_codes,
     rebuild_columns,
 )
-from quokka_tpu.ops.kernels import dense_rank
 
 
 def _seg_fill_forward(values: jax.Array, seg_start: jax.Array) -> jax.Array:
     """Within each segment (seg_start marks first element), running max of
-    `values` — used to propagate the latest quote position forward."""
+    `values` — the sliding window's and the shift's fill-forward
+    (executors/ts_execs.py, parallel/mesh_exec.py).  The asof match takes two
+    plain running maxima instead: positions only grow, so a segment's start
+    need not reset them."""
 
     def combine(a, b):
         av, as_ = a
@@ -62,46 +66,115 @@ def _seg_fill_forward(values: jax.Array, seg_start: jax.Array) -> jax.Array:
     return out
 
 
+# What the match's traced body is made of.  One persisted program traces it
+# (``asof_match``, below): program keys carry no version of the code, so an
+# edit of the body changes this string, or the AOT store goes on running the
+# old executable under the unchanged key (as kernels.SORTED_GROUPBY_FORM).
+ASOF_MATCH_FORM = "sort_cummax_sort"
+
+
 @functools.partial(jax.jit, static_argnames=("t", "forward_ties"))
 def _asof_match(limbs: Tuple[jax.Array, ...], times: Tuple[jax.Array, ...],
                 is_trade: jax.Array, valid: jax.Array, t: int,
                 forward_ties: bool = False):
     """Returns per-trade-row (quote_row_idx, matched) for backward asof.
-    Arrays are the concatenation [trades | quotes]; `t` = trade padded len.
-    `times` is one array for narrow/float time columns, or (hi, lo) limbs for
-    wide int64/ns timestamps (limb lexicographic order == numeric order).
+    Arrays are the concatenation [trades | quotes]; `t` = trade padded len
+    (the trades are rows 0..t-1).  `times` is one array for narrow/float
+    time columns, or (hi, lo) limbs for wide int64/ns timestamps (limb
+    lexicographic order == numeric order).
 
-    Tie-break among quotes sharing (key, time): the scan takes the quote at
-    the MAX sorted position, so the iota tie key orders equal quotes by
-    original index — ascending for backward (pandas/polars pick the LAST
-    tied quote) and descending (`forward_ties`, on the caller's negated
-    times) so forward picks the FIRST tied quote, matching pandas and the
-    native host merge."""
+    A merge, as sort, running maxima, sort; nothing indexed over the n
+    slots (a ``gather`` or a ``scatter`` costs the TPU 7-9 ns an element, a
+    sort of a million rows about a millisecond):
+
+    1. one sort by (*limbs, *times, tag) and nothing carried: ``tag`` is the
+       side, the validity and the row index in one int32 (every operand
+       costs the sort of 8.65 M slots about 8 ms, PERF.md section 6), high
+       bits first: valid quote 0, masked quote 1, valid trade 2, masked
+       trade 3, then the row.  So quotes stand before trades at equal times
+       (backward asof includes same-timestamp quotes) and equal rows in
+       original order, with no stability asked of the sort.  Validity is no
+       leading key: a masked-out row stands wherever its stale key and time
+       put it, inside some key's run or in a run of its own, and is never a
+       quote that counts nor a trade that matches;
+    2. two running maxima over the sorted order: the position of the latest
+       valid quote so far, and the position where the current run of one key
+       began (any limb differs from the row before).  A trade is matched iff
+       its latest quote lies inside its own run: no key is gathered to be
+       compared;
+    3. a second sort on one key brings the t trade slots (valid or not:
+       holes keep their places) back to the front in chunk order, carrying
+       the latest quote's sorted position; the quote's row is gathered for
+       those t rows alone.
+
+    Tie-break among quotes sharing (key, time): the maximum takes the quote
+    at the LAST sorted position, equal quotes stand in original order, so
+    backward picks the last tied quote (pandas/polars).  ``forward_ties``
+    (on the caller's negated times) orders them by descending row instead,
+    so forward picks the FIRST tied quote, matching pandas and the native
+    host merge."""
     n = valid.shape[0]
-    ranks, _ = dense_rank(limbs, valid)
+    bits = (n - 1).bit_length()
+    assert bits <= 29, "the side and the row index share one int32"
     iota = jnp.arange(n, dtype=jnp.int32)
-    inv = (~valid).astype(jnp.int32)
-    # sort by (validity, key rank, time, side): quotes (0) before trades (1)
-    # at equal times -> backward asof includes same-timestamp quotes
-    side = is_trade.astype(jnp.int32)
-    tie = -iota if forward_ties else iota
-    nk = 2 + len(times)
-    sorted_ops = lax.sort([inv, ranks, *times, side, tie, iota],
-                          num_keys=nk + 2)
-    perm = sorted_ops[-1]
-    valid_s = sorted_ops[0] == 0
-    ranks_s = sorted_ops[1]
-    side_s = sorted_ops[nk]
-    seg_start = (ranks_s != jnp.roll(ranks_s, 1)) | (iota == 0)
-    quote_pos = jnp.where(valid_s & (side_s == 0), iota, -1)
-    last_quote_pos = _seg_fill_forward(quote_pos, seg_start)
-    # for each sorted position, the original row of the latest quote <= here
-    quote_orig = perm[jnp.clip(last_quote_pos, 0, n - 1)]
-    matched_s = valid_s & (side_s == 1) & (last_quote_pos >= 0)
-    # scatter back to original (concat) positions
-    match_orig = jnp.zeros(n, dtype=jnp.int32).at[perm].set(quote_orig)
-    matched = jnp.zeros(n, dtype=bool).at[perm].set(matched_s)
-    return match_orig[:t], matched[:t]
+    side = 2 * is_trade.astype(jnp.int32) + (~valid).astype(jnp.int32)
+    tag = (side << bits) | (n - 1 - iota if forward_ties else iota)
+    keys = [*limbs, *times, tag]
+    sorted_ops = lax.sort(keys, num_keys=len(keys), is_stable=False)
+    side_s = sorted_ops[-1] >> bits
+    perm = sorted_ops[-1] & ((1 << bits) - 1)
+    if forward_ties:
+        perm = n - 1 - perm
+    run_start = iota == 0
+    for s in sorted_ops[:len(limbs)]:
+        run_start = run_start | (s != jnp.roll(s, 1))
+    last_quote_pos = lax.cummax(jnp.where(side_s == 0, iota, -1))
+    run_begin = lax.cummax(jnp.where(run_start, iota, 0))
+    hit = (side_s == 2) & (last_quote_pos >= run_begin)
+    # the trades' original rows are 0..t-1, each once; the quotes all get n
+    # and follow them in any order
+    _, pos = lax.sort([jnp.where(side_s >= 2, perm, n),
+                       jnp.where(hit, last_quote_pos, -1)],
+                      num_keys=1, is_stable=False)
+    pos = pos[:t]
+    return perm[jnp.clip(pos, 0, n - 1)], pos >= 0
+
+
+def _asof_match_sides(t_limbs: Tuple[jax.Array, ...],
+                      t_times: Tuple[jax.Array, ...], t_valid: jax.Array,
+                      q_limbs: Tuple[jax.Array, ...],
+                      q_times: Tuple[jax.Array, ...], q_valid: jax.Array,
+                      forward: bool):
+    """One flush's match as ONE program: the two sides' key limbs, times
+    and masks are concatenated, the quote side cast to the trade side's
+    dtypes and (``forward``) the times reversed, here and not as one eager
+    launch each over the quote buffer's capacity.  Returns (quote row index
+    clipped, matched) aligned to the trade slots."""
+    t, q = t_valid.shape[0], q_valid.shape[0]
+    cat = lambda a, b: jnp.concatenate([a, b.astype(a.dtype)])  # noqa: E731
+    times = tuple(cat(a, b) for a, b in zip(t_times, q_times))
+    if forward:
+        # run forward on the backward kernel: an exact decreasing remap
+        from quokka_tpu.ops import timewide
+
+        times = (timewide.not_limbs(times) if len(times) == 2
+                 else (-times[0],))
+    match_orig, matched = _asof_match(
+        tuple(cat(a, b) for a, b in zip(t_limbs, q_limbs)), times,
+        jnp.arange(t + q, dtype=jnp.int32) < t, cat(t_valid, q_valid), t,
+        forward_ties=forward)
+    return jnp.clip(match_orig - t, 0, q - 1), matched
+
+
+# The device trace knows a program by its function's name.  The benchmark's
+# ``asof_match_roofline`` and PERF.md know the match as ``jit__asof_match``;
+# the program a flush launches is the match, whatever wraps it.
+_asof_match_sides.__name__ = "_asof_match"
+
+
+@functools.lru_cache(maxsize=None)
+def match_kernel():
+    return jax.jit(_asof_match_sides, static_argnames=("forward",))
 
 
 # ---------------------------------------------------------------------------
@@ -166,6 +239,16 @@ def _ss_probe(sorted_ops: Tuple[jax.Array, ...], perm: jax.Array,
     return jnp.clip(perm[cpos], 0, nq - 1), matched
 
 
+def _quote_key_limbs(quotes: DeviceBatch,
+                     right_by: Sequence[str]) -> List[jax.Array]:
+    """The quote side's key limbs: the ones a ``RowBuffer`` carried beside
+    its columns (hashed part by part as they arrived), else hashed here."""
+    carried = getattr(quotes, "_asof_key_limbs", None)
+    if carried is not None and carried[0] == tuple(right_by):
+        return list(carried[1])
+    return key_limbs(quotes, list(right_by)) if right_by else []
+
+
 def _ss_quote_sorted(quotes: DeviceBatch, right_on: str,
                      right_by: Sequence[str], wide: bool, time_dtype):
     """(sorted_ops, perm, n_valid, nkey) for a quote batch, cached ON the
@@ -186,11 +269,7 @@ def _ss_quote_sorted(quotes: DeviceBatch, right_on: str,
     key = (tuple(right_by), right_on, wide, str(time_dtype))
     hit = cache.get(key)
     if hit is None:
-        carried = getattr(quotes, "_asof_key_limbs", None)
-        if carried is not None and carried[0] == tuple(right_by):
-            ql = list(carried[1])  # RowBuffer.view(): hashed part by part
-        else:
-            ql = key_limbs(quotes, list(right_by)) if right_by else []
+        ql = _quote_key_limbs(quotes, right_by)
         qc = quotes.columns[right_on]
         if wide:
             from quokka_tpu.ops import timewide
@@ -237,12 +316,36 @@ def _asof_match_searchsorted(trades: DeviceBatch, quotes: DeviceBatch,
     )
 
 
+def _asof_match_sort(trades: DeviceBatch, quotes: DeviceBatch,
+                     left_on: str, right_on: str, left_by: Sequence[str],
+                     right_by: Sequence[str], direction: str):
+    """(quote_idx, matched) aligned to trade rows: one program a flush,
+    keyed on (trade slots, quote slots) like every program of the buffers."""
+    from quokka_tpu.ops import timewide
+    from quokka_tpu.runtime import compileplane
+
+    tl = key_limbs(trades, list(left_by)) if left_by else []
+    ql = _quote_key_limbs(quotes, right_by)
+    assert len(tl) == len(ql), "asof by-key column types must match"
+    tc = trades.columns[left_on]
+    qc = quotes.columns[right_on]
+    if tc.hi is not None or qc.hi is not None:
+        tt, qt = timewide.widen_limbs(tc), timewide.widen_limbs(qc)
+    else:
+        tt, qt = (tc.data,), (qc.data,)
+    return compileplane.aot_kernel_call(
+        "asof_match", match_kernel(),
+        (tuple(tl), tuple(tt), trades.valid, tuple(ql), tuple(qt),
+         quotes.valid),
+        (direction == "forward",), form=(ASOF_MATCH_FORM,))
+
+
 # ---------------------------------------------------------------------------
 # Host fast path (CPU backend): the as-of match is a textbook O(n+m)
 # sequential merge; XLA:CPU's variadic sort makes the device kernel ~340
 # ns/row while the native walk (native/columnar.cpp qk_asof_backward) runs at
 # memory speed.  On the CPU backend np.asarray of a device array is a
-# zero-copy view, so "host" costs no transfer.  TPU keeps the sort+scan
+# zero-copy view, so "host" costs no transfer.  Accelerators keep a device
 # kernel (config.use_host_asof() gates, QUOKKA_HOST_ASOF overrides).
 # ---------------------------------------------------------------------------
 
@@ -592,9 +695,9 @@ def asof_join(
     native library / key shape declines falls back to the device
     searchsorted kernel — never a wrong answer, and the fallback is what
     gets recorded as having run."""
+    from quokka_tpu.obs import opstats
     from quokka_tpu.ops import strategy as kstrategy
 
-    t = trades.padded_len
     if direction not in ("backward", "forward"):
         raise ValueError(direction)
     pick = strategy or kstrategy.choice("asof")
@@ -614,37 +717,13 @@ def asof_join(
             trades, quotes, left_on, right_on, left_by, right_by, direction
         )
         kstrategy.note_used("asof", "searchsorted")
+        opstats.note(asof_match_search=1)
     else:
+        quote_idx, matched = _asof_match_sort(
+            trades, quotes, left_on, right_on, left_by, right_by, direction
+        )
         kstrategy.note_used("asof", "sort")
-        lt = key_limbs(trades, list(left_by)) if left_by else []
-        lq = key_limbs(quotes, list(right_by)) if right_by else []
-        if left_by:
-            limbs = [jnp.concatenate([a, b.astype(a.dtype)]) for a, b in zip(lt, lq)]
-        else:
-            limbs = [jnp.zeros(t + quotes.padded_len, dtype=jnp.int32)]
-        tc = trades.columns[left_on]
-        qc = quotes.columns[right_on]
-        if tc.hi is not None or qc.hi is not None:
-            from quokka_tpu.ops import timewide
-
-            tl, ql = timewide.widen_limbs(tc), timewide.widen_limbs(qc)
-            if direction == "forward":
-                tl, ql = timewide.not_limbs(tl), timewide.not_limbs(ql)
-            times = tuple(jnp.concatenate([a, b.astype(a.dtype)]) for a, b in zip(tl, ql))
-        else:
-            t_time, q_time = tc.data, qc.data
-            if direction == "forward":
-                t_time, q_time = -t_time, -q_time
-            times = (jnp.concatenate([t_time, q_time.astype(t_time.dtype)]),)
-        is_trade = jnp.concatenate(
-            [jnp.ones(t, dtype=bool), jnp.zeros(quotes.padded_len, dtype=bool)]
-        )
-        valid = jnp.concatenate([trades.valid, quotes.valid])
-        match_orig, matched = _asof_match(
-            tuple(limbs), times, is_trade, valid, t,
-            forward_ties=(direction == "forward"),
-        )
-        quote_idx = jnp.clip(match_orig - t, 0, quotes.padded_len - 1)
+        opstats.note(asof_match_sort=1)
     cols = dict(trades.columns)
     from quokka_tpu.ops.batch import with_nulls
 

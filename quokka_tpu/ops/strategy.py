@@ -36,8 +36,9 @@ Operators and choices:
                                      hashtable build_table/pk_probe)
   asof        host | sort | searchsorted
                                     (native O(n+m) host merge vs the
-                                     concat+sort+scan device kernel vs the
-                                     cached-quote-sort device binary search)
+                                     device merge as sort, running maxima,
+                                     sort vs the cached-quote-sort device
+                                     binary search)
   shuffle     masked | compacted    (kernels.split_by_partition modes)
 
 This module and config.py are the ONLY places allowed to probe the platform
@@ -66,15 +67,18 @@ OPS: Dict[str, Tuple[str, ...]] = {
 # config.use_hash_tables()/use_host_asof() used to hard-code.  CPU/GPU:
 # scatter/gather fast, sorts slow -> tables; TPU: random scatters
 # serialize, multi-operand sort is the idiom.  Host asof only where
-# np.asarray is zero-copy (CPU); accelerators get the device searchsorted
-# merge so the benched path needs no host round trip.
+# np.asarray is zero-copy (CPU).  The GPU gets the device binary search over
+# a cached quote sort; the TPU, where a gather costs 8-13 ns an element and
+# the search pays 72 a trade, the merge as sort, running maxima, sort
+# (ops/asof.py _asof_match: nothing indexed over the quote slots; PERF.md
+# section 6, PR 35).  Neither needs a host round trip.
 _PLATFORM_DEFAULTS: Dict[str, Dict[str, str]] = {
     "cpu": {"groupby": "hashtable", "join_build": "hashtable",
             "asof": "host", "shuffle": "masked"},
     "gpu": {"groupby": "hashtable", "join_build": "hashtable",
             "asof": "searchsorted", "shuffle": "masked"},
     "tpu": {"groupby": "sort", "join_build": "sort",
-            "asof": "searchsorted", "shuffle": "masked"},
+            "asof": "sort", "shuffle": "masked"},
 }
 _PLATFORM_DEFAULTS["cuda"] = _PLATFORM_DEFAULTS["gpu"]
 _PLATFORM_DEFAULTS["rocm"] = _PLATFORM_DEFAULTS["gpu"]

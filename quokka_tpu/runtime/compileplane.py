@@ -643,20 +643,24 @@ def _acquire(key: Tuple, builder: Callable[[], object], args,
     return prog, "miss"
 
 
-def aot_kernel_call(kind: str, jit_fn, args: Tuple, statics: Tuple = ()):
+def aot_kernel_call(kind: str, jit_fn, args: Tuple, statics: Tuple = (),
+                    form: Tuple = ()):
     """Dispatch a module-level jitted kernel through the compile plane.
 
     ``args`` are the traced (array) positional arguments; ``statics`` are
     TRAILING static positional arguments.  The program key derives from the
     canonical aval signature (ops/sigkey) + statics, so one ladder bucket =
-    one program.  Inside an active trace the jitted function is called
-    directly (it inlines); a compiled executable cannot trace.  Any aval
-    drift falls back to the plain jit call — never an error."""
+    one program; ``form`` are further parts of the key alone, naming the
+    traced body (keys carry no version of the code: a kernel whose body
+    changes under unchanged shapes changes its form).  Inside an active
+    trace the jitted function is called directly (it inlines); a compiled
+    executable cannot trace.  Any aval drift falls back to the plain jit
+    call — never an error."""
     from quokka_tpu.analysis import compat
 
     if not compat.trace_state_clean():
         return jit_fn(*args, *statics)
-    key = sigkey.make_key(kind, sigkey.aval_sig(args), *statics)
+    key = sigkey.make_key(kind, sigkey.aval_sig(args), *statics, *form)
     prog = PROGRAMS.get(key)
     if prog is not None:
         # in-memory hits still record under the current plan: a plan that

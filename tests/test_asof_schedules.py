@@ -22,9 +22,10 @@ CHANNELS = 2
 N_QUOTES, N_TRADES, N_SYMBOLS = 6000, 1400, 20
 QUOTE_BATCH, TRADE_BATCH = 1000, 400
 # the kinds whose key sets followed arrival before (PERF.md section 7's
-# table) and the two the in-place buffers brought
+# table), the two the in-place buffers brought and the match's one program
+# (the TPU's pick since PR 35; the search's two stay listed and stay empty)
 KINDS = ("fused_concat", "gather", "compact_idx", "asof_ss_sort",
-         "asof_ss_probe", "asof_write", "asof_take")
+         "asof_ss_probe", "asof_write", "asof_take", "asof_match")
 
 
 def _ticks(seed=11):
@@ -175,7 +176,7 @@ def _assert_reference(got, exp):
 def test_schedule_answers_and_asks_for_no_new_program(name, data,
                                                       monkeypatch):
     monkeypatch.setenv("QK_KERNEL_STRATEGY",
-                       "asof=searchsorted,groupby=sort,join_build=sort")
+                       "asof=sort,groupby=sort,join_build=sort")
     monkeypatch.setattr(SortedAsofExecutor, "MIN_FLUSH_ROWS", 256)
     monkeypatch.setattr(SortedAsofExecutor, "COALESCE_ROWS", 64)
     sigkey.reset_ledger()
@@ -190,6 +191,7 @@ def test_schedule_answers_and_asks_for_no_new_program(name, data,
            if later[k] - first[k]}
     assert not new, f"{name} asked for programs outside the first set: {new}"
     assert first["asof_write"] and first["asof_take"]
+    assert first["asof_match"] and not first["asof_ss_probe"]
 
 
 @pytest.mark.parametrize("name", ["quotes_first", "alternate", "random3"])
@@ -197,7 +199,7 @@ def test_a_source_that_knows_no_row_count_still_answers(name, data,
                                                         monkeypatch):
     """No capacity from the plan: the buffers start at the first part's
     length, close their holes and double as parts arrive."""
-    monkeypatch.setenv("QK_KERNEL_STRATEGY", "asof=searchsorted")
+    monkeypatch.setenv("QK_KERNEL_STRATEGY", "asof=sort")
     monkeypatch.setattr(SortedAsofExecutor, "MIN_FLUSH_ROWS", 256)
     monkeypatch.setattr(SortedAsofExecutor, "COALESCE_ROWS", 64)
     got, _ = _run(data, SCHEDULES[name], capacity=(None, None))

@@ -2,9 +2,9 @@
 (benchmarks/queries/asof.py ``build``) over the configuration's rehearsal
 size, submitted to a ``QueryService`` as the benchmark submits it.  A second
 request of one process asks the compile plane for no program of the join's,
-its record carries the executor's four counters, and a killed exec channel
-replays to the same answer from checkpoints that do not keep the buffers'
-padding."""
+its record carries the executor's four counters and the match's two, and a
+killed exec channel replays to the same answer from checkpoints that do not
+keep the buffers' padding."""
 
 import importlib.util
 import json
@@ -84,7 +84,7 @@ def small_flushes(monkeypatch):
     """TPU kernel strategies, and thresholds at which the rehearsal size
     flushes several times a channel."""
     monkeypatch.setenv("QK_KERNEL_STRATEGY",
-                       "asof=searchsorted,groupby=sort,join_build=sort")
+                       "asof=sort,groupby=sort,join_build=sort")
     monkeypatch.setattr(SortedAsofExecutor, "MIN_FLUSH_ROWS", 1024)
     monkeypatch.setattr(SortedAsofExecutor, "COALESCE_ROWS", 256)
 
@@ -106,6 +106,9 @@ def test_second_request_asks_for_no_program_of_the_join(ticks, small_flushes):
     assert not late, late
     for rec in (first, second):
         assert all(rec[k] > 0 for k in COUNTERS), {k: rec[k] for k in COUNTERS}
+        # every flush was the merge's (the TPU's pick), none the search's
+        assert rec["asof_match_sort"] == rec["asof_flushes"]
+        assert rec["asof_match_search"] == 0
         # every trade went through one chunk probe, matched or dropped
         assert rec["asof_probe_rows"] == ticks["trades"]
         # chunks of one size, each against the whole quote buffer

@@ -42,6 +42,7 @@ RECORD_KEYS = {
     "compile_misses", "rows_in", "padded_in", "rows_unknown",
     "agg_merges_compiled", "agg_merges_general", "asof_flushes",
     "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
+    "asof_match_sort", "asof_match_search",
     "join_probe_direct", "join_probe_search", "join_probe_general",
     "join_builds", "str_pred_dict_rows", "groupby_sort_slots",
     "groupby_groups_out", "scan_hits", "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
@@ -366,7 +367,7 @@ def test_a_rehearsed_traced_run_reports_the_six_metrics(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                BENCH_KEEP_TRACE=str(tmp_path / "kept"),
                QK_KERNEL_STRATEGY=("groupby=sort,join_build=sort,"
-                                   "asof=searchsorted"))
+                                   "asof=sort"))
     env.pop("XLA_FLAGS", None)
     done = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),

@@ -35,7 +35,7 @@ class TestResolution:
         for plat, want_gb, want_asof in (
             ("cpu", "hashtable", "host"),
             ("gpu", "hashtable", "searchsorted"),
-            ("tpu", "sort", "searchsorted"),
+            ("tpu", "sort", "sort"),
         ):
             monkeypatch.setattr(config, "_platform", lambda p=plat: p)
             assert strategy.resolve("groupby") == (want_gb, "default")

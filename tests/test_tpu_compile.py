@@ -7,8 +7,8 @@ lower) fails this file at no chip time.  Shapes are the ones
 ``chip_smoke.py`` drives at SF 1: ``config.DEFAULT_BATCH_ROWS`` = 1<<20
 rows, 32-bit dtypes (x64 is OFF on the chip; pytest turns it on, so every
 lowering here happens under ``jax.enable_x64(False)``), the kernel
-strategies the TPU picks (sort group-by, sorted join build, searchsorted
-asof).  A compile that passes is not a chip run.
+strategies the TPU picks (sort group-by, sorted join build, sort asof; the
+searchsorted asof the GPU picks stays compiled beside it).  A compile that passes is not a chip run.
 
 Only one process at a time may load the TPU's library, so the topology is
 described inside a module-scoped fixture (never at import, in a ``skipif``
@@ -199,6 +199,33 @@ def test_asof_searchsorted(chip):
              chip(bool, q), tuple(chip(d) for d in cols), chip(bool),
              tuple(chip("int32", 128 if i == 1 else 0) for i in range(5)),
              chip("int32", ()))
+
+
+def test_asof_sort_match(chip):
+    """The asof match the TPU takes since PR 35, at ``ticks_1d``'s flush: a
+    chunk of 1<<18 trade slots merged with a channel's 8M-slot quote buffer
+    (two symbol hash limbs, one int32 time; the side, the validity and the
+    row index share a fourth operand) as ONE program.  What the chip
+    charges by the element is not in its text: no ``scatter``, no loop (a
+    ``cummax`` that lowered to ``associative_scan``'s ``while`` would be
+    one), the merged sort and the compacting sort and no third, and no
+    ``gather`` wider than the chunk (the quote's row, for the trade slots
+    alone).  On the chip 50-80 ms a flush where the quote sort and the
+    24-halving search were 38 + 265 (PERF.md section 6, PR 35)."""
+    from quokka_tpu.ops import asof
+
+    q, chunk = 8 * N, N >> 2
+    side = lambda n: ((chip("int32", n),) * 2, (chip("int32", n),),  # noqa: E731
+                      chip(bool, n))
+    text = _compile(asof.match_kernel(), *side(chunk), *side(q),
+                    forward=False).as_text()
+    assert "jit__asof_match" in text.splitlines()[0]
+    assert not re.search(r"\b(scatter|while)\(", text)
+    assert len(re.findall(r"\bsort\(", text)) == 2
+    gathers = re.findall(r"(\w+)\[([\d,]*)\]\S* gather\(", text)
+    assert gathers, "the quote's row is gathered for the chunk"
+    for dtype, dims in gathers:
+        assert (dtype, dims) == ("s32", str(chunk)), (dtype, dims)
 
 
 def test_pack_decode(chip):
