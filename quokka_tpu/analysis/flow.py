@@ -1,6 +1,6 @@
 """qkflow: interprocedural dataflow engine for the lint rules.
 
-The name-heuristic rules (QK004/QK008/QK011) matched *names*: any function
+The name-heuristic rules (QK004/QK008) matched *names*: any function
 whose bare name appeared in a call was "reachable", every parameter was a
 potential tracer, every config mutation was a finding.  This module gives
 them actual program structure to stand on:
@@ -22,10 +22,7 @@ them actual program structure to stand on:
   when every call site in the file set passes a literal, trace-time
   metadata (``x.dtype``/``.shape``/``.ndim``/``.size``), or a value that
   is itself static — branching on it is trace-time control flow, not a
-  tracer sync (fixpoint over (function, param));
-- **an async-copy def-use helper**: ``np.asarray(x)`` preceded by
-  ``x.copy_to_host_async()`` on the same local is an overlap pattern, not
-  a blocking readback.
+  tracer sync (fixpoint over (function, param)).
 
 The context is built once per lint invocation over the whole file set;
 single-file invocations (fixtures) get a one-module context, so rules
@@ -452,24 +449,6 @@ class FlowContext:
                     state[fid] = keep
                     changed = True
         return state
-
-    # -- def-use helpers -----------------------------------------------------
-
-    @staticmethod
-    def async_copy_started(fn_node: ast.AST, name: str, line: int) -> bool:
-        """True when `name.copy_to_host_async()` is called in `fn_node`
-        strictly before `line` — the d2h transfer of `name` was already
-        dispatched, so a later host materialization overlaps device work
-        instead of draining the pipeline."""
-        for node in ast.walk(fn_node):
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr == "copy_to_host_async"
-                    and isinstance(node.func.value, ast.Name)
-                    and node.func.value.id == name
-                    and getattr(node, "lineno", line) < line):
-                return True
-        return False
 
 
 def build_context(files: Sequence[Tuple[str, ast.Module]]) -> FlowContext:

@@ -36,12 +36,14 @@ Rule ids:
                                 counters must go through the typed
                                 obs.REGISTRY so the Prometheus exporter,
                                 bench snapshots and /status see them
-  QK011 push-path-host-sync     blocking host readbacks (np.asarray /
+  QK011 device-read-outside-funnel  blocking host readbacks (np.asarray /
                                 .item() / device_get / block_until_ready /
-                                .tolist()) reachable from the shuffle push
-                                path (Engine.push, the lowered partition
-                                fns, split_by_partition) — the exchange
-                                critical path must never drain the device
+                                .tolist()) in any function under ops/,
+                                executors/, runtime/, service/ that do not
+                                go through obs.spans.device_read — every
+                                wait for the device is a sync.<site> span
+                                the query record counts, and the exchange
+                                critical path never drains the device
                                 pipeline; deliberate readbacks carry
                                 baseline rationales
   QK012 raw-len-cache-key       jit-program cache keys built from raw
@@ -1128,96 +1130,80 @@ def check_adhoc_counter_dict(tree: ast.Module, path: str, rel: str,
 
 
 # ---------------------------------------------------------------------------
-# QK011 — blocking host readbacks on the shuffle push path
+# QK011 — blocking device reads outside the funnel (obs/spans.device_read)
 # ---------------------------------------------------------------------------
 
 # Function names that ARE the shuffle push path: Engine.push, the partition-
 # fn lowering (and the closures it builds), the range splitter and the
-# multi-partition kernels.  The rule walks same-module reachability from
-# these (simple-name call edges + nested defs), like QK004 does from jit
-# entry points.  _spill_one is deliberately NOT an entry: it is the
-# background spill worker, whose whole job is an off-critical-path d2h.
+# multi-partition kernels (a seed set of the query-execution surface, QK008).
+# _spill_one is deliberately NOT an entry: it is the background spill worker,
+# whose whole job is an off-critical-path d2h.
 _PUSH_PATH_ENTRY_FUNCS = (
     "push", "_partition_fn", "_range_split",
     "split_by_partition", "partition_ids",
 )
-# the readback shapes banned on the push path (host round trips / pipeline
-# drains); scalar int()/float() conversions are NOT flagged here — the push
-# path legitimately converts host-side plan metadata (e.g. range boundaries)
-_PUSH_SYNC_TAILS = ("asarray", "item", "tolist", "device_get",
-                    "block_until_ready")
+# where a thread of the program serves a request: every function of these
+# directories is in the rule's sight (and a fixture named qk011*)
+_QK011_SCOPED_DIRS = ("quokka_tpu/ops/", "quokka_tpu/executors/",
+                      "quokka_tpu/runtime/", "quokka_tpu/service/")
+# the readback shapes an AST can see; int()/bool()/float() of a device
+# value cannot be told from a host conversion here — jax's transfer guard
+# on the chip finds those (PERF.md section 6, PR 36)
+_DEVICE_READ_TAILS = ("asarray", "item", "tolist", "device_get",
+                      "block_until_ready")
 
 
-def check_push_path_host_sync(tree: ast.Module, path: str, rel: str,
-                              src_lines: Sequence[str],
-                              ctx: FlowContext) -> List[Finding]:
-    """The shuffle push path (Engine.push -> partition fn -> split kernels)
-    is the producer's hot loop: a blocking host readback there drains the
-    whole queued device pipeline once per batch per edge — exactly the
-    stall the device-resident data plane removed.  Flags np.asarray/.item()/
-    .tolist()/device_get/block_until_ready in functions reachable from the
-    push-path entry set.  Reachability comes from the flow call graph:
-    nested closures count only when they actually ESCAPE into the caller
-    (called, returned, stored or passed — the old rule pulled in every
-    nested def of an entry unconditionally), and an ``np.asarray(x)`` whose
-    ``x.copy_to_host_async()`` was dispatched earlier in the same function
-    is an overlapped transfer, not a pipeline drain."""
-    mt = ctx.module_table(rel)
-    if mt is None:
+def check_device_read_outside_funnel(tree: ast.Module, path: str, rel: str,
+                                     src_lines: Sequence[str]
+                                     ) -> List[Finding]:
+    """Every place a thread blocks until the device has produced something
+    goes through ``obs.spans.device_read`` (a ``sync.<site>`` span: the ring,
+    the trace and the query record's ``syncs`` / ``sync.wait`` /
+    ``d2h_bytes`` see it).  Flags np.asarray / .item() / .tolist() /
+    device_get / block_until_ready in any function under ops/, executors/,
+    runtime/ and service/: a read beside the funnel is host time the record
+    calls work, and on the shuffle push path it drains the queued device
+    pipeline once per batch per edge.  A site left on purpose (a path no
+    served request reaches; ``np.asarray`` of what is already a host array)
+    carries a baseline rationale."""
+    r = rel.replace("\\", "/")
+    base = r.rsplit("/", 1)[-1]
+    if not (any(d in r for d in _QK011_SCOPED_DIRS)
+            or base.startswith("qk011")):
         return []
-    entries = [fi.fid for fi in mt.functions.values()
-               if fi.name in _PUSH_PATH_ENTRY_FUNCS]
-    if not entries:
-        return []
-
     out: List[Finding] = []
-    for fid in sorted(_module_reachable(ctx, mt, entries)):
-        fi = ctx.funcs[fid]
-        for node in FlowContext._own_nodes(fi.node):
-            if not isinstance(node, ast.Call):
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call):
+            continue
+        d = _dotted(node.func)
+        if d is None:
+            # chained-call receivers (x.sum().item()) defeat _dotted; the
+            # attribute tail alone decides for the no-base shapes
+            if not (isinstance(node.func, ast.Attribute)
+                    and node.func.attr in _DEVICE_READ_TAILS
+                    and node.func.attr != "asarray"):
                 continue
-            d = _dotted(node.func)
-            if d is None:
-                # chained-call receivers (x.sum().item()) defeat _dotted;
-                # the attribute tail alone decides for the no-base shapes
-                if (isinstance(node.func, ast.Attribute)
-                        and node.func.attr in _PUSH_SYNC_TAILS
-                        and node.func.attr != "asarray"):
-                    d = f"...{node.func.attr}"
-                    tail = node.func.attr
-                else:
-                    continue
-            else:
-                base, _, tail = d.rpartition(".")
-                if tail not in _PUSH_SYNC_TAILS:
-                    continue
-                # jnp.asarray is an h2d upload, not a readback; np/numpy/
-                # bare asarray (and any-receiver .item()/.tolist()/
-                # device_get/block_until_ready) are the blocking shapes
-                if tail == "asarray" and base not in ("np", "numpy", "onp",
-                                                      ""):
-                    continue
-                # def-use: the d2h copy of this local was already dispatched
-                # asynchronously earlier in the function — materializing it
-                # here overlaps the device pipeline instead of draining it
-                if (tail == "asarray" and len(node.args) == 1
-                        and isinstance(node.args[0], ast.Name)
-                        and FlowContext.async_copy_started(
-                            fi.node, node.args[0].id, node.lineno)):
-                    continue
-            scope = _scope_of(tree, node)
-            out.append(_mk(
-                "QK011", "push-path-host-sync", path, rel, node, scope,
-                f"'{d}(...)' inside '{scope}' (reachable from the shuffle "
-                "push path) blocks on a device->host readback, draining "
-                "the queued pipeline once per batch per edge — keep the "
-                "push path sync-free (async counts / masked views / "
-                "background spill), or baseline with a rationale",
-                src_lines))
+            d = f"...{node.func.attr}"
+        else:
+            mod, _, tail = d.rpartition(".")
+            if tail not in _DEVICE_READ_TAILS:
+                continue
+            # jnp.asarray is an h2d upload, not a readback; np/numpy/bare
+            # asarray (and any-receiver .item()/.tolist()/device_get/
+            # block_until_ready) are the blocking shapes
+            if tail == "asarray" and mod not in ("np", "numpy", "onp", ""):
+                continue
+        scope = _scope_of(tree, node)
+        out.append(_mk(
+            "QK011", "device-read-outside-funnel", path, rel, node, scope,
+            f"'{d}(...)' inside '{scope}' blocks on a device->host read "
+            "beside the funnel — go through obs.spans.device_read(site, "
+            "value) so the read is a sync.<site> span the query record "
+            "counts (and keep the shuffle push path free of reads: async "
+            "counts / masked views / background spill), or baseline with a "
+            "rationale",
+            src_lines))
     return out
-
-
-check_push_path_host_sync._needs_flow = True
 
 
 # ---------------------------------------------------------------------------
@@ -1882,7 +1868,7 @@ RULES = (
     check_global_config_mutation,
     check_unbounded_io,
     check_adhoc_counter_dict,
-    check_push_path_host_sync,
+    check_device_read_outside_funnel,
     check_raw_len_cache_key,
     check_platform_gate,
     check_unledgered_device_alloc,

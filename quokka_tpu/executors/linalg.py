@@ -17,6 +17,7 @@ import numpy as np
 import pyarrow as pa
 
 from quokka_tpu.executors.base import Executor
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops import bridge
 from quokka_tpu.ops.batch import DeviceBatch
 
@@ -54,8 +55,8 @@ class GramianExecutor(Executor):
         # emit RAW partials (gram rows + a sums row + a count row): channels
         # must combine raw moments before any normalization, otherwise
         # per-channel covariances sum to N-channels times the true value
-        g = np.asarray(self.gram, dtype=np.float64)
-        sums = np.asarray(self.sums, dtype=np.float64)
+        g, sums = tracing.device_read("linalg.gram", (self.gram, self.sums))
+        g, sums = g.astype(np.float64), sums.astype(np.float64)
         labels = list(self.columns) + ["__sums__", "__count__"]
         count_row = np.zeros(len(self.columns))
         count_row[0] = self.count
@@ -121,7 +122,9 @@ class ReservoirQuantileExecutor(Executor):
         for b in batches:
             if b is None or b.count_valid() == 0:
                 continue
-            x = np.asarray(b.columns[self.column].data)[np.asarray(b.valid)]
+            x, live = tracing.device_read(
+                "linalg.quantile_col", (b.columns[self.column].data, b.valid))
+            x = x[live]
             self.digest.add(x.astype(np.float64))
 
     def done(self, channel):

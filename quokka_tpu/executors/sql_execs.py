@@ -174,7 +174,7 @@ class _FoldsPartials:
     # first, which blocks on every live count that has not landed: the async
     # copies start at partial creation and merges run batches later, so on
     # the CPU those reads are from host memory; on the chip a pool thread
-    # waits in them (count_valid.block, PERF.md section 5)
+    # waits in them (sync.count_valid, PERF.md section 5)
 
     def _merge(self) -> None:
         if not self._buffer:
@@ -929,8 +929,10 @@ class SortExecutor(Executor):
             merged = bridge.concat_batches(parts) if len(parts) > 1 else parts[0]
             s = kernels.sort_batch(merged, self.by, self.descending)
             nvalid = s.count_valid()
-            run_arr = np.asarray(s.columns["__run"].data)[:nvalid]
-            pos_arr = np.asarray(s.columns["__pos"].data)[:nvalid]
+            run_arr, pos_arr = tracing.device_read(
+                "sort.merge_runs",
+                (s.columns["__run"].data, s.columns["__pos"].data))
+            run_arr, pos_arr = run_arr[:nvalid], pos_arr[:nvalid]
             pending = [b for b in bounds if b is not None]
             if pending:
                 cut = min(

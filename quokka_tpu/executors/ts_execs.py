@@ -41,7 +41,8 @@ def _time_max(batch: DeviceBatch, col: str):
     c = batch.columns[col]
     if c.hi is not None:
         return timewide.host_max_i64(c, batch.valid)
-    return float(kernels.reduce_array(c.data, batch.valid, "max"))
+    return float(tracing.device_read(
+        "asof.watermark", kernels.reduce_array(c.data, batch.valid, "max")))
 
 
 def _time_min(batch: DeviceBatch, col: str, valid=None):
@@ -50,7 +51,8 @@ def _time_min(batch: DeviceBatch, col: str, valid=None):
     v = batch.valid if valid is None else valid
     if c.hi is not None:
         return timewide.host_min_i64(c, v)
-    return float(kernels.reduce_array(c.data, v, "min"))
+    return float(tracing.device_read(
+        "asof.cutoff", kernels.reduce_array(c.data, v, "min")))
 
 
 def _cmp_time(col, v, op: str):
@@ -311,7 +313,8 @@ class SortedAsofExecutor(Executor):
         # contain quotes at exactly `safe` (ties must win per backward-asof)
         op = "<=" if safe == float("inf") else "<"
         ready_mask = trades.valid & _cmp_time(tcol, safe, op)
-        nready = int(jnp.sum(ready_mask.astype(jnp.int32)))
+        nready = int(tracing.device_read(
+            "asof.ready", jnp.sum(ready_mask.astype(jnp.int32))))
         if nready == 0:
             return None
         # each flush searches the ENTIRE quote buffer (and sorts it, when
@@ -402,7 +405,7 @@ class SortedAsofExecutor(Executor):
         tcol = trades.columns[self.left_on]
         unmatched = trades.valid & ~matched
         emit = trades.valid & matched
-        if bool(jnp.any(unmatched)):
+        if bool(tracing.device_read("asof.unmatched", jnp.any(unmatched))):
             cutoff = _time_min(trades, self.left_on, unmatched)
             emit = emit & _cmp_time(tcol, cutoff, "<")
         result = kernels.compact(kernels.apply_mask(out, emit))
@@ -452,7 +455,7 @@ class SortedAsofExecutor(Executor):
             keep_s = (s.valid & _cmp_time(st, safe, ">")) | is_last_below
             pruned = kernels.apply_mask(s, keep_s)
         else:
-            if bool(jnp.any(below)):
+            if bool(tracing.device_read("asof.prune_below", jnp.any(below))):
                 maxt = _time_max(
                     DeviceBatch(
                         {self.right_on: qt}, below, None, None

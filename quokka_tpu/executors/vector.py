@@ -16,6 +16,7 @@ import numpy as np
 import pyarrow as pa
 
 from quokka_tpu.executors.base import Executor
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops import bridge
 from quokka_tpu.ops.batch import DeviceBatch, NumCol, VecCol
 
@@ -66,13 +67,13 @@ class NearestNeighborExecutor(Executor):
             bridge.device_to_arrow(b.select(payload_cols))
         )
         # map padded row index -> compacted arrow row index
-        valid_np = np.asarray(b.valid)
+        valid_np, top_i_np, top_s_np = tracing.device_read(
+            "vector.topk", (b.valid, top_i, top_s))
         remap = np.cumsum(valid_np) - 1
-        top_i_np = remap[np.asarray(top_i)]
+        top_i_np = remap[top_i_np]
         handles = np.stack(
             [np.full_like(top_i_np, table_idx), top_i_np], axis=-1
         )  # [Q, k, 2]
-        top_s_np = np.asarray(top_s)
         if self.scores is None:
             self.scores = top_s_np
             self.rows = handles

@@ -298,10 +298,13 @@ class EngineMetrics:
     def snapshot(self) -> Dict:
         """Resolve deferred device rows and render the store payload:
         {(actor, ch): {tasks, rows, bytes}, "__compile__": compile stats}."""
+        from quokka_tpu.obs import spans
+
         for key, dev in self._pending:
             # a dead device buffer must not sink the flush
             with contextlib.suppress(Exception):
-                self._chan[key].rows += int(dev)
+                self._chan[key].rows += int(
+                    spans.device_read("metrics.rows", dev))
         self._pending = []
         snap: Dict = {k: c.as_dict() for k, c in self._chan.items()}
         from quokka_tpu.utils import compilestats

@@ -329,13 +329,28 @@ class OpStats:
             self._pending = later
         if not pend:
             return
+        from quokka_tpu.obs import spans
+
+        # one read for all of them; a dead buffer among them sends each
+        # through alone, and only that one is dropped
+        try:
+            counts = spans.device_read("opstats.snapshot",
+                                       [ent[-1] for ent in pend])
+        except Exception:  # noqa: BLE001 — diagnostics never raise
+            counts = []
+            for ent in pend:
+                try:
+                    counts.append(spans.device_read("opstats.snapshot",
+                                                    ent[-1]))
+                except Exception:  # noqa: BLE001
+                    counts.append(None)
         resolved = []
-        for ent in pend:
+        for ent, n in zip(pend, counts):
             with contextlib.suppress(Exception):
                 if ent[0] in ("op", "note"):
-                    resolved.append((ent[0], ent[1], ent[2], int(ent[3])))
+                    resolved.append((ent[0], ent[1], ent[2], int(n)))
                 else:
-                    resolved.append(("edge", ent[1], int(ent[2])))
+                    resolved.append(("edge", ent[1], int(n)))
         with self._lock:
             for ent in resolved:
                 if ent[0] == "op":

@@ -21,12 +21,20 @@ Record keys (README "Observability" documents each):
 - self seconds by layer.  The dispatches' partition: ``runtime.dispatch_self``
   + ``executors.exec_self`` + ``runtime.push`` + ``io.read`` + ``emit.d2h`` +
   ``compile.acquire`` + ``other`` = ``task_s``, the summed duration of the
-  dispatches that progressed (``other.sync_block`` is the part of ``other``
-  spent in ``count_valid.block``, a blocking device read).  Beside it: ``runtime.pick``,
+  dispatches that progressed.  Beside it: ``runtime.pick``,
   ``service.sched_wait``, ``service.finalize`` and its ``finalize.*`` parts,
   ``entry.submit`` and its ``entry.*`` parts, and the ``offthread.*`` sums
   of helper threads (overlapping the workers; outside every partition);
-- counts: ``tasks``, ``requeues``, ``backoffs``, ``sync_blocks``,
+- the wait for the device, beside the partition and inside its seconds
+  (``spans.device_read``: every ``sync.<site>`` span of the query's threads):
+  ``syncs`` (blocking reads), ``sync.wait`` (their seconds, all),
+  ``sync.in_dispatch`` (the part inside dispatches that progressed, so at
+  most ``task_s``), ``sync.offthread`` (the part on helper threads),
+  ``d2h_bytes`` (what the reads brought from the device, the result's
+  tables included), ``h2d_bytes`` (what ``bridge.to_device`` put, on
+  whichever thread) and ``sync_sites``: at most 8 ``[site, count,
+  seconds]``, the largest seconds first;
+- counts: ``tasks``, ``requeues``, ``backoffs``,
   ``compile_hits``,
   ``compile_misses``, ``compiled``, ``rows_in``, ``padded_in``,
   ``rows_unknown``, ``agg_merges_compiled``, ``agg_merges_general`` (merges
@@ -60,6 +68,7 @@ from typing import Dict, List, Optional
 
 MAXLEN = 4096
 COMPILED_MAX = 32  # programs named per record; the counts go on
+SYNC_SITES_MAX = 8  # read sites named per record; the sums hold them all
 
 STAMPS = ("submit_in", "submit_out", "admitted", "first_task", "last_task",
           "finalize_in", "done", "wall_done")
@@ -91,9 +100,10 @@ _OUTSIDE = {
 _COUNTED = {"svc.fruitless": "requeues", "svc.backoff": "backoffs"}
 SECONDS = tuple(dict.fromkeys(
     [key for key, _ in _OUTSIDE.values()] + list(DISPATCH_LAYERS)
-    + ["other.sync_block"] + list(OFFTHREAD) + ["task_s"]))
-COUNTS = ("tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
-          "compile_misses", "rows_in", "padded_in", "rows_unknown",
+    + ["sync.wait", "sync.in_dispatch", "sync.offthread"] + list(OFFTHREAD)
+    + ["task_s"]))
+COUNTS = ("tasks", "requeues", "backoffs", "syncs", "d2h_bytes", "h2d_bytes",
+          "compile_hits", "compile_misses", "rows_in", "padded_in", "rows_unknown",
           "agg_merges_compiled", "agg_merges_general", "asof_flushes",
           "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
           "asof_match_sort", "asof_match_search",
@@ -101,7 +111,8 @@ COUNTS = ("tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
           "join_builds", "str_pred_dict_rows", "groupby_sort_slots",
           "groupby_groups_out", "scan_hits", "scan_misses")
 KEYS = (("q", "plan_fp", "status") + STAMPS + SECONDS + COUNTS
-        + ("compiled", "pool_size", "park_s_total", "loop_s_total"))
+        + ("compiled", "sync_sites", "pool_size", "park_s_total",
+           "loop_s_total"))
 
 # summed over the operators of the finish-time opstats snapshot
 _FROM_OPSTATS = ("rows_in", "padded_in", "rows_unknown",
@@ -125,6 +136,7 @@ def open(q: str) -> None:  # noqa: A001 — the log's verb
             acc.update(dict.fromkeys(COUNTS, 0))
             acc.update(dict.fromkeys(STAMPS))
             acc["compiled"] = []
+            acc["sync_sites"] = {}  # site -> [count, seconds]; a list at close
             _open[q] = acc
 
 
@@ -167,8 +179,7 @@ def add(q: str, name: str, dur: float, self_s: float) -> None:
 def task(q: str, t0: float, dur: float, parts: Dict[str, float]) -> None:
     """One dispatch that progressed: its start, its duration and, in
     ``parts``, its self times by layer (DISPATCH_LAYERS: they sum to
-    ``dur``) with the ``count_valid.block`` device reads inside it
-    (``sync_blocks``, and ``other.sync_block``: their part of ``other``)."""
+    ``dur``)."""
     with _lock:
         acc = _open.get(q)
         if acc is None:
@@ -180,6 +191,36 @@ def task(q: str, t0: float, dur: float, parts: Dict[str, float]) -> None:
         acc["last_task"] = max(acc["last_task"] or 0.0, t0 + dur)
         for key, v in parts.items():
             acc[key] += v
+
+
+def sync(q: str, reads, where: Optional[str]) -> None:
+    """Device reads of the query's threads (``spans.device_read``), each a
+    ``(site, seconds, bytes)``.  ``where`` is the SECONDS key their seconds
+    also count under: ``sync.in_dispatch`` (a dispatch that progressed),
+    ``sync.offthread`` (a helper thread) or None (``submit``,
+    ``svc.finalize``, a dispatch that could not progress)."""
+    with _lock:
+        acc = _open.get(q)
+        if acc is None:
+            return
+        kept = acc["sync_sites"]
+        for site, seconds, nbytes in reads:
+            acc["syncs"] += 1
+            acc["sync.wait"] += seconds
+            acc["d2h_bytes"] += nbytes
+            if where is not None:
+                acc[where] += seconds
+            ent = kept.setdefault(site, [0, 0.0])
+            ent[0] += 1
+            ent[1] += seconds
+
+
+def h2d(q: str, nbytes: int) -> None:
+    """Bytes a ``bridge.to_device`` span of the query put on the device."""
+    with _lock:
+        acc = _open.get(q)
+        if acc is not None:
+            acc["h2d_bytes"] += nbytes
 
 
 def compiled(q: Optional[str], kind: str, key_hash: str, seconds: float,
@@ -223,6 +264,9 @@ def close(q: str, status: str, plan_fp: Optional[str] = None,
             pool_size=int(pool_size), park_s_total=park_s,
             loop_s_total=loop_s,
             done=time.perf_counter(), wall_done=time.time())
+        top = sorted(acc["sync_sites"].items(), key=lambda kv: -kv[1][1])
+        acc["sync_sites"] = [[site, n, round(seconds, 6)]
+                             for site, (n, seconds) in top[:SYNC_SITES_MAX]]
         _log.append({k: acc[k] for k in KEYS})
 
 
@@ -231,7 +275,8 @@ def records(since: Optional[float] = None) -> List[dict]:
     ``done`` (``time.perf_counter()``) is later."""
     with _lock:
         kept = list(_log)
-    return [dict(r, compiled=[list(c) for c in r["compiled"]])
+    return [dict(r, compiled=[list(c) for c in r["compiled"]],
+                 sync_sites=[list(c) for c in r["sync_sites"]])
             for r in kept if since is None or r["done"] > since]
 
 

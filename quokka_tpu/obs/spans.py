@@ -19,6 +19,13 @@ this module).  Four consumers share one ``span(...)`` call site:
   span is also a ``TraceAnnotation("qk.<name>")`` on the thread that ran
   it, so the device's timeline and the engine's meet on the trace's clock.
 
+``device_read(site, value)`` is the one place a thread of the program blocks
+until the device has produced something: a ``sync.<site>`` span around an
+explicit ``jax.device_get``, seen by all four consumers like any span, and
+summed by the query log into the request's wait for the device (``syncs``,
+``sync.wait``, ``d2h_bytes``, ``sync_sites``) beside the partition, which it
+leaves as it was: a read's seconds stay the enclosing span's own.
+
 Nesting is tracked per thread: a span opened inside another is its child,
 inherits its query id, and adds its duration to the parent's "covered by
 children" sum when it closes.  ``dispatch(...)`` opens the root of one task
@@ -32,8 +39,10 @@ import os
 import threading
 import time
 from collections import defaultdict
-from typing import Dict, Optional
+from typing import Any, Callable, Dict, Optional
 
+import jax
+import numpy as np
 from jax.profiler import TraceAnnotation as _Annotation
 
 from quokka_tpu.obs import querylog as _querylog
@@ -59,9 +68,8 @@ _LAYER_PREFIXES = (
     (("compile.acquire",), "compile.acquire"),
 )
 _LAYER_OF: Dict[str, str] = {}
-# the one blocking device read that is a span (DeviceBatch.count_valid): the
-# record counts it and says how much of ``other`` it is
-_SYNC_BLOCK = "count_valid.block"
+# the span whose ``bytes`` are what crossed host -> device (runtime/engine.py)
+_H2D = "bridge.to_device"
 
 
 def _layer(name: str) -> str:
@@ -99,6 +107,9 @@ class Span:
     __slots__ = ("name", "q", "ring", "args", "parent", "root", "covered",
                  "t0", "dur", "self_s", "keep", "_ann")
 
+    # a transparent span (a device read) leaves its seconds the parent's own
+    transparent = False
+
     def __init__(self, name: str, q: Optional[str] = None,
                  ring: bool = True):
         self.name = name
@@ -134,7 +145,7 @@ class Span:
         _TLS.top = parent
         if self.keep:
             if parent is not None:
-                parent.covered += dt
+                parent.covered += self.covered if self.transparent else dt
             self.self_s = dt - self.covered
             self._account(dt, parent)
         return False
@@ -153,14 +164,16 @@ class Span:
             # inside a dispatch: its thread's own dict, no lock
             layer = _layer(self.name)
             root.parts[layer] = root.parts.get(layer, 0.0) + self.self_s
-            if self.name == _SYNC_BLOCK:
-                parts = root.parts
-                parts["sync_blocks"] = parts.get("sync_blocks", 0) + 1
-                parts["other.sync_block"] = (
-                    parts.get("other.sync_block", 0.0) + self.self_s)
+            if self.name == _H2D and self.args:
+                root.h2d += self.args.get("bytes", 0)
         elif self.q is not None:
             _querylog.add(self.q, _off_name(self.name, parent), dt,
                           self.self_s)
+            if self.name == _H2D and self.args:
+                _querylog.h2d(self.q, self.args.get("bytes", 0))
+        self._emit(dt, parent)
+
+    def _emit(self, dt: float, parent: Optional["Span"]) -> None:
         if not self.ring:
             return
         if _enabled:
@@ -174,7 +187,7 @@ class Span:
             if self.q is not None:
                 args["q"] = self.q
             if parent is not None:
-                args["p"] = "task" if parent is root else parent.name
+                args["p"] = "task" if parent is self.root else parent.name
             rec.record("span", self.name, dur=dt, **args)
 
 
@@ -187,10 +200,85 @@ def _off_name(name: str, parent: Optional[Span]) -> str:
 span = Span
 
 
+def add_bytes(n: int) -> None:
+    """Add ``n`` to the ``bytes`` of the innermost span open on this thread
+    (``pack.pack_put``: what it put on the wire; under ``bridge.to_device``
+    they are the record's ``h2d_bytes``)."""
+    top = getattr(_TLS, "top", None)
+    if top is not None:
+        if top.args is None:
+            top.args = {"bytes": n}
+        else:
+            top.args["bytes"] = top.args.get("bytes", 0) + n
+
+
+class _Sync(Span):
+    """``sync.<site>``: one blocking read of the device (``device_read``) or
+    wait for another thread's (``device_wait``).  Transparent unless it keeps
+    a layer of its own (``sync.count_valid``: ``other``)."""
+
+    __slots__ = ("transparent",)
+
+    def __init__(self, site: str, own_layer: bool):
+        Span.__init__(self, "sync." + site)
+        self.transparent = not own_layer
+
+    def _account(self, dt: float, parent: Optional[Span]) -> None:
+        root = self.root
+        site = self.name[5:]
+        nbytes = self.args["bytes"] if self.args else 0
+        if root is not None:
+            if not self.transparent:
+                root.parts["other"] = root.parts.get("other", 0.0) + self.self_s
+            root.syncs.append((site, dt, nbytes))
+        elif self.q is not None:
+            p = parent
+            while p is not None and p.name != "offthread":
+                p = p.parent
+            _querylog.sync(self.q, [(site, dt, nbytes)],
+                           "sync.offthread" if p is not None else None)
+        self._emit(dt, parent)
+
+
+def _device_bytes(value: Any, host: Any) -> int:
+    if isinstance(value, jax.Array):
+        return host.nbytes
+    if isinstance(value, (np.ndarray, np.generic, int, float, bool)):
+        return 0
+    return sum(h.nbytes for v, h in zip(jax.tree_util.tree_leaves(value),
+                                        jax.tree_util.tree_leaves(host))
+               if isinstance(v, jax.Array))
+
+
+def device_read(site: str, value: Any, own_layer: bool = False) -> Any:
+    """Bring ``value`` (an array, a scalar or a pytree of them) to the host:
+    THE place a thread blocks until the device has produced something.  Opens
+    ``sync.<site>`` around an explicit ``jax.device_get`` (allowed under any
+    ``jax.transfer_guard``, so a process that disallows transfers finds the
+    reads that go beside this), sets the span's ``bytes`` to what came from
+    the device, and returns numpy values shaped like ``value``: the caller
+    converts with ``int()`` / ``bool()`` as it did.  ``site`` is one stable
+    name a call site, dots by layer (``join.build_stats``)."""
+    with _Sync(site, own_layer) as sp:
+        with jax.transfer_guard_device_to_host("allow"):
+            host = jax.device_get(value)
+        sp.args = {"bytes": _device_bytes(value, host)}
+    return host
+
+
+def device_wait(site: str, wait: Callable[[], Any]) -> Any:
+    """``wait()`` under ``sync.<site>``, for a thread that parks until another
+    thread's device read is done (a future's ``result``): a read of 0 bytes."""
+    with _Sync(site, False) as sp:
+        sp.args = {"bytes": 0}
+        return wait()
+
+
 class Dispatch(Span):
     """The root of one task dispatch (``Engine.dispatch_task``).  Spans
-    closed under it sum their self times by layer into ``parts`` (and the
-    ``count_valid.block`` reads among them: a count, and their seconds); the
+    closed under it sum their self times by layer into ``parts``, the device
+    reads among them go into ``syncs`` (site, seconds, bytes) and what
+    ``bridge.to_device`` put into ``h2d``; the
     caller sets ``ok`` before the block ends, and a dispatch that
     progressed hands ``parts`` (its own self time under
     ``runtime.dispatch_self``) to the query's record.  One that could not
@@ -199,13 +287,15 @@ class Dispatch(Span):
     whatever compiles under it.  The caller writes the ``task`` ring event
     itself (it carries the dispatch's causal note)."""
 
-    __slots__ = ("label", "parts", "ok")
+    __slots__ = ("label", "parts", "ok", "syncs", "h2d")
 
     def __init__(self, kind: str, label: str, q: Optional[str]):
         Span.__init__(self, "task:" + kind, q, ring=False)
         self.label = label
         self.parts: Dict[str, float] = {}
         self.ok = False
+        self.syncs: list = []
+        self.h2d = 0
 
     def __enter__(self) -> "Dispatch":
         Span.__enter__(self)
@@ -220,6 +310,11 @@ class Dispatch(Span):
             _querylog.task(self.q, self.t0, dt, self.parts)
         else:
             _querylog.add(self.q, "task.requeue", dt, dt)
+        if self.syncs:
+            _querylog.sync(self.q, self.syncs,
+                           "sync.in_dispatch" if self.ok else None)
+        if self.h2d:
+            _querylog.h2d(self.q, self.h2d)
 
 
 dispatch = Dispatch

@@ -37,6 +37,7 @@ from jax import lax
 import numpy as np
 
 from quokka_tpu import config
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops.batch import (
     DeviceBatch,
     NumCol,
@@ -355,11 +356,11 @@ def _np_time64(col: NumCol) -> np.ndarray:
     columns map through an IEEE bit trick, so the result is only comparable
     against another float column's encoding — _asof_match_host bails when
     the two sides' dtype families differ."""
-    d = np.asarray(col.data)
-    if col.hi is not None:
+    hi, d = tracing.device_read("asof.host_time", (col.hi, col.data))
+    if hi is not None:
         from quokka_tpu.ops import bridge
 
-        return bridge._limbs_to_int64(np.asarray(col.hi), d)
+        return bridge._limbs_to_int64(hi, d)
     if d.dtype.kind == "f":
         # IEEE total-order bit trick: non-negative floats' bit patterns are
         # already ordered non-negative ints; negatives flip their low 63
@@ -373,7 +374,7 @@ def _np_time64(col: NumCol) -> np.ndarray:
 def _time_family(col: NumCol) -> str:
     if col.hi is not None:
         return "i"
-    return "f" if np.asarray(col.data).dtype.kind == "f" else "i"
+    return "f" if col.data.dtype.kind == "f" else "i"
 
 
 def _np_key64(batch: DeviceBatch, by: Sequence[str]) -> "np.ndarray | None":
@@ -382,7 +383,8 @@ def _np_key64(batch: DeviceBatch, by: Sequence[str]) -> "np.ndarray | None":
     to the device kernel."""
     if not by:
         return np.zeros(batch.padded_len, dtype=np.int64)
-    limbs = [np.asarray(l) for l in key_limbs(batch, list(by))]
+    limbs = tracing.device_read("asof.host_keys",
+                                list(key_limbs(batch, list(by))))
     if any(l.dtype.kind == "f" for l in limbs):
         return None
     if len(limbs) == 1:
@@ -411,8 +413,8 @@ def _asof_match_host(trades, quotes, left_on, right_on, left_by, right_by,
         return None
     tt = _np_time64(trades.columns[left_on])
     qt = _np_time64(quotes.columns[right_on])
-    tv = np.asarray(trades.valid)
-    qv = np.asarray(quotes.valid)
+    tv, qv = tracing.device_read("asof.host_valid",
+                                 (trades.valid, quotes.valid))
     tidx = np.flatnonzero(tv)
     qidx = np.flatnonzero(qv)
     tt, tk = np.ascontiguousarray(tt[tidx]), np.ascontiguousarray(tk[tidx])
@@ -671,7 +673,8 @@ class RowBuffer:
     def keep(self, mask: jax.Array) -> None:
         """Drop the live rows outside ``mask`` (one blocking count)."""
         valid = self.valid & mask
-        self._mask(valid, int(jnp.sum(valid.astype(jnp.int32))))
+        self._mask(valid, int(tracing.device_read(
+            "asof.buffer_count", jnp.sum(valid.astype(jnp.int32)))))
 
 
 def asof_join(

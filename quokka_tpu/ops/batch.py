@@ -395,8 +395,10 @@ class DeviceBatch:
             from quokka_tpu.obs import spans as tracing
 
             src = self.nrows_dev if self.nrows_dev is not None else jnp.sum(self.valid)
-            with tracing.span("count_valid.block"):
-                self.nrows = int(src)
+            # the one read with a layer of its own: ``other``, where its
+            # span has always been
+            self.nrows = int(tracing.device_read("count_valid", src,
+                                                 own_layer=True))
         return self.nrows
 
     def note_count(self, num: jax.Array) -> "DeviceBatch":

@@ -198,9 +198,10 @@ def host_cols_to_device(
     return DeviceBatch(cols, valid, nrows=n, sorted_by=sorted_by)
 
 
-def device_to_arrow(batch: DeviceBatch) -> pa.Table:
+def device_to_arrow(batch: DeviceBatch, site: str = "to_arrow") -> pa.Table:
     """Sync a batch to the host as a compacted Arrow table (valid rows only).
-    All columns + the validity mask come back in ONE device->host transfer."""
+    All columns + the validity mask come back in ONE device->host transfer,
+    the blocking read ``sync.<site>`` (``spans.device_read``)."""
     leaves = [batch.valid]
     slots = []
     for col in batch.columns.values():
@@ -217,8 +218,8 @@ def device_to_arrow(batch: DeviceBatch) -> pa.Table:
                 slots.append(2)
             else:
                 slots.append(1)
-    host = pack.get_packed(leaves)
-    mask = np.asarray(host[0])
+    host = pack.get_packed(leaves, site)
+    mask = host[0]
     host_cols = {}
     i = 1
     for (name, col), width in zip(batch.columns.items(), slots):

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from quokka_tpu import config
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.expression import (
     Agg,
     Alias,
@@ -744,12 +745,12 @@ def _cast_to_string(v, batch: DeviceBatch) -> StrCol:
 
     # stringify only VALID, non-null rows: padded/invalid slots hold garbage
     # that would bloat the dictionary and waste host time
-    valid = np.asarray(batch.valid)
-    nm = np.asarray(null_mask(v))
+    valid, nm, data = tracing.device_read(
+        "expr.cast_string", (batch.valid, null_mask(v), v.data))
     live = valid & ~nm
     idx = np.nonzero(live)[0]
     if v.kind == "d":
-        days = np.asarray(v.data)[idx].astype("datetime64[D]")
+        days = data[idx].astype("datetime64[D]")
         host = np.array([str(x) for x in days], dtype=object)
     elif v.kind == "t" or v.hi is not None:
         vals = timewide.host_i64(v, jnp.asarray(live))
@@ -762,10 +763,10 @@ def _cast_to_string(v, batch: DeviceBatch) -> StrCol:
             host = np.array([str(int(x)) for x in vals], dtype=object)
     elif v.kind == "b":
         host = np.array(
-            ["true" if x else "false" for x in np.asarray(v.data)[idx]], dtype=object
+            ["true" if x else "false" for x in data[idx]], dtype=object
         )
     else:
-        data = np.asarray(v.data)[idx]
+        data = data[idx]
         if v.kind == "f":
             host = np.array([str(float(x)) for x in data], dtype=object)
         else:

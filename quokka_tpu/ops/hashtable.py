@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from quokka_tpu.analysis import compat
+from quokka_tpu.obs import spans as tracing
 
 EMPTY = jnp.int32(2**31 - 1)
 
@@ -298,7 +299,7 @@ def hash_groupby(limbs: Tuple[jax.Array, ...], arrays: Tuple[jax.Array, ...],
 
     outs, counts, rep, num, converged = _hash_groupby_jit(
         tuple(limbs), tuple(arrays), ops, valid, capbits)
-    if not bool(converged):
+    if not bool(tracing.device_read("groupby.hash_converged", converged)):
         from quokka_tpu.ops import kernels
 
         kstrategy.note_used("groupby", "sort")  # the fallback is what ran
@@ -362,7 +363,7 @@ def build_table(build, build_keys: Sequence[str], key_limbs_fn,
         capbits = capbits_for(build.padded_len)
         _, tbl, converged = _insert(limbs, valid_fn() & ~nan_rows(raw),
                                     capbits)
-        if not bool(converged):
+        if not bool(tracing.device_read("join.hash_converged", converged)):
             cache[key] = _DIVERGED
             raise HashTableConvergenceError(
                 f"hash-table build did not place every row "

@@ -29,6 +29,7 @@ import numpy as np
 from jax import lax
 
 from quokka_tpu import config
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops import kernels
 from quokka_tpu.runtime import compileplane
 from quokka_tpu.ops.batch import (
@@ -183,7 +184,7 @@ def _build_stats_cached(build: DeviceBatch,
     if hit is None:
         sorted_limbs, _perm, n_valid = _build_sorted_cached(build, build_keys)
         stats = _sorted_build_stats(tuple(sorted_limbs), n_valid)
-        dup, n_ok, kmin, kmax = jax.device_get(stats)
+        dup, n_ok, kmin, kmax = tracing.device_read("join.build_stats", stats)
         hit = cache[key] = _BuildStats(
             bool(dup), int(n_ok), kmin.item(), kmax.item(), *stats[2:])
     return hit
@@ -436,7 +437,8 @@ def hash_join_general(
     match_count, total, offsets, build_pos_sorted, rp = mm_plan_for(
         limbs, valid, p, how, probe_valid=probe.valid
     )
-    ntotal = int(total)  # host sync: pick output bucket
+    # host sync: pick output bucket
+    ntotal = int(tracing.device_read("join.mm_total", total))
     out_padded = config.bucket_size(ntotal)
     probe_idx, build_idx, out_valid = compileplane.aot_kernel_call(
         "mm_expand", _mm_expand,
@@ -506,7 +508,8 @@ def build_keys_unique(build: DeviceBatch, build_keys: Sequence[str]) -> bool:
             raw = key_limbs(build, build_keys)
             ok = _nonnull_valid(build, build_keys) & ~hashtable.nan_rows(raw)
             distinct, n_ok = _distinct_from_table(table.tbl, ok)
-            distinct, n_ok = int(distinct), int(n_ok)
+            distinct, n_ok = map(int, tracing.device_read(
+                "join.hash_distinct", (distinct, n_ok)))
             return distinct == n_ok and nvalid - n_ok <= 1
     st = _build_stats_cached(build, build_keys)
     unique = (not st.dup) and nvalid - st.n_ok <= 1

@@ -30,6 +30,7 @@ import numpy as np
 from jax import lax
 
 from quokka_tpu import config
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops import hashtable
 from quokka_tpu.ops.batch import (
     DeviceBatch,
@@ -591,10 +592,10 @@ def split_by_partition(batch: DeviceBatch, part_ids: jax.Array, n_parts: int,
         ]
     perm, counts, offsets = _aot("partition_plan", _partition_plan,
                                  (part_ids, batch.valid), (n_parts,))
-    with contextlib.suppress(Exception):  # numpy-backed arrays lack it
-        counts.copy_to_host_async()
     _shuffle_sync()
-    host_counts = np.asarray(counts)  # overlaps the plan kernel's execution
+    # the one counts readback of a compacted split (device_get starts the
+    # copy before it waits; numpy-backed counts pass through)
+    host_counts = tracing.device_read("shuffle.counts", counts)
     max_count = int(host_counts.max()) if n_parts else 0
     uniform = config.bucket_size(max_count)
     total = int(host_counts.sum())

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from quokka_tpu.obs import spans as tracing
+
 # below this many elements a min/max or distinct scan costs more than it saves
 _NARROW_MIN_ELEMS = 4096
 # float columns: sample-distinct cutoff before paying for a full unique()
@@ -240,22 +242,19 @@ def pack_put(leaves: Sequence) -> List[jax.Array]:
     if prog is None:
         prog = _build_decode(key)
         _DECODE_PROGRAMS[key] = prog
+    tracing.add_bytes(sum(w.nbytes for w in wires))
     dwires = jax.device_put(wires)
     return list(prog(dwires))
 
 
-def get_packed(arrays: Sequence) -> List[np.ndarray]:
-    """Read device arrays back to host in one ``device_get`` (transfers are
-    started async first so the runtime can pipeline them); returns numpy
-    arrays with the original dtypes/shapes.  No device program is involved —
-    the d2h direction must never pay a compile."""
+def get_packed(arrays: Sequence, site: str = "to_arrow") -> List[np.ndarray]:
+    """Read device arrays back to host in one ``device_get`` under the span
+    ``sync.<site>`` (``spans.device_read``; jax starts every leaf's transfer
+    before it waits for the first); returns numpy arrays with the original
+    dtypes/shapes.  No device program is involved — the d2h direction must
+    never pay a compile."""
     if not arrays:
         return []
     if all(isinstance(a, np.ndarray) for a in arrays):
-        return [np.asarray(a) for a in arrays]
-    for a in arrays:
-        try:
-            a.copy_to_host_async()
-        except AttributeError:
-            pass
-    return [np.asarray(a) for a in jax.device_get(list(arrays))]
+        return list(arrays)
+    return tracing.device_read(site, list(arrays))

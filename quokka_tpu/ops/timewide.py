@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops.batch import DeviceBatch, NumCol
 
 _SIGN = np.uint32(0x80000000)
@@ -88,6 +89,7 @@ def host_max_i64(col: NumCol, valid) -> int:
     neg = jnp.int32(-(2**31))
     mh = jnp.max(jnp.where(valid, hi, neg))
     ml = jnp.max(jnp.where(valid & (hi == mh), lo, neg))
+    mh, ml = tracing.device_read("time.max", (mh, ml))
     return int(mh) * 2**32 + int(ml) + 2**31
 
 
@@ -97,6 +99,7 @@ def host_min_i64(col: NumCol, valid) -> int:
     pos = jnp.int32(2**31 - 1)
     mh = jnp.min(jnp.where(valid, hi, pos))
     ml = jnp.min(jnp.where(valid & (hi == mh), lo, pos))
+    mh, ml = tracing.device_read("time.min", (mh, ml))
     return int(mh) * 2**32 + int(ml) + 2**31
 
 
@@ -113,12 +116,13 @@ def cmp_scalar(col: NumCol, v: int, op: str) -> jax.Array:
 
 def host_i64(col: NumCol, valid) -> np.ndarray:
     """Exact int64 host values of the valid rows (one device->host sync)."""
-    mask = np.asarray(valid)
-    if col.hi is not None:
-        hi = np.asarray(col.hi)[mask].astype(np.int64)
-        lo = np.asarray(col.data)[mask].astype(np.int64) + 2**31
+    mask, hi, lo = tracing.device_read("time.values",
+                                       (valid, col.hi, col.data))
+    if hi is not None:
+        hi = hi[mask].astype(np.int64)
+        lo = lo[mask].astype(np.int64) + 2**31
         return (hi << np.int64(32)) | lo
-    return np.asarray(col.data)[mask].astype(np.int64)
+    return lo[mask].astype(np.int64)
 
 
 def rebase_narrow(col: NumCol, valid, base: int, headroom: int = 0) -> NumCol:
@@ -135,7 +139,8 @@ def rebase_narrow(col: NumCol, valid, base: int, headroom: int = 0) -> NumCol:
     rel = _bitcast(diff_lo, jnp.int32)
     limit = jnp.int32(2**31 - 1 - int(headroom))
     ok = (diff_hi == 0) & (rel >= 0) & (rel <= limit)
-    if not bool(jnp.all(ok | ~valid)):
+    if not bool(tracing.device_read("time.rebase_fits",
+                                    jnp.all(ok | ~valid))):
         unit = f" {col.unit}" if col.unit else ""
         raise ValueError(
             f"time column spans more than 2^31{unit} units within one stream "

@@ -797,7 +797,8 @@ class Engine:
                         # count scalar — a kernel-queue wait on an already-
                         # dispatched reduction, not a shuffle host sync
                         n = (part.nrows if part.nrows is not None
-                             else int(part.nrows_dev)
+                             else int(tracing.device_read(
+                                 "shuffle.adapt_count", part.nrows_dev))
                              if part.nrows_dev is not None else 0)
                         track[tgt_ch] = track.get(tgt_ch, 0) + int(n)
                     name = (actor, channel, seq, tgt_actor, actor, tgt_ch)
@@ -851,7 +852,8 @@ class Engine:
                 if len(self._spill_futs) <= config.SPILL_INFLIGHT:
                     break
                 f = self._spill_futs.pop(0)
-            f.result()  # bound device memory pinned by pending spills
+            # bound device memory pinned by pending spills
+            tracing.device_wait("spill.backlog", f.result)
 
     def _offthread(self, fn, *args):
         """Run ``fn`` on a helper thread (prefetch pool, emitter, spill
@@ -867,7 +869,7 @@ class Engine:
             # the partition, not the parent batch
             if part.padded_len > (1 << 16):
                 part = kernels.compact(part)
-            table = bridge.device_to_arrow(part)
+            table = bridge.device_to_arrow(part, site="spill.part")
             self.g.hbq.put(name, table)
         obs.REGISTRY.counter("shuffle.spill_bytes").inc(table.nbytes)
 
@@ -877,7 +879,8 @@ class Engine:
             with self._spill_lock:
                 futs, self._spill_futs = self._spill_futs, []
             for f in futs:
-                f.result()  # propagate the first spill error loudly
+                # propagate the first spill error loudly
+                tracing.device_wait("spill.flush", f.result)
 
     def _shutdown_spill(self) -> None:
         pool = getattr(self, "_spill_pool", None)
@@ -2050,7 +2053,7 @@ class Engine:
 
     def _convert_and_append(self, info, channel, seq, out):
         with tracing.span("emit.result_d2h"):
-            table = bridge.device_to_arrow(out)
+            table = bridge.device_to_arrow(out, site="emit.result")
         self._result_append(info, channel, seq, table)
 
     def _emit_submit(self, fn) -> None:
@@ -2073,7 +2076,8 @@ class Engine:
                 if len(self._emit_futs) <= self._EMIT_INFLIGHT:
                     break
                 f = self._emit_futs.pop(0)
-            f.result()  # wait OUTSIDE the lock: conversion is a d2h sync
+            # wait OUTSIDE the lock: conversion is a d2h sync
+            tracing.device_wait("emit.backlog", f.result)
 
     def _flush_emits(self) -> None:
         futs = getattr(self, "_emit_futs", None)
@@ -2081,7 +2085,8 @@ class Engine:
             with self._emit_lock:
                 futs, self._emit_futs = self._emit_futs, []
             for f in futs:
-                f.result()  # propagate the first conversion/append error
+                # propagate the first conversion/append error
+                tracing.device_wait("emit.flush", f.result)
 
     def _shutdown_emitter(self) -> None:
         pool = getattr(self, "_emit_pool", None)

@@ -25,6 +25,7 @@ CASES = [
     ("QK009", "qk009_io_timeout.py", 5),     # create_connection, settimeout(None), timeout=None, fsspec.open, fs.mv
     ("QK010", "qk010_counter_dict.py", 3),   # 2x dict +=, 1x .get()+1 RMW
     ("QK011", "qk011_push_sync.py", 3),      # np.asarray, .item(), device_get
+    ("QK011", "qk011_exec_sync.py", 3),      # the same three in an executor
     ("QK012", "qk012_raw_len_key.py", 3),    # sig tuple, .get key, store key
     ("QK013", "qk013_platform_gate.py", 3),  # probe, string gate, _platform
     ("QK018", "qk018_device_alloc.py", 3),   # jnp.zeros, device_put, asarray
@@ -39,8 +40,13 @@ def _fixture(name):
     return os.path.join(FIXTURES, name)
 
 
-@pytest.mark.parametrize("rule,fixture,expected", CASES,
-                         ids=[c[0] for c in CASES])
+# a rule with a second fixture is told apart by the fixture's name
+IDS = [c[0] if c[1].startswith(c[0].lower() + "_") and not any(
+    o[0] == c[0] for o in CASES[:i]) else c[1][:-3]
+       for i, c in enumerate(CASES)]
+
+
+@pytest.mark.parametrize("rule,fixture,expected", CASES, ids=IDS)
 def test_rule_fires_on_fixture(rule, fixture, expected):
     findings = run_lint([_fixture(fixture)])
     hits = [f for f in findings if f.rule == rule]
@@ -51,11 +57,41 @@ def test_rule_fires_on_fixture(rule, fixture, expected):
         [f.render() for f in findings]
 
 
-@pytest.mark.parametrize("rule,fixture,expected", CASES,
-                         ids=[c[0] for c in CASES])
+@pytest.mark.parametrize("rule,fixture,expected", CASES, ids=IDS)
 def test_cli_exits_nonzero_on_fixture(rule, fixture, expected, capsys):
     rc = lint_main([_fixture(fixture), "--no-baseline", "--quiet"])
     assert rc == 1
+
+
+def test_qk011_sees_an_executors_reads_and_passes_the_funnel():
+    """The widened scope: every function of an executor-shaped file is in
+    sight, whatever calls it; the reads that go through
+    ``spans.device_read`` are not findings."""
+    findings = run_lint([_fixture("qk011_exec_sync.py")])
+    assert {f.scope for f in findings} == {"FakeJoinExecutor.execute"}
+    assert sorted(f.snippet.split("#")[0].strip() for f in findings) == [
+        "dup, n_ok = jax.device_get(build.stats)",
+        'keys = np.asarray(build.columns["k"].data)',
+        "nready = jnp.sum(build.valid).item()"]
+    assert all("obs.spans.device_read" in f.message for f in findings)
+
+
+def test_qk011_baselined_read_passes(tmp_path):
+    """A read left on purpose carries a rationale in the baseline and the
+    gate lets it by; the other two still fail it."""
+    import json
+
+    fixture = _fixture("qk011_exec_sync.py")
+    keys = sorted(f.key() for f in run_lint([fixture]))
+    assert len(keys) == 3
+    whole = tmp_path / "whole.json"
+    whole.write_text(json.dumps({"findings": {
+        k: "a path no served request reaches" for k in keys}}))
+    assert lint_main([fixture, "--baseline", str(whole), "--quiet"]) == 0
+    part = tmp_path / "part.json"
+    part.write_text(json.dumps({"findings": {
+        keys[0]: "a path no served request reaches"}}))
+    assert lint_main([fixture, "--baseline", str(part), "--quiet"]) == 1
 
 
 def test_cli_subprocess_entry_point():

@@ -35,10 +35,12 @@ RECORD_KEYS = {
     "runtime.pick", "service.sched_wait", "service.finalize",
     "finalize.flush", "finalize.snapshots", "finalize.cleanup",
     "runtime.dispatch_self", "executors.exec_self", "runtime.push",
-    "io.read", "emit.d2h", "compile.acquire", "other", "other.sync_block",
+    "io.read", "emit.d2h", "compile.acquire", "other",
+    "sync.wait", "sync.in_dispatch", "sync.offthread",
     "offthread.reader.execute", "offthread.bridge.to_device",
     "offthread.emit.result_d2h", "offthread.spill.hbq", "offthread.other",
-    "task_s", "tasks", "requeues", "backoffs", "sync_blocks", "compile_hits",
+    "task_s", "tasks", "requeues", "backoffs", "syncs", "d2h_bytes", "h2d_bytes",
+    "sync_sites", "compile_hits",
     "compile_misses", "rows_in", "padded_in", "rows_unknown",
     "agg_merges_compiled", "agg_merges_general", "asof_flushes",
     "asof_probe_rows", "asof_probe_padded", "asof_quote_padded",
@@ -115,8 +117,9 @@ def test_one_flat_record_per_finished_query_and_nothing_pinned(paths):
             assert set(r) == RECORD_KEYS == set(querylog.KEYS)
             assert r["status"] == "done" and r["pool_size"] == 2
             for key, value in r.items():
-                if key == "compiled":
-                    assert all(isinstance(c, list) and len(c) == 4
+                if key in ("compiled", "sync_sites"):
+                    width = 4 if key == "compiled" else 3
+                    assert all(isinstance(c, list) and len(c) == width
                                and not any(isinstance(x, (list, dict))
                                            for x in c) for c in value)
                 else:
@@ -186,8 +189,12 @@ def test_self_times_partition_the_task_time(paths):
         # them all here: three small queries)
         assert r["task_s"] == pytest.approx(ring_tasks[r["q"]], rel=0.01)
         assert all(r[k] >= 0.0 for k in IN_DISPATCH)
-        assert 0.0 <= r["other.sync_block"] <= r["other"] + 1e-9
-        assert (r["sync_blocks"] > 0) == (r["other.sync_block"] > 0)
+        # the wait for the device lies inside the partition's seconds
+        assert 0.0 <= r["sync.in_dispatch"] <= r["task_s"] + 1e-9
+        assert r["sync.in_dispatch"] + r["sync.offthread"] <= (
+            r["sync.wait"] + 1e-9)
+        assert (r["syncs"] > 0) == (r["sync.wait"] > 0)
+        assert r["syncs"] >= sum(n for _, n, _ in r["sync_sites"]) > 0
         assert r["executors.exec_self"] > 0 and r["runtime.dispatch_self"] > 0
         assert 0 < r["service.finalize"] <= r["done"] - r["last_task"]
         assert (r["finalize.flush"] + r["finalize.snapshots"]
