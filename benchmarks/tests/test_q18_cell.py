@@ -1,0 +1,251 @@
+"""The ``tpch_q18_sf1.q18_s2`` cell: it resolves from entries alone, a traced
+rehearsal of it is ``correct``, an answer with one ``o_totalprice`` or one
+``sum_qty`` altered is not, both bfloat16 controls fail its limits, and its
+two per-layer readers read what their files say and nothing (never an
+error, never a 0) from a run with nothing to read."""
+
+import json
+import types
+
+import pytest
+
+from harness import check, loadgen, lowprec, spec, tables
+
+CELL = "tpch_q18_sf1.q18_s2"
+QUERY = "q18"
+NEW_METRICS = ("agg_merge_slots_per_row", "highcard_groupby_roofline")
+PEAKS = {"hbm_bytes_per_s": 819e9}
+# at the rehearsal's SF 0.1 the specification's QUANTITY keeps an order in
+# about one seed of two; these seeds keep one or more at all four values
+SEED = 2**31 + 3805          # 3, 3, 3 and 2 orders at 312 .. 315
+OTHER_SEED = 2**31 + 3813    # 2, 2, 1 and 1
+
+
+def reader(name):
+    return spec.load_module("metrics", name)
+
+
+def run_cell(capsys, seed, *extra):
+    import run
+
+    rc = run.main(["--workload", CELL, "--seed", str(seed), "--seconds", "3",
+                   "--rehearse", *extra])
+    out = capsys.readouterr().out.strip().splitlines()
+    return rc, json.loads(out[-1])
+
+
+def test_cell_resolves_from_its_entries():
+    cell = spec.Cell(CELL)
+    assert cell.chips == 1 and cell.config_name == "tpch_q18_sf1"
+    conf = cell.config
+    assert conf["datagen"] == {"module": "tpch_q18", "args": {"sf": 1.0},
+                               "rehearsal_args": {"sf": 0.1}}
+    assert conf["reduced"] == ["scale_factor"]
+    assert {"generator", "service", "precision"} <= set(conf["assumed"])
+    for word in ("complete", "exact", "float64", "never", "cached",
+                 "non-durable"):
+        assert word in conf["guarantees"]
+    traffic = cell.traffic
+    assert traffic["clients"] == 2 and traffic["mix"] == {QUERY: 1}
+    assert traffic["params"][QUERY] == {"quantity": {"int_range": [312, 315]}}
+    assert traffic["values_per_run"] == {QUERY: 4}
+    assert traffic["request_timeout_s"] == 120
+    sets = loadgen.plan(traffic, 2**31 + 3806)[QUERY]
+    assert sorted(s["quantity"] for s in sets) == [312, 313, 314, 315]
+    assert list(cell.queries) == [QUERY]
+    entry = {c["name"]: c for c in cell.bench["configs"]}["tpch_q18_sf1"]
+    assert entry["reduced"] == ["scale_factor"]
+    assert len(entry["source"]) <= 200 and "2.4.18" in entry["source"]
+    assert len(cell.entry["why"]) <= 200
+    # appended behind what was there
+    names = [c["name"] for c in cell.bench["configs"]]
+    assert names[-1] == "tpch_q18_sf1"
+    assert [w["name"] for w in cell.bench["workloads"]][-1] == CELL
+    assert {e["name"] for e, _ in cell.metrics("end_to_end")} == {
+        "queries_per_s", "latency_p50_ms", "setup_s"}
+    per_layer = {e["name"]: e for e, _ in cell.metrics("per_layer")}
+    order = [m["name"] for m in cell.bench["per_layer"]]
+    assert order[-2:] == list(NEW_METRICS)
+    for name in NEW_METRICS:
+        assert per_layer[name]["workloads"] == [CELL]
+    assert {"scan_roofline", "device_idle_pct", "pad_waste_pct",
+            "exec_host_ms", "compiles_in_window"} <= set(per_layer)
+    for other in ("tpch_sf1.q1_s2", "tpch_sf1.q3_s2", "ticks_1d.asof_s2",
+                  "h2o_g1_1e7.q5_s2", "tpch8_sf1.q9_s2"):
+        assert not set(NEW_METRICS) & {
+            e["name"] for e, _ in spec.Cell(other).metrics("per_layer")}
+
+
+def test_a_traced_rehearsal_is_correct(capsys):
+    rc, result = run_cell(capsys, SEED, "--trace", "1")
+    assert rc == 0 and result["correct"] is True and result["rehearsal"]
+    assert result["failed"] == 0
+    assert result["window"]["answered_right"] == result["attempted"] >= 4
+    assert result["window"]["parameter_sets"] == 4
+    compared = result["compared"]
+    assert compared[f"{QUERY}.wrong_cells"] == {"value": 0, "limit": 0}
+    assert compared[f"{QUERY}.unanswered"] == {"value": 0, "limit": 0}
+    # o_totalprice is float32 on the device, its rounding well inside the
+    # limit; sum_qty sums integers exactly
+    err = compared[f"{QUERY}.rel_err.o_totalprice"]
+    assert 0 < err["value"] < err["limit"]
+    metrics = result["metrics"]
+    assert metrics["compiles_in_window"]["value"] == 0
+    # the merges sort the partials' groups (the rehearsal's 150 K orders,
+    # each part padded to its bucket), over the configuration's 6.0 M rows
+    merged = metrics["agg_merge_slots_per_row"]
+    assert merged["unit"] == "slots/row" and 0.025 < merged["value"] < 0.5
+    assert "highcard_groupby_roofline" not in metrics  # no chip, no peaks
+
+
+@pytest.mark.parametrize("precision", lowprec.CONTROLS,
+                         ids=[p.name for p in lowprec.CONTROLS])
+def test_both_controls_fail_the_limits_at_the_rehearsal_size(precision):
+    cell = spec.Cell(CELL)
+    q18 = cell.queries[QUERY]
+    numbers = {}
+    for seed in (SEED, OTHER_SEED):
+        paths = tables.for_cell(cell, seed, rehearse=True)
+        for params in loadgen.plan(cell.traffic, seed)[QUERY]:
+            reference = q18.reference(paths, params)
+            assert len(reference) >= 1
+            found, _ = check.compare([q18.control(paths, params, precision)],
+                                     reference, q18.SORT_KEYS, q18.EXACT)
+            check.merge(numbers, found)
+            exact, _ = check.compare([reference], reference, q18.SORT_KEYS,
+                                     q18.EXACT)
+            assert check.judge(exact, q18.LIMITS)
+    # bfloat16 rounds o_totalprice (about 2e-3 at 4e5); only that precision
+    # rounds the sums too (beyond 256 it keeps even integers)
+    limit = q18.LIMITS["rel_err.o_totalprice"]
+    assert numbers["rel_err.o_totalprice"] > 100 * limit
+    if precision is lowprec.BFLOAT16_COLUMNS:
+        assert numbers["rel_err.sum_qty"] == 0  # integers 1..50 stay exact
+    assert not check.judge(numbers, q18.LIMITS)
+
+
+@pytest.mark.parametrize("column", ["o_totalprice", "sum_qty"])
+def test_one_altered_answer_is_not_correct(column, capsys, monkeypatch):
+    """The second answer of the window altered where it is produced: one
+    cell off by a thousandth; every other answer is right."""
+    from quokka_tpu.service.session import QueryHandle
+
+    to_df, run_closed, state = QueryHandle.to_df, loadgen.run_closed, {}
+
+    def window_aware(*a, **kw):
+        if kw.get("seconds") is not None:
+            state["answers"] = 0
+        return run_closed(*a, **kw)
+
+    def altered(self, timeout=None):
+        frame = to_df(self, timeout)
+        if "answers" in state:
+            state["answers"] += 1
+            if state["answers"] == 2:
+                frame = frame.copy()
+                frame.loc[frame.index[0], column] *= 1.001
+        return frame
+
+    monkeypatch.setattr(loadgen, "run_closed", window_aware)
+    monkeypatch.setattr(QueryHandle, "to_df", altered)
+    rc, result = run_cell(capsys, SEED, "--trace", "0")
+    assert rc == 0 and result["correct"] is False
+    assert result["failed"] == 1 and result["attempted"] > 2
+    keys = [f"{QUERY}.sum_rel_err"] + (
+        [f"{QUERY}.rel_err.o_totalprice"] if column == "o_totalprice" else [])
+    for key in keys:
+        assert result["compared"][key]["value"] > (
+            result["compared"][key]["limit"])
+
+
+# -- the two readers on hand-made runs ---------------------------------------
+
+
+def traced_run(device_ops, requests=((100.0, 104.0, 4.0, True),),
+               span=(100.0, 105.0), peaks=PEAKS, query=QUERY):
+    log = [loadgen.Request(client=0, query=query, params={}, t_submit=a,
+                           t_done=b, t_end=b, run_s=c, ok=ok)
+           for a, b, c, ok in requests]
+    return types.SimpleNamespace(
+        log=log, trace={"device_ops": device_ops, "busy_s": 4.9},
+        trace_span=span, peaks=peaks, parameter_sets={})
+
+
+@pytest.fixture
+def counted(monkeypatch):
+    """What the reference counts of the tables at SF 1."""
+    q18 = spec.load_module("queries", QUERY)
+    monkeypatch.setattr(q18, "SUBQUERY", {"rows": 6_001_215,
+                                          "groups": 1_500_000})
+    return 6_001_215 * 8 + 1_500_000 * 8
+
+
+def test_roofline_counts_the_groupby_modules_only_and_cannot_pass_100(
+        counted):
+    m = reader("highcard_groupby_roofline")
+    ops = [["jit_fused_groupby", 0.4], ["jit_sorted_groupby", 0.5],
+           ["jit_agg_recombine", 0.05], ["jit_agg_final_tail", 0.05],
+           ["jit__gather_all", 0.9], ["jit__compact_idx", 0.3],
+           ["jit__pk_probe_sorted", 0.2], ["jit__fused_concat_kernel", 0.1]]
+    got = m.read(traced_run(ops))
+    assert got == pytest.approx(100 * counted / 819e9 / 1.0)
+    assert 0 < got < 100
+    # half of a request inside the span counts half; a failed one nothing
+    half = m.read(traced_run(ops, requests=((98.0, 102.0, 4.0, True),
+                                            (100.0, 104.0, 4.0, False))))
+    assert half == pytest.approx(got / 2)
+    # the span's request in the least time the peak allows: 100 exactly
+    assert m.read(traced_run([["jit_fused_groupby", counted / 819e9]])) == (
+        pytest.approx(100.0))
+
+
+def test_every_new_reader_returns_none_with_nothing_to_read(monkeypatch,
+                                                            counted):
+    from quokka_tpu.obs import querylog
+
+    roofline = reader("highcard_groupby_roofline")
+    empty = types.SimpleNamespace(log=[], trace=None, trace_span=None,
+                                  peaks=PEAKS, parameter_sets={})
+    for name in NEW_METRICS:
+        assert reader(name).read(empty) is None
+    ops = [["jit_fused_groupby", 3.0]]
+    # no peaks (a rehearsal), no group-by module among the span's, another
+    # query's requests, no request at all, nothing the reference counted
+    assert roofline.read(traced_run(ops, peaks=None)) is None
+    assert roofline.read(traced_run([["jit__gather_all", 1.0]])) is None
+    assert roofline.read(traced_run(ops, query="q3")) is None
+    assert roofline.read(traced_run(ops, requests=())) is None
+    monkeypatch.setattr(spec.load_module("queries", QUERY), "SUBQUERY", {})
+    assert roofline.read(traced_run(ops)) is None
+    # records without the counter (the parent has none), the counter at 0,
+    # and no record
+    run = types.SimpleNamespace(log=[loadgen.Request(
+        client=0, query=QUERY, params={}, t_submit=100.0, t_end=110.0)])
+    recs = [{"status": "done", "done": 103.0, "groupby_sort_slots": 5}]
+    monkeypatch.setattr(
+        querylog, "records",
+        lambda since=None: [r for r in recs if r["done"] > (since or 0)])
+    merges = reader("agg_merge_slots_per_row")
+    assert merges.read(run) is None
+    recs[0]["agg_merge_slots"] = 0
+    assert merges.read(run) is None
+    monkeypatch.setattr(querylog, "records", lambda since=None: [])
+    assert merges.read(run) is None
+
+
+def test_the_counter_reader_reads_the_window_records(monkeypatch):
+    from quokka_tpu.obs import querylog
+
+    run = types.SimpleNamespace(log=[loadgen.Request(
+        client=0, query=QUERY, params={}, t_submit=100.0, t_end=110.0)])
+    recs = [
+        {"status": "done", "done": 99.0, "agg_merge_slots": 10**12},  # before
+        {"status": "done", "done": 103.0, "agg_merge_slots": 6_291_456},
+        {"status": "done", "done": 108.0, "agg_merge_slots": 5_242_880},
+        {"status": "failed", "done": 109.0, "agg_merge_slots": 10**12},
+    ]
+    monkeypatch.setattr(
+        querylog, "records",
+        lambda since=None: [r for r in recs if r["done"] > (since or 0)])
+    assert reader("agg_merge_slots_per_row").read(run) == pytest.approx(
+        (6_291_456 + 5_242_880) / (2 * 6e6))

@@ -263,8 +263,8 @@ def _interp_node(node: logical.Node, inputs):
         out = left.merge(r2, on=list(node.left_on), how="inner")
         return out[list(node.schema)]
     if isinstance(node, logical.AggNode):
-        if node.having is not None or node.order_by or node.limit is not None:
-            raise NotImplementedError("planfuzz interp: having/order/limit agg")
+        if node.order_by or node.limit is not None:
+            raise NotImplementedError("planfuzz interp: order/limit agg")
         df = inputs[0].copy()
         plan = node.plan
         for tmp, e in plan.pre:
@@ -300,6 +300,8 @@ def _interp_node(node: logical.Node, inputs):
             pdf = pd.DataFrame({k: [v] for k, v in parts.items()})
         for out_name, e in plan.finals:
             pdf[out_name] = _eval(e, pdf)
+        if node.having is not None:
+            pdf = pdf[_eval(node.having, pdf).astype(bool)]
         return pdf[list(node.schema)]
     if isinstance(node, logical.DistinctNode):
         return inputs[0][list(node.keys)].drop_duplicates()[list(node.schema)]

@@ -389,6 +389,10 @@ class FinalAggExecutor(_FoldsPartials, Executor):
         # dedupe (a key may also be an output)
         cols = list(dict.fromkeys(self.keys + [n for n, _ in self.plan.finals]))
         g = g.select(cols)
+        if self.order_by or self.limit is not None:
+            from quokka_tpu.obs import opstats
+
+            opstats.note(agg_merge_slots=g.padded_len)
         if self.order_by:
             names = [n for n, _ in self.order_by]
             desc = [d for _, d in self.order_by]

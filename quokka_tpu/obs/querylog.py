@@ -57,7 +57,10 @@ Record keys (README "Observability" documents each):
   the host: ops/expr_compile.py), ``groupby_sort_slots``,
   ``groupby_groups_out`` (the general group-by: padded slots its partial
   aggregates and merges sorted, and the groups they emitted: ops/fuse.py,
-  ops/aggtail.py, ops/kernels.py), ``scan_hits``, ``scan_misses``;
+  ops/aggtail.py, ops/kernels.py), ``agg_merge_slots`` (padded slots the
+  aggregators' merges and final tails sorted, compiled or op by op, the
+  per-batch partials left out: ops/aggtail.py, executors/sql_execs.py),
+  ``scan_hits``, ``scan_misses``;
 - ``pool_size`` and ``park_s_total``, ``loop_s_total``: the service's
   worker threads, and their cumulative counters when the query finished.
 """
@@ -112,8 +115,8 @@ COUNTS = ("tasks", "requeues", "backoffs", "syncs", "d2h_bytes", "h2d_bytes",
           "asof_match_sort", "asof_match_search",
           "join_probe_direct", "join_probe_search", "join_payload_slots",
           "join_probe_general", "join_builds", "str_pred_dict_rows",
-          "groupby_sort_slots", "groupby_groups_out", "scan_hits",
-          "scan_misses")
+          "groupby_sort_slots", "groupby_groups_out", "agg_merge_slots",
+          "scan_hits", "scan_misses")
 KEYS = (("q", "plan_fp", "status") + STAMPS + SECONDS + COUNTS
         + ("compiled", "sync_sites", "pool_size", "park_s_total",
            "loop_s_total"))
@@ -126,7 +129,7 @@ _FROM_OPSTATS = ("rows_in", "padded_in", "rows_unknown",
                  "join_probe_direct", "join_probe_search",
                  "join_payload_slots", "join_probe_general", "join_builds",
                  "str_pred_dict_rows", "groupby_sort_slots",
-                 "groupby_groups_out")
+                 "groupby_groups_out", "agg_merge_slots")
 
 _lock = threading.Lock()
 _open: Dict[str, dict] = {}   # live queries' accumulators

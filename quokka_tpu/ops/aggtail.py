@@ -210,6 +210,10 @@ def recombine(keys: Sequence[str], ops: Sequence[Tuple[str, str]],
     note_path(False)
     parts = [kernels.compact(p) for p in parts]
     merged = bridge.concat_batches(parts) if len(parts) > 1 else parts[0]
+    if keys:
+        from quokka_tpu.obs import opstats
+
+        opstats.note(agg_merge_slots=merged.padded_len)
     aggs = [(p, op, merged.columns[p].data) for (p, op) in ops]
     return kernels.groupby_aggregate(merged, keys, aggs).select(names)
 
@@ -261,7 +265,8 @@ def _recombine_compiled(keys, ops, parts: List[DeviceBatch]) -> DeviceBatch:
     if keys:
         from quokka_tpu.obs import opstats
 
-        opstats.note(groupby_sort_slots=total, groupby_groups_out=num)
+        opstats.note(groupby_sort_slots=total, groupby_groups_out=num,
+                     agg_merge_slots=total)
     cols = {}
     for name, (data, hi) in zip(keys, key_outs):
         c0 = first.columns[name]
@@ -386,6 +391,10 @@ def final_tail(g: DeviceBatch, keys: Sequence[str], plan, having,
     if meta is None:
         return None
     outs, valid, num = _dispatch_program(sig, lambda: jax.jit(body), args)
+    if by or limit is not None:
+        from quokka_tpu.obs import opstats
+
+        opstats.note(agg_merge_slots=g.padded_len)  # the tail's one sort
     cols, outs = {}, iter(outs)
     kinds = {n: (kind, unit) for n, kind, unit in meta}
     for name in out_names:

@@ -147,6 +147,11 @@ def _build_sorted_cached(build: DeviceBatch, build_keys: Sequence[str]):
 # twice as selective; a build thinner than that is sparse and keeps the
 # search, whose ~20 gathers a row then cost less than the table's bytes.
 DIRECT_SLOTS_PER_BUILD_ROW = 32
+# ... and any build may take this many (32 MB): a build of a few rows over a
+# wide key range (the orders a HAVING keeps, Q18) would otherwise pay the
+# search's log2(padded build) + 1 gathers for every probe row of a scan, where
+# the table costs one gather a row and 32 MB while the build lives
+DIRECT_MIN_SLOTS = 1 << 23
 
 
 @jax.jit
@@ -225,7 +230,8 @@ def _direct_table(build: DeviceBatch, build_keys: Sequence[str]):
     st = _build_stats_cached(build, build_keys)
     span = st.kmax - st.kmin + 1  # python ints: no overflow
     limit = min(config.MAX_BUCKET,
-                DIRECT_SLOTS_PER_BUILD_ROW * build.padded_len)
+                max(DIRECT_SLOTS_PER_BUILD_ROW * build.padded_len,
+                    DIRECT_MIN_SLOTS))
     # the span first (`bucket_size` refuses one beyond MAX_BUCKET), then
     # its rung: below the ladder's knee the next rung is 4x away
     if st.n_ok == 0 or st.dup or span > limit:

@@ -3,7 +3,8 @@
 Pass lineup mirrors the reference driver (pyquokka/df.py:887-907):
   1. push_filters      — predicate pushdown per CNF conjunct, through
                          projections/maps/joins down into source readers
-                         (df.py:1029-1139 + parquet pushdown)
+                         (df.py:1029-1139 + parquet pushdown); a filter
+                         over a group-by becomes its HAVING
   2. early_projection  — column-requirement analysis; prunes the column set
                          each source actually reads (df.py:1141-1262)
   3. choose_broadcast  — catalog-estimated small build sides switch their
@@ -216,6 +217,17 @@ def _try_push_one(sub, sink_id, fid, fnode, pid, parent, cons) -> bool:
         fnode.sorted_by = _copy_order(sub[fnode.parents[0]])
         parent.parents = [fid]
         _relink_except(sub, sink_id, fid, pid, skip=pid)
+        return True
+
+    if isinstance(parent, logical.AggNode) and parent.limit is None:
+        if parent_shared:
+            return False
+        # a filter over a group-by's output is its HAVING: the final
+        # aggregate applies it before its ORDER BY and compacts what it keeps
+        # (as a filter it masked the final's whole padded output)
+        parent.having = (pred if parent.having is None
+                         else conjoin([parent.having, pred]))
+        _relink(sub, sink_id, fid, pid)
         return True
 
     if isinstance(parent, logical.MapNode) and parent.exprs is not None:

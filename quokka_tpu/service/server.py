@@ -316,6 +316,7 @@ class QueryService:
                 session = QuerySession(qid, graph, sink_actor, est,
                                        self.inflight_per_query)
                 session.durable = durable
+                session.schema = list(stream.schema)
                 if deadline_s is not None:
                     session.deadline_at = (session.submitted_at
                                            + float(deadline_s))

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 # status values a session moves through (strictly forward)
 QUEUED = "queued"
@@ -95,6 +95,9 @@ class QuerySession:
         # final progress snapshot (obs/progress.py), stamped fraction=1.0
         # at finish before the tracker drops the query
         self.progress_snap: Optional[Dict] = None
+        # the plan's output columns (QueryService.submit): an answer with no
+        # rows is an empty frame of them, as DataStream.collect gives
+        self.schema: Optional[List[str]] = None
 
     # -- finish (exactly once) ----------------------------------------------
     def finish(self, error: Optional[BaseException] = None) -> bool:
@@ -299,7 +302,12 @@ class QueryHandle:
         return self._materialize(self.result(timeout).to_arrow)
 
     def to_df(self, timeout: Optional[float] = None):
-        return self._materialize(self.result(timeout).to_df)
+        df = self._materialize(self.result(timeout).to_df)
+        if df is None and self._s.schema is not None:
+            import pandas as pd
+
+            return pd.DataFrame(columns=self._s.schema)
+        return df
 
     def _materialize(self, convert):
         """What the caller's thread still does once the query is done (the

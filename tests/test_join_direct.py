@@ -176,9 +176,17 @@ def _string_key():
 
 
 def _span_beyond_the_multiple():
-    # 256 build slots may hold a table of 8,192: a span of 40,000 may not
-    b = R.permutation(np.arange(0, 40_000, 200))
-    return Side([b]), Side([_probe_around(b, n=3000)])
+    # 1,024 build slots may hold a table of 32,768, and any build one of
+    # 8 M (DIRECT_MIN_SLOTS): a span of 12 M may not
+    b = R.permutation(np.arange(0, 12_000_000, 12_000))
+    return Side([b]), Side([np.concatenate([_probe_around(b, n=3000), b])])
+
+
+def _few_rows_wide_span():
+    # the orders a HAVING keeps (Q18): ten keys over 6.0 M, a table of 8 M
+    b = R.choice(6_000_000, 10, replace=False)
+    return Side([b]), Side([np.concatenate([_probe_around(b, n=3000),
+                                            np.repeat(b, 30)])])
 
 
 def _span_beyond_max_bucket():
@@ -209,6 +217,7 @@ CASES = {
     "wide_int64": (_wide_int64, False),
     "float_key": (_float_key, False),
     "string_key": (_string_key, False),
+    "few_rows_wide_span": (_few_rows_wide_span, True),
     "span_beyond_the_multiple": (_span_beyond_the_multiple, False),
     "span_beyond_max_bucket": (_span_beyond_max_bucket, False),
     "duplicate_build_keys": (_duplicate_build_keys, False),

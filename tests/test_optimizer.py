@@ -76,6 +76,38 @@ class TestPushdown:
         fact_src = [s for s in srcs if "x" in s.schema][0]
         assert fact_src.predicate is not None and "x" in fact_src.predicate.sql()
 
+    @pytest.mark.parametrize("limit", [None, 5])
+    def test_filter_over_a_groupby_is_its_having(self, pq_env, limit):
+        """A filter over a group-by's output folds into the group-by as its
+        HAVING, where the final aggregate compacts what it keeps; over a
+        LIMIT it must stay after it."""
+        fp, dp, fdf, ddf = pq_env
+        exp = fdf.groupby("k").agg(n=("x", "size"), sx=("x", "sum"))
+        exp = exp[(exp.n > 200) & (exp.sx > 0)].reset_index()
+        for opt in (True, False):
+            ctx = QuokkaContext(optimize=opt)
+            q = (ctx.read_parquet(fp).groupby("k", orderby=["k"])
+                 .agg_sql("count(*) as n, sum(x) as sx"))
+            if limit is not None:
+                q = q._child(logical.AggNode(
+                    list(q._node.parents), q.schema, q._node.keys,
+                    q._node.plan, order_by=[("k", False)], limit=limit))
+            q = q.filter(col("n") > 200).filter(col("sx") > 0)
+            if opt:
+                sub, sid = optimized_plan(q)
+                aggs = find_nodes(sub, sid, logical.AggNode)
+                filters = find_nodes(sub, sid, logical.FilterNode)
+                if limit is None:
+                    assert not filters and aggs[-1].having is not None
+                    assert aggs[-1].having.required_columns() == {"n", "sx"}
+                else:
+                    assert filters and aggs[-1].having is None
+            got = q.collect().sort_values("k").reset_index(drop=True)
+            want = exp if limit is None else (
+                exp[exp.k.isin(range(limit))].reset_index(drop=True))
+            pd.testing.assert_frame_equal(got, want, check_dtype=False,
+                                          rtol=1e-9)
+
     def test_pushdown_correctness(self, pq_env):
         fp, dp, fdf, ddf = pq_env
         for opt in (True, False):

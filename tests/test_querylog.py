@@ -47,7 +47,7 @@ RECORD_KEYS = {
     "asof_match_sort", "asof_match_search",
     "join_probe_direct", "join_probe_search", "join_payload_slots",
     "join_probe_general", "join_builds", "str_pred_dict_rows", "groupby_sort_slots",
-    "groupby_groups_out", "scan_hits", "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
+    "groupby_groups_out", "agg_merge_slots", "scan_hits", "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
 }
 IN_DISPATCH = ("runtime.dispatch_self", "executors.exec_self",
                "runtime.push", "io.read", "emit.d2h", "compile.acquire",
@@ -349,10 +349,10 @@ def test_explain_as_dict_has_a_pinned_shape(paths):
     for op in snap["operators"]:
         assert always <= set(op)
         # what is left are the executors' own notes: rows (join_build_rows
-        # ...), the aggregators' merges by path (ops/aggtail.py), the
-        # general group-by's sorted slots and emitted groups, and the joins'
-        # builds and probe slots
-        assert all(k.endswith("_rows") or k.startswith(("agg_merges_",
+        # ...), the aggregators' merges by path and the slots they sorted
+        # (ops/aggtail.py), the general group-by's sorted slots and emitted
+        # groups, and the joins' builds and probe slots
+        assert all(k.endswith("_rows") or k.startswith(("agg_merge",
                                                         "groupby_", "join_"))
                    for k in set(op) - always - sometimes), sorted(op)
         assert op["padded_in"] >= op["rows_in"] >= 0
