@@ -60,7 +60,10 @@ Record keys (README "Observability" documents each):
   ops/aggtail.py, ops/kernels.py), ``agg_merge_slots`` (padded slots the
   aggregators' merges and final tails sorted, compiled or op by op, the
   per-batch partials left out: ops/aggtail.py, executors/sql_execs.py),
-  ``scan_hits``, ``scan_misses``;
+  ``compact_sort_slots``, ``compact_nonzero_slots`` (mask slots whose live
+  indices one sort of a packed key found, and ``jnp.nonzero``: every
+  compaction, fused concat and asof take: ops/kernels.py
+  ``compact_method``), ``scan_hits``, ``scan_misses``;
 - ``pool_size`` and ``park_s_total``, ``loop_s_total``: the service's
   worker threads, and their cumulative counters when the query finished.
 """
@@ -116,6 +119,7 @@ COUNTS = ("tasks", "requeues", "backoffs", "syncs", "d2h_bytes", "h2d_bytes",
           "join_probe_direct", "join_probe_search", "join_payload_slots",
           "join_probe_general", "join_builds", "str_pred_dict_rows",
           "groupby_sort_slots", "groupby_groups_out", "agg_merge_slots",
+          "compact_sort_slots", "compact_nonzero_slots",
           "scan_hits", "scan_misses")
 KEYS = (("q", "plan_fp", "status") + STAMPS + SECONDS + COUNTS
         + ("compiled", "sync_sites", "pool_size", "park_s_total",
@@ -129,7 +133,8 @@ _FROM_OPSTATS = ("rows_in", "padded_in", "rows_unknown",
                  "join_probe_direct", "join_probe_search",
                  "join_payload_slots", "join_probe_general", "join_builds",
                  "str_pred_dict_rows", "groupby_sort_slots",
-                 "groupby_groups_out", "agg_merge_slots")
+                 "groupby_groups_out", "agg_merge_slots",
+                 "compact_sort_slots", "compact_nonzero_slots")
 
 _lock = threading.Lock()
 _open: Dict[str, dict] = {}   # live queries' accumulators

@@ -472,12 +472,15 @@ def _append_rows(bufs: Tuple[jax.Array, ...], buf_valid: jax.Array,
 
 
 def _take_rows(arrays: Tuple[jax.Array, ...], valid: jax.Array,
-               ready: jax.Array, n: jax.Array, chunk: int):
+               ready: jax.Array, n: jax.Array, chunk: int, method: str):
     """The first ``n`` rows of ``ready`` (a subset of ``valid``; ``n`` at
     most ``chunk`` and at most its count) as a chunk of exactly ``chunk``
     slots under its own mask, and the buffer's mask without them.  The rows
-    stay where they are: a buffer is a log."""
-    idx = jnp.nonzero(ready, size=chunk, fill_value=0)[0]
+    stay where they are: a buffer is a log.  ``method``: how the rows'
+    indices are found (``kernels.live_indices``)."""
+    from quokka_tpu.ops import kernels
+
+    idx = kernels.live_indices(ready, chunk, method)
     live = jnp.arange(chunk) < n
     outs = tuple(
         jnp.where(_row_mask(live, a), a[idx], jnp.zeros((), a.dtype))
@@ -496,7 +499,7 @@ def append_kernel():
 
 @functools.lru_cache(maxsize=None)
 def take_kernel():
-    return jax.jit(_take_rows, static_argnames=("chunk",))
+    return jax.jit(_take_rows, static_argnames=("chunk", "method"))
 
 
 class RowBuffer:
@@ -605,7 +608,11 @@ class RowBuffer:
 
     def _make_room(self, slots: int) -> None:
         if self.rows < self.end:  # dead slots: close them first
-            idx = jnp.nonzero(self.valid, size=self.capacity, fill_value=0)[0]
+            from quokka_tpu.ops import kernels
+
+            idx = kernels._compact_idx(
+                self.valid, self.capacity,
+                kernels.compact_method(self.capacity))
             live = jnp.arange(self.capacity) < self.rows
             self._set([jnp.where(_row_mask(live, a), a[idx],
                                  jnp.zeros((), a.dtype))
@@ -646,12 +653,14 @@ class RowBuffer:
     def take(self, ready: jax.Array, n: int, chunk: int) -> DeviceBatch:
         """The first ``n`` rows of ``ready`` (at most ``chunk``) as a batch
         of ``chunk`` slots; they leave the buffer."""
+        from quokka_tpu.ops import kernels
         from quokka_tpu.runtime import compileplane
 
         arrays, live, valid = compileplane.aot_kernel_call(
             "asof_take", take_kernel(),
             (tuple(flat_arrays(self.columns)), self.valid, ready,
-             np.int32(n)), (chunk,))
+             np.int32(n)), (chunk, kernels.compact_method(self.capacity)),
+            form=(kernels.COMPACT_FORM,))
         self._mask(valid, self.rows - n)
         return DeviceBatch(rebuild_columns(self.columns, arrays), live, n,
                            self.sorted_by)

@@ -385,21 +385,24 @@ def concat_batches(batches: Sequence[DeviceBatch]) -> DeviceBatch:
     return DeviceBatch(out_cols, valid, nrows=total, sorted_by=sorted_by)
 
 
-@functools.partial(jax.jit, static_argnames=("out_padded",))
-def _fused_concat_kernel(part_arrays, valids, out_padded: int):
+@functools.partial(jax.jit, static_argnames=("out_padded", "method"))
+def _fused_concat_kernel(part_arrays, valids, out_padded: int, method: str):
     """One XLA program for the whole compact-concat: stack validity, gather
     the live rows of every column to the front of one bucketed output.
     ``part_arrays``: per column, the tuple of per-part arrays.  Replaces the
     eager per-part compact + per-column concat chain (dozens of dispatches
     and intermediate buffers per call) that dominated the vectorized
-    probe/aggregate pipelines' host overhead."""
+    probe/aggregate pipelines' host overhead.  ``method``: how the live
+    rows' indices are found (``kernels.live_indices``)."""
+    from quokka_tpu.ops import kernels
+
     vcat = jnp.concatenate(valids)
-    idx = jnp.nonzero(vcat, size=out_padded, fill_value=0)[0]
+    idx = kernels.live_indices(vcat, out_padded, method)
     live = jnp.arange(out_padded) < jnp.sum(vcat.astype(jnp.int32))
     outs = []
     for arrays in part_arrays:
         g = jnp.concatenate(arrays)[idx]
-        # zero the invalid tail (nonzero's fill duplicates row 0 there):
+        # zero the invalid tail (the index's fill duplicates row 0 there):
         # downstream sort-segmented kernels key off raw limb values and a
         # duplicated real key could extend a segment into the padding
         m = live if g.ndim == 1 else live[:, None]
@@ -441,11 +444,14 @@ def _try_fused_concat(batches, total: int, padded: int):
                                 tuple(c.hi for c in cols)))
             str_meta[name] = cols[0]  # aligned kind/unit source
     valids = tuple(jnp.asarray(b.valid) for b in batches)
+    from quokka_tpu.ops import kernels
     from quokka_tpu.runtime import compileplane
 
+    method = kernels.compact_method(sum(v.shape[0] for v in valids))
     outs, valid = compileplane.aot_kernel_call(
         "fused_concat", _fused_concat_kernel,
-        (tuple(arrs for (_n, _k, arrs) in per_col), valids), (padded,))
+        (tuple(arrs for (_n, _k, arrs) in per_col), valids),
+        (padded, method), form=(kernels.COMPACT_FORM,))
     out_cols = {}
     it = iter(zip(per_col, outs))
     pending_hi = {}

@@ -47,12 +47,12 @@ from quokka_tpu.ops.batch import (
 # ---------------------------------------------------------------------------
 
 
-def _aot(kind, jit_fn, args, statics=()):
+def _aot(kind, jit_fn, args, statics=(), form=()):
     """Kernel dispatch through the compile plane (persisted executables,
     canonical aval keys); inlines untouched inside traces."""
     from quokka_tpu.runtime import compileplane
 
-    return compileplane.aot_kernel_call(kind, jit_fn, args, statics)
+    return compileplane.aot_kernel_call(kind, jit_fn, args, statics, form)
 
 
 def apply_mask(batch: DeviceBatch, mask: jax.Array) -> DeviceBatch:
@@ -66,10 +66,61 @@ def _mask_and_count(valid, mask):
     return v, jnp.sum(v.astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("out_size",))
-def _compact_idx(valid, out_size):
-    idx = jnp.nonzero(valid, size=out_size, fill_value=0)[0]
-    return idx
+# Names ``live_indices``' sort body in the key of every persisted program
+# that traces it (``compact_idx`` below, ``fused_concat`` in ops/bridge.py,
+# ``asof_take`` in ops/asof.py): an edit of the body changes this string, or
+# the AOT store goes on running the old executable under the unchanged key.
+COMPACT_FORM = "pack_unstable_sort"
+
+
+def _nonzero_indices(valid, size):
+    return jnp.nonzero(valid, size=size, fill_value=0)[0]
+
+
+def _sorted_indices(valid, size):
+    n = valid.shape[0]
+    iota = jnp.arange(n, dtype=jnp.int32)
+    # unique values: a stable sort would carry an iota operand as its tie
+    # break, a second operand for nothing
+    key = lax.sort(jnp.where(valid, iota, iota + n), is_stable=False)
+    key = jnp.concatenate(
+        [key, jnp.full(max(size - n, 0), 2 * n, jnp.int32)])[:size]
+    return jnp.where(key < n, key, 0)
+
+
+_LIVE_INDICES = {"nonzero": _nonzero_indices, "sort": _sorted_indices}
+
+
+def live_indices(valid: jax.Array, size: int, method: str) -> jax.Array:
+    """``jnp.nonzero(valid, size=size, fill_value=0)[0]``, value for value.
+
+    ``method`` is the ``compact`` row of the strategy matrix.  ``nonzero``
+    is jax's own: a cumsum, a ``bincount`` (a scatter-add of one update a
+    mask slot, whatever ``size`` is) and a cumsum.  ``sort`` sorts one int32
+    operand, each slot's index with the mask's length added where the slot
+    is dead: the live indices come first in their order, the dead ones
+    after, and every entry from the mask's length on reads 0 as nonzero's
+    fill does.  Works inside a trace; ``size`` and ``method`` are
+    static."""
+    return _LIVE_INDICES[method](valid, size)
+
+
+def compact_method(slots: int) -> str:
+    """The ``compact`` strategy for a compaction over ``slots`` mask slots,
+    recorded as what ran (``strategy.note_used``, and the operator's
+    ``compact_sort_slots`` / ``compact_nonzero_slots``)."""
+    from quokka_tpu.obs import opstats
+    from quokka_tpu.ops import strategy as kstrategy
+
+    method = kstrategy.choice("compact")
+    kstrategy.note_used("compact", method)
+    opstats.note(**{f"compact_{method}_slots": int(slots)})
+    return method
+
+
+@functools.partial(jax.jit, static_argnames=("out_size", "method"))
+def _compact_idx(valid, out_size, method):
+    return live_indices(valid, out_size, method)
 
 
 def compact(batch: DeviceBatch) -> DeviceBatch:
@@ -79,7 +130,8 @@ def compact(batch: DeviceBatch) -> DeviceBatch:
     padded = config.bucket_size(n)
     if n == batch.padded_len and padded == batch.padded_len:
         return batch
-    idx = _aot("compact_idx", _compact_idx, (batch.valid,), (padded,))
+    idx = _aot("compact_idx", _compact_idx, (batch.valid,),
+               (padded, compact_method(batch.padded_len)), (COMPACT_FORM,))
     valid = jnp.arange(padded) < n
     return batch.take(idx, valid, n)
 

@@ -40,6 +40,9 @@ Operators and choices:
                                      sort vs the cached-quote-sort device
                                      binary search)
   shuffle     masked | compacted    (kernels.split_by_partition modes)
+  compact     nonzero | sort        (kernels.live_indices: jnp.nonzero, a
+                                     scatter-add of one update a mask slot,
+                                     vs one sort of a packed int32 key)
 
 This module and config.py are the ONLY places allowed to probe the platform
 (lint rule QK013): a platform string check anywhere else is a scattered gate
@@ -61,6 +64,7 @@ OPS: Dict[str, Tuple[str, ...]] = {
     "join_build": ("sort", "hashtable"),
     "asof": ("host", "sort", "searchsorted"),
     "shuffle": ("masked", "compacted"),
+    "compact": ("nonzero", "sort"),
 }
 
 # The pre-calibration safe defaults — the argued per-platform gates that
@@ -72,18 +76,21 @@ OPS: Dict[str, Tuple[str, ...]] = {
 # the search pays 72 a trade, the merge as sort, running maxima, sort
 # (ops/asof.py _asof_match: nothing indexed over the quote slots; PERF.md
 # section 6, PR 35).  Neither needs a host round trip.
+# A compaction's live indices: the TPU sorts a packed key (0.48 ms at 1<<20
+# slots on a v5e) where ``nonzero``'s scatter-add costs 9.4 ms; the CPU and
+# GPU scatter cheaply and keep ``nonzero``.
 _PLATFORM_DEFAULTS: Dict[str, Dict[str, str]] = {
     "cpu": {"groupby": "hashtable", "join_build": "hashtable",
-            "asof": "host", "shuffle": "masked"},
+            "asof": "host", "shuffle": "masked", "compact": "nonzero"},
     "gpu": {"groupby": "hashtable", "join_build": "hashtable",
-            "asof": "searchsorted", "shuffle": "masked"},
+            "asof": "searchsorted", "shuffle": "masked", "compact": "nonzero"},
     "tpu": {"groupby": "sort", "join_build": "sort",
-            "asof": "sort", "shuffle": "masked"},
+            "asof": "sort", "shuffle": "masked", "compact": "sort"},
 }
 _PLATFORM_DEFAULTS["cuda"] = _PLATFORM_DEFAULTS["gpu"]
 _PLATFORM_DEFAULTS["rocm"] = _PLATFORM_DEFAULTS["gpu"]
 _FALLBACK_DEFAULTS = {"groupby": "sort", "join_build": "sort",
-                      "asof": "sort", "shuffle": "masked"}
+                      "asof": "sort", "shuffle": "masked", "compact": "sort"}
 
 _CALIB_VERSION = 1
 
@@ -479,6 +486,8 @@ def calibrate(rows: Optional[int] = None, reps: int = 3,
         "compacted": _time_best(lambda: _shuffle(True), reps),
     }
 
+    # compact is not timed: its platform default stands (a device trace
+    # priced both forms: PERF.md section 6)
     picks: Dict[str, str] = {}
     for op, t in timings.items():
         if t and op != "shuffle":

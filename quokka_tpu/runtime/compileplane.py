@@ -414,7 +414,12 @@ class AotProgram:
         if c is not None:
             try:
                 out = c(*args)
-                self._unproven = False
+                if self._unproven:
+                    # a loaded executable that lacks a kernel fails at its
+                    # first result's read, outside this guard, unless the
+                    # first call waits for it here
+                    jax.block_until_ready(out)
+                    self._unproven = False
                 return out
             except _MISMATCH_ERRORS:
                 # aval/sharding drift: drop to the jitted fallback for good

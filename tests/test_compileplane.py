@@ -234,6 +234,35 @@ def test_runtime_refusal_on_first_call_of_loaded_executable_is_a_mismatch(
         fresh(jnp.ones(4))
 
 
+def test_refusal_at_the_first_results_read_is_a_mismatch(aot_dir):
+    """A loaded CPU executable that lacks a kernel is dispatched without
+    complaint and fails when its result is waited for (``Function ... not
+    found``): the first call waits for its result inside the guard, so that
+    too is a mismatch and not the query's error; later calls do not wait."""
+    import jax
+
+    class Unready:
+        waited = 0
+
+        def block_until_ready(self):
+            Unready.waited += 1
+            raise jax.errors.JaxRuntimeError(
+                "NOT_FOUND: Function convert_convert_fusion not found")
+
+    before = compileplane.stats().get("aot_mismatch", 0)
+    prog = compileplane.AotProgram(lambda *a: Unready())  # loaded
+    with pytest.raises(compileplane.AotMismatch):
+        prog(jnp.ones(4))
+    assert compileplane.stats().get("aot_mismatch", 0) == before + 1
+    ok = compileplane.AotProgram(lambda *a: jnp.ones(4))
+    ok(jnp.ones(4))
+    assert not ok._unproven
+    proven = compileplane.AotProgram(lambda *a: Unready(),
+                                     builder=lambda: None)
+    proven(jnp.ones(4))  # a program built here: nothing is waited for
+    assert Unready.waited == 1
+
+
 def test_corrupt_artifact_falls_back_to_fresh_compile(aot_dir):
     key = sigkey.make_key("t_corrupt", _RUN_TOKEN, 2, ((4,), "float32"))
     args = (jnp.arange(4.0, dtype=jnp.float32),

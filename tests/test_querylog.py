@@ -47,7 +47,8 @@ RECORD_KEYS = {
     "asof_match_sort", "asof_match_search",
     "join_probe_direct", "join_probe_search", "join_payload_slots",
     "join_probe_general", "join_builds", "str_pred_dict_rows", "groupby_sort_slots",
-    "groupby_groups_out", "agg_merge_slots", "scan_hits", "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
+    "groupby_groups_out", "agg_merge_slots", "compact_sort_slots",
+    "compact_nonzero_slots", "scan_hits", "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
 }
 IN_DISPATCH = ("runtime.dispatch_self", "executors.exec_self",
                "runtime.push", "io.read", "emit.d2h", "compile.acquire",
@@ -351,9 +352,11 @@ def test_explain_as_dict_has_a_pinned_shape(paths):
         # what is left are the executors' own notes: rows (join_build_rows
         # ...), the aggregators' merges by path and the slots they sorted
         # (ops/aggtail.py), the general group-by's sorted slots and emitted
-        # groups, and the joins' builds and probe slots
+        # groups, the joins' builds and probe slots, and the compactions'
+        # mask slots by how their indices were found (ops/kernels.py)
         assert all(k.endswith("_rows") or k.startswith(("agg_merge",
-                                                        "groupby_", "join_"))
+                                                        "groupby_", "join_",
+                                                        "compact_"))
                    for k in set(op) - always - sometimes), sorted(op)
         assert op["padded_in"] >= op["rows_in"] >= 0
     for edge in snap["edges"]:

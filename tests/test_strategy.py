@@ -5,6 +5,7 @@ bench-honesty validation, and device-asof bit-equality across strategies."""
 import json
 import os
 
+import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 import pyarrow as pa
@@ -41,6 +42,28 @@ class TestResolution:
             assert strategy.resolve("groupby") == (want_gb, "default")
             assert strategy.resolve("asof") == (want_asof, "default")
             assert strategy.choice("shuffle") == "masked"
+
+    @pytest.mark.parametrize("plat, want", [
+        ("cpu", "nonzero"), ("gpu", "nonzero"), ("cuda", "nonzero"),
+        ("tpu", "sort")])
+    def test_compact_platform_defaults(self, plat, want, monkeypatch):
+        """The TPU sorts a packed key where a scatter-add of one update a
+        mask slot is dear; the CPU and GPU scatter cheaply."""
+        monkeypatch.setattr(config, "_platform", lambda: plat)
+        assert strategy.resolve("compact") == (want, "default")
+
+    def test_compact_forced_wins_over_the_default(self, monkeypatch):
+        monkeypatch.setattr(config, "_platform", lambda: "tpu")
+        monkeypatch.setenv("QK_KERNEL_STRATEGY", "compact=nonzero")
+        assert strategy.resolve("compact") == ("nonzero", "env")
+        # the legacy envs name other operators and leave this one alone
+        monkeypatch.setenv("QK_KERNEL_STRATEGY", "groupby=sort")
+        monkeypatch.setenv("QUOKKA_HASH_TABLES", "1")
+        monkeypatch.setenv("QUOKKA_HOST_ASOF", "1")
+        assert strategy.resolve("compact") == ("sort", "default")
+        monkeypatch.setenv("QK_KERNEL_STRATEGY", "compact=scatter")
+        with pytest.raises(strategy.StrategyError, match="scatter"):
+            strategy.choice("compact")
 
     def test_env_override_wins(self, monkeypatch):
         monkeypatch.setenv("QK_KERNEL_STRATEGY",
@@ -79,8 +102,9 @@ class TestCalibrationPersistence:
         res = strategy.calibrate(rows=2048, reps=1)
         # shuffle is never picked by calibration (a pipeline property, not
         # a kernel wall — see calibrate(); it is still timed for the
-        # profile's information)
-        assert set(res["choices"]) == set(strategy.OPS) - {"shuffle"}
+        # profile's information); compact keeps its platform default
+        assert set(res["choices"]) == set(strategy.OPS) - {"shuffle",
+                                                           "compact"}
         for op, ch in res["choices"].items():
             assert ch in strategy.OPS[op]
         assert res["timings_s"]["shuffle"].keys() == {"masked", "compacted"}
@@ -179,6 +203,16 @@ class TestHonesty:
         pids = kernels.partition_ids(big, ["k"], 4)
         kernels.split_by_partition(big, pids, 4)
         assert strategy.used_snapshot()["shuffle"] == "masked"
+
+    @pytest.mark.parametrize("forced", ["nonzero", "sort"])
+    def test_compaction_records_used(self, forced, monkeypatch):
+        monkeypatch.setenv("QK_KERNEL_STRATEGY", f"compact={forced}")
+        r = np.random.default_rng(39)
+        b = bridge.arrow_to_device(pa.table({"k": r.integers(0, 9, 3000)}))
+        kernels.compact(kernels.apply_mask(
+            b, jnp.asarray(r.uniform(0, 1, b.padded_len) < 0.3)))
+        assert strategy.used_snapshot() == {"compact": forced}
+        assert strategy.invalid_for_platform("tpu", "compact", forced) is None
 
     def test_multiple_kernels_per_op_all_recorded(self):
         """A mesh query's timed shard kernel and its coordinator-side
@@ -330,3 +364,32 @@ class TestAsofStrategyEquality:
         assert np.asarray(
             out.columns["__asof_matched__"].data)[:3].all()
         assert strategy.used_snapshot()["asof"] == "searchsorted"
+
+
+class TestCompactForm:
+    """Every persisted program that finds live rows' indices
+    (``kernels.live_indices``) ends its compile-plane key in the strategy
+    that ran and ``kernels.COMPACT_FORM``: program keys carry no version of
+    the code, so a body edited under an unchanged key is found by the AOT
+    store only if that string changes with it."""
+
+    def test_each_program_key_ends_in_the_form(self, monkeypatch):
+        from quokka_tpu.ops import sigkey
+
+        r = np.random.default_rng(39)
+        for forced in ("nonzero", "sort"):
+            monkeypatch.setenv("QK_KERNEL_STRATEGY", f"compact={forced}")
+            parts = [kernels.apply_mask(
+                bridge.arrow_to_device(pa.table({
+                    "k": r.integers(0, 9, n), "s": ["a", "b"] * (n // 2)})),
+                jnp.asarray(r.uniform(0, 1, 4096) < 0.4))
+                for n in (3000, 2000)]
+            kernels.compact(parts[0])
+            bridge.concat_batches(parts)
+            buf = asof_ops.RowBuffer(parts[0], 8192)
+            buf.append(parts[0])
+            buf.take(buf.valid, 10, 1024)
+        for kind in ("compact_idx", "fused_concat", "asof_take"):
+            keys = sigkey.ledger_keys(kind)
+            methods = {k[-2] for k in keys if k[-1] == kernels.COMPACT_FORM}
+            assert methods == {"nonzero", "sort"}, (kind, keys)

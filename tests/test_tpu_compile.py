@@ -80,7 +80,14 @@ def _compile(jit_fn, *args, **static):
 def test_compact_and_split(chip):
     from quokka_tpu.ops import kernels
 
-    _compile(kernels._compact_idx, chip(bool), out_size=N >> 2)
+    # the TPU's compaction index: one sort of one int32 operand, where
+    # jnp.nonzero (the CPU's) is a scatter-add of one update a mask slot
+    sort = _compile(kernels._compact_idx, chip(bool), out_size=N >> 6,
+                    method="sort")
+    assert _indexed_ops(sort) == {"sort": 1, "scatter": 0, "gather": 0}
+    assert _indexed_ops(_compile(kernels._compact_idx, chip(bool),
+                                 out_size=N >> 6, method="nonzero"))[
+        "scatter"] >= 1
     _compile(kernels._partition_ids, (chip("int32"),), n_parts=2)
     _compile(kernels._split_masks, chip("int32"), chip(bool), n_parts=2)
     _compile(kernels._mask_and_count, chip(bool), chip(bool))
