@@ -5,10 +5,13 @@ match's least work, over the time it spent in the match's own programs.
 as ``scan_roofline`` counts them) / peak HBM bytes/s / the device seconds of
 the modules that implement the match.  Those are the XLA modules of the
 traced span's ``device_ops`` whose names start ``jit_asof``, ``jit__asof`` or
-``jit__ss_`` (today ``jit__ss_sort_quotes`` and ``jit__ss_probe``,
-quokka_tpu/ops/asof.py; a kernel that replaces them keeps ``asof`` in its
-function's name).  ``device_ops`` holds the span's ten largest modules: a
-match module too small to be among them is not in the time.
+``jit__ss_`` (today ``jit__asof_match``, the merge of both sides as one sort,
+two running maxima and a compacting sort that the TPU runs; the device
+search's ``jit__ss_sort_quotes`` and ``jit__ss_probe`` where the ``asof``
+strategy is ``searchsorted``; quokka_tpu/ops/asof.py; a kernel that replaces
+them keeps ``asof`` in its function's name).  ``device_ops`` holds the span's
+ten largest modules: a match module too small to be among them is not in the
+time.
 
 The match's least bytes are what no implementation can skip: every trade's
 time and symbol code and every quote's (4 bytes each, read once), and a

@@ -1,8 +1,7 @@
 """``asof_sort_match_pct``: its entry, its arithmetic on hand-made records,
 nothing (never an error) against a program whose records lack the counters,
-and 100 in a traced rehearsal of the cell that lists it under the TPU's
-strategies as they are since PR 35 (this directory's conftest still pins the
-search for every other test)."""
+and in a traced rehearsal of the cell that lists it, 100 under the TPU's
+merge (this directory's conftest's pick) and 0 under the device search."""
 
 import json
 import sys

@@ -7,12 +7,11 @@ from a run with nothing to read, and adding it edited no file the benchmark
 already had."""
 
 import json
-import subprocess
 import types
 
 import pytest
 
-from conftest import ROOT
+from conftest import edited_benchmark_files
 from harness import check, loadgen, lowprec, spec, tables
 
 CELL = "h2o_g1_1e7.q5_s2"
@@ -56,24 +55,26 @@ def test_cell_resolves_from_its_entries():
     assert entry["reduced"] == [] and len(entry["source"]) <= 200
     assert "h2oai/db-benchmark" in entry["source"]
     assert "G1_1e7_1e2_0_0" in entry["source"]
-    # appended behind what was there (not "last": the next PR appends too)
-    names = [c["name"] for c in cell.bench["configs"]]
-    assert names.index("h2o_g1_1e7") > names.index("ticks_1d")
-    cells = [w["name"] for w in cell.bench["workloads"]]
-    assert cells.index(CELL) > cells.index("ticks_1d.asof_s2")
+    # the entries by name, never by where they sit: later cells append
+    assert entry["file"] == "benchmarks/configs/h2o_g1_1e7.json"
+    assert {w["name"]: w for w in cell.bench["workloads"]}[CELL] == {
+        "name": CELL, "config": "h2o_g1_1e7", "traffic": "h2o_q5_s2",
+        "chips": 1, "why": cell.entry["why"]}
     assert "same query" in cell.entry["why"] and len(cell.entry["why"]) <= 200
     assert {e["name"] for e, _ in cell.metrics("end_to_end")} == {
         "queries_per_s", "latency_p50_ms", "setup_s"}
     per_layer = {e["name"]: e for e, _ in cell.metrics("per_layer")}
-    order = [m["name"] for m in cell.bench["per_layer"]]
-    assert [n for n in order if n in NEW_METRICS] == list(NEW_METRICS)
-    assert order.index(NEW_METRICS[0]) > order.index("join_direct_probe_pct")
     for name in NEW_METRICS:
         assert per_layer[name]["workloads"] == [CELL]
+    units = [(per_layer[n]["unit"], per_layer[n]["layer"])
+             for n in NEW_METRICS]
+    assert units == [("%", "kernels"), ("slots/row", "executors"),
+                     ("merges", "executors")]
     # the accepted per-layer metrics without a list are this cell's too
     assert {"scan_roofline", "device_idle_pct", "pad_waste_pct",
             "exec_host_ms", "compiles_in_window"} <= set(per_layer)
-    for other in ("tpch_sf1.q1_s2", "tpch_sf1.q3_s2", "ticks_1d.asof_s2"):
+    for other in ("tpch_sf1.q1_s2", "tpch_sf1.q3_s2", "ticks_1d.asof_s2",
+                  "tpch8_sf1.q9_s2", "tpch_q18_sf1.q18_s2"):
         assert not set(NEW_METRICS) & {
             e["name"] for e, _ in spec.Cell(other).metrics("per_layer")}
 
@@ -262,14 +263,7 @@ def test_the_counter_readers_read_the_window_records(monkeypatch):
 
 
 def test_no_existing_benchmark_file_was_edited():
-    """Against the checkout's HEAD: files under ``benchmarks/`` may be added
-    (``??`` / ``A``), never changed, renamed or deleted."""
-    try:
-        out = subprocess.run(
-            ["git", "status", "--porcelain", "--", "benchmarks"], cwd=ROOT,
-            capture_output=True, text=True, timeout=60, check=True).stdout
-    except (OSError, subprocess.SubprocessError):
-        pytest.skip("not a git checkout")
-    edited = [line for line in out.splitlines()
-              if line[:2].strip() not in ("??", "A")]
+    """Against the benchmark as last accepted (``conftest.ACCEPTED``): files
+    under ``benchmarks/`` may be added, never changed, renamed or deleted."""
+    edited = edited_benchmark_files()
     assert not edited, edited

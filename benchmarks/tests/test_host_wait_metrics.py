@@ -45,11 +45,10 @@ def served(monkeypatch, recs):
 
 def test_the_five_entries_were_appended_and_name_layer_and_moves():
     per_layer = spec.load_json(spec.ROOT + "/BENCHMARK.json")["per_layer"]
-    # appended behind the 21 entries the benchmark had, together and in this
-    # order (the last five at this PR; a later PR appends behind them)
-    first = [m["name"] for m in per_layer].index(NEW[0])
-    last = per_layer[first:first + 5]
-    assert first == 21 and tuple(m["name"] for m in last) == NEW
+    # the five entries by name, each once, never by where they sit
+    names = [m["name"] for m in per_layer]
+    assert [names.count(n) for n in NEW] == [1] * 5
+    last = [{m["name"]: m for m in per_layer}[n] for n in NEW]
     assert [(m["unit"], m["better"], m["layer"], m["moves"]) for m in last] \
         == [("reads", "lower", "executors", "latency_p50_ms"),
             ("ms", "lower", "executors", "latency_p50_ms"),
@@ -65,6 +64,9 @@ def test_the_five_entries_were_appended_and_name_layer_and_moves():
     bench = spec.load_json(spec.ROOT + "/BENCHMARK.json")
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert all("workloads" not in e2e[m["moves"]] for m in last)
+    for cell in (w["name"] for w in bench["workloads"]):
+        assert set(NEW) <= {e["name"] for e, _ in spec.Cell(cell).metrics(
+            "per_layer")}
 
 
 def test_arithmetic_on_hand_made_records(monkeypatch):

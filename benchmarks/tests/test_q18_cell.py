@@ -28,7 +28,7 @@ def reader(name):
 def run_cell(capsys, seed, *extra):
     import run
 
-    rc = run.main(["--workload", CELL, "--seed", str(seed), "--seconds", "3",
+    rc = run.main(["--workload", CELL, "--seed", str(seed), "--seconds", "6",
                    "--rehearse", *extra])
     out = capsys.readouterr().out.strip().splitlines()
     return rc, json.loads(out[-1])
@@ -57,17 +57,19 @@ def test_cell_resolves_from_its_entries():
     assert entry["reduced"] == ["scale_factor"]
     assert len(entry["source"]) <= 200 and "2.4.18" in entry["source"]
     assert len(cell.entry["why"]) <= 200
-    # appended behind what was there
-    names = [c["name"] for c in cell.bench["configs"]]
-    assert names[-1] == "tpch_q18_sf1"
-    assert [w["name"] for w in cell.bench["workloads"]][-1] == CELL
+    # the entries by name, never by where they sit: later cells append
+    assert entry["file"] == "benchmarks/configs/tpch_q18_sf1.json"
+    assert {w["name"]: w for w in cell.bench["workloads"]}[CELL] == {
+        "name": CELL, "config": "tpch_q18_sf1", "traffic": "q18_s2",
+        "chips": 1, "why": cell.entry["why"]}
     assert {e["name"] for e, _ in cell.metrics("end_to_end")} == {
         "queries_per_s", "latency_p50_ms", "setup_s"}
     per_layer = {e["name"]: e for e, _ in cell.metrics("per_layer")}
-    order = [m["name"] for m in cell.bench["per_layer"]]
-    assert order[-2:] == list(NEW_METRICS)
     for name in NEW_METRICS:
         assert per_layer[name]["workloads"] == [CELL]
+    units = [(per_layer[n]["unit"], per_layer[n]["layer"])
+             for n in NEW_METRICS]
+    assert units == [("slots/row", "executors"), ("%", "kernels")]
     assert {"scan_roofline", "device_idle_pct", "pad_waste_pct",
             "exec_host_ms", "compiles_in_window"} <= set(per_layer)
     for other in ("tpch_sf1.q1_s2", "tpch_sf1.q3_s2", "ticks_1d.asof_s2",

@@ -4,12 +4,11 @@ an error, from a program that lacks what they read), and adding it edited no
 file the benchmark already had."""
 
 import os
-import subprocess
 import types
 
 import pytest
 
-from conftest import ROOT
+from conftest import ROOT, edited_benchmark_files
 from harness import loadgen, spec
 
 CELL = "ticks_1d.asof_s2"
@@ -118,16 +117,9 @@ def test_flushes_per_query_reads_the_window_records(monkeypatch):
 
 
 def test_no_existing_benchmark_file_was_edited():
-    """Against the checkout's HEAD: files under ``benchmarks/`` may be added
-    (``??`` / ``A``), never changed, renamed or deleted."""
-    try:
-        out = subprocess.run(
-            ["git", "status", "--porcelain", "--", "benchmarks"], cwd=ROOT,
-            capture_output=True, text=True, timeout=60, check=True).stdout
-    except (OSError, subprocess.SubprocessError):
-        pytest.skip("not a git checkout")
-    edited = [line for line in out.splitlines()
-              if line[:2].strip() not in ("??", "A")]
+    """Against the benchmark as last accepted (``conftest.ACCEPTED``): files
+    under ``benchmarks/`` may be added, never changed, renamed or deleted."""
+    edited = edited_benchmark_files()
     assert not edited, edited
     assert os.path.exists(os.path.join(ROOT, "benchmarks", "metrics",
                                        "asof_match_roofline.py"))
