@@ -17,8 +17,9 @@ import jax.numpy as jnp
 from quokka_tpu import config
 from quokka_tpu.obs import spans as tracing
 from quokka_tpu.ops import aggtail, bridge, kernels
+from quokka_tpu.ops import distinct as distinct_ops
 from quokka_tpu.ops import join as join_ops
-from quokka_tpu.ops.batch import DeviceBatch, NumCol
+from quokka_tpu.ops.batch import DeviceBatch, NumCol, key_limbs
 from quokka_tpu.ops.expr_compile import AggPlan, evaluate_predicate, evaluate_to_column
 from quokka_tpu.executors.base import Executor
 
@@ -788,30 +789,30 @@ class BroadcastJoinExecutor(BuildProbeJoinExecutor):
 
 
 class DistinctExecutor(Executor):
-    """Streaming distinct: emit rows not seen before (anti-join against the
-    accumulated key state, reference sql_executors.py:517)."""
+    """Streaming distinct: emit each batch's rows whose key was not seen
+    before, the first row of each key (reference sql_executors.py:517).  The
+    keys seen so far stay sorted on the device and each batch is folded in:
+    one sort of the batch, a search of the state, a linear merge
+    (ops/distinct.py), never a sort of the state."""
+
+    # one batch a dispatch, so what it emits has its input's shape and no
+    # concat of outputs follows which batches were ready together
+    MAX_PIPELINE_BATCHES = 1
 
     def __init__(self, keys: Sequence[str]):
         self.keys = list(keys)
-        self.seen: Optional[DeviceBatch] = None
+        self.seen = distinct_ops.SortedKeys()
 
     def execute(self, batches, stream_id, channel):
         outs = []
         for b in batches:
             if b is None:
                 continue
-            b = kernels.distinct(b, self.keys)
-            b = kernels.compact(b)
-            if self.seen is not None:
-                b = kernels.compact(
-                    join_ops.hash_join_general(b, self.seen, self.keys, self.keys, "anti")
-                )
-            if b.count_valid() == 0:
-                continue
-            self.seen = (
-                b if self.seen is None else bridge.concat_batches([self.seen, b])
-            )
-            outs.append(b)
+            with tracing.span("distinct.batch"):
+                emit, added = self.seen.fold(key_limbs(b, self.keys), b.valid)
+            if added:
+                outs.append(_shrunk(DeviceBatch(
+                    {k: b.columns[k] for k in self.keys}, emit, added)))
         if not outs:
             return None
         return bridge.concat_batches(outs) if len(outs) > 1 else outs[0]

@@ -63,7 +63,11 @@ Record keys (README "Observability" documents each):
   ``compact_sort_slots``, ``compact_nonzero_slots`` (mask slots whose live
   indices one sort of a packed key found, and ``jnp.nonzero``: every
   compaction, fused concat and asof take: ops/kernels.py
-  ``compact_method``), ``scan_hits``, ``scan_misses``;
+  ``compact_method``), ``distinct_rows_in``, ``distinct_rows_out``,
+  ``distinct_sort_slots`` (the streaming distinct: live rows into it, the
+  new keys it emitted, and the slots its two sorts and its probe's window
+  or search went over, counted on the device: ops/distinct.py),
+  ``scan_hits``, ``scan_misses``;
 - ``pool_size`` and ``park_s_total``, ``loop_s_total``: the service's
   worker threads, and their cumulative counters when the query finished.
 """
@@ -120,6 +124,7 @@ COUNTS = ("tasks", "requeues", "backoffs", "syncs", "d2h_bytes", "h2d_bytes",
           "join_probe_general", "join_builds", "str_pred_dict_rows",
           "groupby_sort_slots", "groupby_groups_out", "agg_merge_slots",
           "compact_sort_slots", "compact_nonzero_slots",
+          "distinct_rows_in", "distinct_rows_out", "distinct_sort_slots",
           "scan_hits", "scan_misses")
 KEYS = (("q", "plan_fp", "status") + STAMPS + SECONDS + COUNTS
         + ("compiled", "sync_sites", "pool_size", "park_s_total",
@@ -134,7 +139,9 @@ _FROM_OPSTATS = ("rows_in", "padded_in", "rows_unknown",
                  "join_payload_slots", "join_probe_general", "join_builds",
                  "str_pred_dict_rows", "groupby_sort_slots",
                  "groupby_groups_out", "agg_merge_slots",
-                 "compact_sort_slots", "compact_nonzero_slots")
+                 "compact_sort_slots", "compact_nonzero_slots",
+                 "distinct_rows_in", "distinct_rows_out",
+                 "distinct_sort_slots")
 
 _lock = threading.Lock()
 _open: Dict[str, dict] = {}   # live queries' accumulators

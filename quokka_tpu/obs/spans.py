@@ -60,7 +60,7 @@ _now = time.perf_counter
 
 # in-dispatch span name -> the query record's layer key (obs/querylog.py)
 _LAYER_PREFIXES = (
-    (("exec.", "done.", "asof.", "groupby.", "join."),
+    (("exec.", "done.", "asof.", "groupby.", "join.", "distinct."),
      "executors.exec_self"),
     (("push.",), "runtime.push"),
     (("reader.execute", "bridge.to_device", "prefetch.wait"), "io.read"),

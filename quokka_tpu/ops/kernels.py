@@ -475,11 +475,6 @@ def groupby_aggregate(
     return DeviceBatch(cols, group_valid, None, None).note_count(num)
 
 
-def distinct(batch: DeviceBatch, keys: Sequence[str]) -> DeviceBatch:
-    g = groupby_aggregate(batch, list(keys), [])
-    return g.select(list(keys))
-
-
 # ---------------------------------------------------------------------------
 # sort / top-k
 # ---------------------------------------------------------------------------

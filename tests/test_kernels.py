@@ -8,8 +8,9 @@ import pyarrow as pa
 import pytest
 
 from quokka_tpu.ops import bridge, kernels
+from quokka_tpu.ops import distinct as distinct_ops
 from quokka_tpu.ops import join as join_ops
-from quokka_tpu.ops.batch import DeviceBatch, NumCol, StrCol
+from quokka_tpu.ops.batch import DeviceBatch, NumCol, StrCol, key_limbs
 
 from conftest import make_table
 
@@ -153,10 +154,16 @@ class TestKernels:
 
     def test_distinct(self, table, pdf):
         b = bridge.arrow_to_device(table)
-        d = kernels.distinct(b, ["k", "s"])
-        got = bridge.device_to_arrow(kernels.compact(d)).to_pandas()
+        seen = distinct_ops.SortedKeys()
+        emit, added = seen.fold(key_limbs(b, ["k", "s"]), b.valid)
+        got = bridge.device_to_arrow(kernels.compact(DeviceBatch(
+            {k: b.columns[k] for k in ("k", "s")}, emit, added))).to_pandas()
         exp = pdf[["k", "s"]].drop_duplicates()
-        assert len(got) == len(exp)
+        assert added == seen.count == len(exp)
+        assert got.astype(str).values.tolist() == (
+            exp.astype(str).values.tolist())
+        # the same rows again: every key seen, nothing new
+        assert seen.fold(key_limbs(b, ["k", "s"]), b.valid)[1] == 0
 
     def test_partition_deterministic_and_complete(self, table):
         b = bridge.arrow_to_device(table)

@@ -48,7 +48,8 @@ RECORD_KEYS = {
     "join_probe_direct", "join_probe_search", "join_payload_slots",
     "join_probe_general", "join_builds", "str_pred_dict_rows", "groupby_sort_slots",
     "groupby_groups_out", "agg_merge_slots", "compact_sort_slots",
-    "compact_nonzero_slots", "scan_hits", "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
+    "compact_nonzero_slots", "distinct_rows_in", "distinct_rows_out",
+    "distinct_sort_slots", "scan_hits", "scan_misses", "compiled", "pool_size", "park_s_total", "loop_s_total",
 }
 IN_DISPATCH = ("runtime.dispatch_self", "executors.exec_self",
                "runtime.push", "io.read", "emit.d2h", "compile.acquire",
